@@ -1,17 +1,24 @@
 """ctypes loader for the native data-plane library (native/).
 
-Builds on demand with `make -C native` the first time, caches the .so.
-Every entry point has a pure-python fallback, so the framework works
-without a C toolchain — but the native path is what makes the CPU
-baseline honest (reference analog: crc32c_intel_fast + ISA-L/gf-complete
-SIMD kernels vs their table fallbacks).
+The first load in every process runs `make -C native`, so the library
+is always built from the .c files the checkout holds and `make` alone
+decides staleness (a no-op when it is up to date).  Every entry point
+keeps a pure-python fallback so the framework still runs on a host
+without a C toolchain — but that fallback is a per-byte loop (a 4 MiB
+frame takes seconds), so a failed build is LOUD: it warns with the
+compiler's output, `build_error()` keeps it, and chip_smoke.py fails
+on `available() == False`.  The native path is also what makes the CPU
+baseline honest (reference analog: crc32c_intel_fast + ISA-L/
+gf-complete SIMD kernels vs their table fallbacks).
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import subprocess
 import threading
+import warnings
 from pathlib import Path
 
 _NATIVE_DIR = Path(__file__).resolve().parent.parent.parent / "native"
@@ -20,21 +27,37 @@ _LIB_PATH = _NATIVE_DIR / "libceph_tpu_native.so"
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _tried = False
+_build_error: str | None = None
+
+
+def _build() -> None:
+    """`make -C native` under an exclusive file lock: ProcCluster boots
+    many daemon processes at once, and two makes linking the same .so
+    would hand a third a half-written library."""
+    with open(_NATIVE_DIR / "Makefile") as lockf:
+        fcntl.flock(lockf, fcntl.LOCK_EX)
+        subprocess.run(["make", "-C", str(_NATIVE_DIR), "-s"],
+                       check=True, capture_output=True, text=True,
+                       timeout=120)
 
 
 def load() -> ctypes.CDLL | None:
-    """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _tried
+    """Build (when stale) and load the native library; None — after a
+    loud warning — if it cannot be built or loaded."""
+    global _lib, _tried, _build_error
     with _lock:
         if _lib is not None or _tried:
             return _lib
         _tried = True
         try:
-            if not _LIB_PATH.exists():
-                subprocess.run(["make", "-C", str(_NATIVE_DIR), "-s"],
-                               check=True, capture_output=True, timeout=120)
+            _build()
             lib = ctypes.CDLL(str(_LIB_PATH))
-        except Exception:  # noqa: BLE001 - fall back to pure python
+        except (OSError, subprocess.SubprocessError) as e:
+            _build_error = f"{e!r}: {getattr(e, 'stderr', '') or ''}"
+            warnings.warn(
+                f"native library unavailable, using the slow "
+                f"pure-python crc/GF paths: {_build_error}",
+                RuntimeWarning, stacklevel=2)
             return None
         lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
         lib.ceph_tpu_crc32c.argtypes = [
@@ -60,6 +83,11 @@ def load() -> ctypes.CDLL | None:
 
 def available() -> bool:
     return load() is not None
+
+
+def build_error() -> str | None:
+    """Why load() returned None (make's stderr included)."""
+    return _build_error
 
 
 def gf8_matvec(mat, chunks):

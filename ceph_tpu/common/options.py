@@ -303,13 +303,9 @@ register_options([
            "persist every XLA/Mosaic compile to disk "
            "(ops/compile_cache.py): a restarted daemon re-traces its "
            "jit buckets but never re-compiles them; hits surface in "
-           "the compile ledger as fast first-launches, not stalls",
-           flags=("startup",)),
-    Option("osd_ec_compile_cache_dir", str, "",
-           "persistent compile cache directory; empty = "
-           "~/.cache/ceph_tpu/xla beside the autotune v2 cache "
-           "(CEPH_TPU_COMPILE_CACHE also honored).  One directory per "
-           "host — the first daemon to enable it wins",
+           "the compile ledger as fast first-launches, not stalls.  "
+           "The directory is JAX_COMPILATION_CACHE_DIR, else "
+           ".jax_cache/ in the checkout",
            flags=("startup",)),
     Option("osd_ec_prewarm", bool, False,
            "compile the expected jit-bucket set at OSD boot BEFORE "

@@ -79,17 +79,17 @@ class ErasureCodeJax(ErasureCode):
         else:
             self.matrix = gf.cauchy_rs_matrix(self.k, self.m)
         bs = _ops()
-        import jax
         import jax.numpy as jnp
+        from ...ops import device
         self._enc_bitmat = jnp.asarray(
             bs.interleave_bitmatrix(self.matrix[self.k:]), dtype=jnp.int8)
         # word-packed variant: ~4x the byte kernel on TPU (bit unpack
         # touches 4 bytes per VPU op); byte path retained for CPU/XLA
-        self._use_w32 = jax.default_backend() != "cpu"
+        self._use_w32 = not device.on_cpu()
         self._enc_bitmat32 = jnp.asarray(
             bs._w32_bitmat(self.matrix[self.k:]), dtype=jnp.int8) \
             if self._use_w32 else None
-        self._fused_point: dict | None = None   # lazy autotune result
+        self._fused_point: dict | None = None   # lazy, ops/autotune
         super().init(profile)
 
     def get_alignment(self) -> int:
@@ -158,25 +158,20 @@ class ErasureCodeJax(ErasureCode):
         return bs.gf_bitmatmul_w32(self._enc_bitmat32, words, self.m)
 
     def fused_point(self) -> dict:
-        """The fused kernel's (tile, wb, extract, combine) operating
-        point for this device, resolved lazily through the
-        ops/autotune cache (first fused call on a fresh accelerator
-        pays the sweep; CPU and opted-out runs get the static
-        defaults)."""
+        """The fused kernel's (tile, wb, combine) operating point for
+        this device and geometry plus its `source` — the committed
+        ops/fused_points.json entry or the static default
+        (ops/autotune.fused_operating_point; never a sweep)."""
         if self._fused_point is None:
             from ...ops import autotune
-            try:
-                self._fused_point = autotune.fused_operating_point(
-                    self.k, self.m, mat=self.matrix[self.k:],
-                    bitmat32=self._enc_bitmat32)
-            except Exception:  # noqa: BLE001 — tuning must never
-                self._fused_point = autotune.default_point()  # break IO
+            self._fused_point = autotune.fused_operating_point(
+                self.k, self.m)
         return self._fused_point
 
     def encode_words_with_crc(self, words, tile: int | None = None,
                               wb: int | None = None):
         """Device-resident fused parity + crc over word-packed input at
-        the autotuned operating point (the overlapped hier-crc kernel
+        the device's operating point (the overlapped hier-crc kernel
         with the device-side combine — in-kernel VMEM accumulator or
         XLA log-fold per the point's `combine` axis; see
         ops/bitsliced.gf_encode_with_crc_w32_fold).  words (k, W)
@@ -198,8 +193,7 @@ class ErasureCodeJax(ErasureCode):
         cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
         return bs.gf_encode_with_crc_w32_fold(
             self._enc_bitmat32, cmat_sub, words, self.m,
-            tile=tile, wb=wb, extract=point["extract"],
-            combine=point["combine"])
+            tile=tile, wb=wb, combine=point["combine"])
 
     def encode_stripes(self, stripes):
         """Batched encode: (B, k, C) -> (B, m, C), one kernel launch.
@@ -234,7 +228,6 @@ class ErasureCodeJax(ErasureCode):
             use_w32=self._use_w32,
             tile=point["tile"] if point else None,
             wb=point["wb"] if point else None,
-            extract=point["extract"] if point else "planar",
             combine=point["combine"] if point else "xla")
 
     def encode_extents_with_crc_submit(self, runs: list[np.ndarray]):
@@ -251,13 +244,12 @@ class ErasureCodeJax(ErasureCode):
             use_w32=self._use_w32,
             tile=point["tile"] if point else None,
             wb=point["wb"] if point else None,
-            extract=point["extract"] if point else "planar",
             combine=point["combine"] if point else "xla")
 
     def launch_bucket(self, handle) -> str:
         """Flight-recorder jit-bucket key of one submit handle
         (ops/profiler.py): the axes XLA/Mosaic actually key their
-        caches on — kernel path, the autotuned (tile, wb) operating
+        caches on — kernel path, the (tile, wb, combine) operating
         point, and the pow2-padded (width, run-count) launch shape —
         so the compile ledger's first-seen detection matches real
         compiles instead of guessing from raw widths."""
@@ -265,9 +257,8 @@ class ErasureCodeJax(ErasureCode):
         base = _extents_bucket(handle)
         point = self._fused_point
         if point and self._use_w32:
-            return (f"{base}:t{point.get('tile')}"
-                    f":wb{point.get('wb')}"
-                    f":{point.get('extract')}.{point.get('combine')}")
+            return (f"{base}:t{point['tile']}:wb{point['wb']}"
+                    f":{point['combine']}")
         return base
 
     def encode_extents_with_crc_finalize(self, handle):
@@ -400,7 +391,6 @@ class ErasureCodeJax(ErasureCode):
         the gf_encode_extents_with_crc_submit dispatch shapes (tile
         padding, pow2 tile-count bucketing, pow2 run-count bucketing
         all reproduced)."""
-        import jax
         import jax.numpy as jnp
         bs = _ops()
         from ...common.util import next_pow2
@@ -415,11 +405,10 @@ class ErasureCodeJax(ErasureCode):
                 (self._enc_bitmat, cmat,
                  self._aot_spec((k, nt * tile), np.uint8)),
                 {"m": m, "tile": tile})
+        # an accelerator (use_w32): the submit half donates its staged
+        # words there, so the donated twins are what it dispatches
         point = self.fused_point()
-        tile_hier = point["tile"] or bs.FUSED_TILE_HIER
-        wb = point["wb"] or bs.FUSED_WB
-        extract = point["extract"]
-        donate = jax.default_backend() != "cpu"
+        tile_hier, wb = point["tile"], point["wb"]
         hier = min(widths) >= tile_hier
         tile = tile_hier if hier else bs.FUSED_TILE
         ntiles_run = [-(-w // tile) for w in widths]
@@ -435,21 +424,17 @@ class ErasureCodeJax(ErasureCode):
             ntiles_run += [0] * (nruns_acc - len(ntiles_run))
             run_map, first_map, adv, comb = bs._acc_launch_args(
                 ntiles_run, tile, wb)
-            acc_fn = bs._hier_acc_donate if donate else bs._hier_acc
             return bs.aot_compile(
-                "hier_acc_donate" if donate else "hier_acc", acc_fn,
+                "hier_acc_donate", bs._hier_acc_donate,
                 (self._enc_bitmat32, cmat_sub, adv, comb, run_map,
                  first_map, words),
                 {"m": m, "tile": tile, "wb": wb, "nruns": nruns_acc,
-                 "interpret": False, "extract": extract})
+                 "interpret": False})
         if hier:
-            hier_fn = bs._fused_hier_lsub_donate if donate \
-                else bs._fused_hier_lsub
             return bs.aot_compile(
-                "hier_lsub_donate" if donate else "hier_lsub", hier_fn,
+                "hier_lsub_donate", bs._fused_hier_lsub_donate,
                 (self._enc_bitmat32, cmat_sub, words),
-                {"m": m, "tile": tile, "wb": wb, "interpret": False,
-                 "extract": extract})
+                {"m": m, "tile": tile, "wb": wb, "interpret": False})
         cmat32 = jnp.asarray(cl.crc_tile_matrix_w32(tile // 4))
         return bs.aot_compile(
             "fused_w32", bs.gf_encode_with_crc_pallas_w32,

@@ -1,185 +1,109 @@
-"""Cached autotuner for the fused parity+crc kernel's operating point.
+"""Operating point of the fused parity+crc kernel.
 
-The fused kernel has four knobs with hardware-dependent optima:
+The fused hier kernel has three knobs with hardware-dependent optima:
 
   * `tile` — bytes per grid step (DMA granularity vs VMEM pressure);
   * `wb` — crc sub-block words (the crc matmul's M dimension is
     (k+m) * tile/4/wb, so wb trades MXU row utilization against matrix
     VMEM, and with the in-kernel combine also the accumulator size);
-  * `extract` — the crc bit-extraction variant: "planar" (32
-    single-bit passes, lowers everywhere), "packed" (4 bits per masked
-    pass) or "wide" (mask-free shift-only passes, mod-2 junk
-    cancellation) — the non-planar variants use a strided sublane
-    slice that only lowers on some Mosaic generations;
   * `combine` — the L combine depth: "xla" streams per-grid-step
     sub-block L-blocks to HBM and log-folds them in XLA (parallel grid
     semantics), "kernel" folds them into a VMEM-resident per-run
     accumulator inside the kernel (sequential grid, no HBM round-trip
-    or relayout).  Which wins depends on how the generation prices
-    sequential-grid pipelining vs the XLA epilogue.
+    or relayout).
 
-tools/fused_tile_sweep.py used to sweep tile/wb by hand and the
-winners were frozen into bitsliced.FUSED_TILE_HIER / FUSED_WB; this
-module replaces the hardcoded constants with a measured, per-device
-choice:
+The SERVED path never measures anything.  `fused_operating_point`
+reads the point for this device kind and geometry from
+`fused_points.json`, a file in git beside this module, and falls back
+to the static `default_point()` — saying which in the returned
+`source`, so `perf dump`-level provenance (the launch bucket string,
+chip_smoke.py's JSON) always names where the running kernel's shape
+came from.  Two cold processes on the same checkout therefore run the
+same program.
 
-  * the sweep runs at plugin init (first fused encode) on accelerator
-    backends only — CPU/interpret callers get the static defaults;
-  * every candidate is first VALIDATED bit-exactly against the host
-    crc32c and parity oracles, so a variant that miscompiles or
-    misbehaves on this Mosaic generation is skipped, never shipped;
-  * results persist in a JSON cache keyed by (platform, device_kind,
-    k, m), so only the first init on a given device pays the sweep;
-  * a wall-clock budget (CEPH_TPU_AUTOTUNE_BUDGET_S, default 75 s)
-    bounds init latency — candidates are ordered best-guess-first
-    (the cached winner of the nearest (platform, device_kind) key
-    when this exact (k, m) is cold, then the static default) and the
-    sweep keeps the best fully-measured point when time runs out.
-
-Env knobs: CEPH_TPU_AUTOTUNE=0 disables sweeping (cache hits are still
-honored); CEPH_TPU_AUTOTUNE_CACHE overrides the cache path.
+The sweep that produces the file is a tool, not a code path:
+`python -m ceph_tpu.tools.fused_tile_sweep` on the chip validates every
+candidate bit-exactly against the host parity and crc32c oracles,
+times the valid ones, prints the table — every candidate that fails
+to compile is a finding, with its error — and writes the winner into
+the file for the builder to commit.  The default point failing is
+fatal there.
 """
 
 from __future__ import annotations
 
+import functools
 import json
-import os
-import threading
 import time
 from pathlib import Path
 
 import numpy as np
 
-# candidate space: tiles around the headline kernel's W32_TILE, wb
-# spanning crc-matmul M from ~(k+m)*32 to ~(k+m)*256
-SWEEP_TILES = (32768, 65536, 131072, 262144)
+from . import device
+
+POINTS_FILE = Path(__file__).with_name("fused_points.json")
+
+# candidate space: tiles up to the headline kernel's W32_TILE, wb
+# spanning crc-matmul M from ~(k+m)*32 to ~(k+m)*256.  Tile 262144 is
+# gone: on TPU v5 lite (jax 0.9.0, libtpu 0.0.34) Mosaic runs out of
+# scoped VMEM for it at every wb after a 3-5 minute compile (chip run,
+# PR 21).  Compile cost grows with the tile (the kernel body is fully
+# unrolled over it): ~6 s at 32768, ~16 s at 65536, ~60 s at 131072
+# per launch shape at wb=512 (same run), which is why the sweep's
+# report carries each candidate's compile seconds beside its rate.
+SWEEP_TILES = (32768, 65536, 131072)
 SWEEP_WBS = (256, 512, 1024)
-SWEEP_EXTRACTS = ("planar", "packed", "wide")
 SWEEP_COMBINES = ("xla", "kernel")
 
 # measurement input: bytes per shard (multiple of every sweep tile)
 MEASURE_BYTES = 1 << 21
 MEASURE_ITERS = (5, 15)
-ROOFLINE_BPS = 1e12           # same elision gate as bench.py
 
-# the cache's kernel-generation tag: bumped when the kernel family
-# changes shape (r2 = the overlapped/accumulator kernel), so winners
-# measured under an older kernel never satisfy a lookup — they remain
-# visible to the nearest-key SEEDING below, which only affects sweep
-# ordering, never skips validation
-KERNEL_GEN = "fused_w32r2"
+# Selection: candidates within this fraction of the fastest rate count
+# as tied, and the tie goes to the cheapest compile.  Why: on TPU v5
+# lite every (tile, wb, combine) landed between 31 and 41 GB/s — three
+# orders above what the served path feeds the kernel — while compile
+# cost per launch shape spread 10x with the tile (PERF.md Findings, PR
+# 21).  A first-seen launch shape compiles in-band, inside a client
+# op with a 30 s timeout, and the boot prewarm pays the same cost per
+# bucket; a few percent of kernel rate does not buy that back.
+RATE_TIE = 0.05
 
-_lock = threading.Lock()
+_POINT_KEYS = ("tile", "wb", "combine")
 
 
 def default_point() -> dict:
-    """The static fallback point: the frozen tile/wb with the planar
-    extraction and XLA combine — the only variant shipped without a
-    per-device validation run (it is the one that lowers everywhere)."""
+    """The static point: the frozen tile/wb with the XLA combine."""
     from . import bitsliced as bs
     return {"tile": bs.FUSED_TILE_HIER, "wb": bs.FUSED_WB,
-            "extract": "planar", "combine": "xla"}
+            "combine": "xla"}
 
 
-def _cache_path() -> Path:
-    env = os.environ.get("CEPH_TPU_AUTOTUNE_CACHE")
-    if env:
-        return Path(env)
-    return Path.home() / ".cache" / "ceph_tpu" / "autotune.json"
+@functools.cache
+def _load_points() -> dict:
+    return json.loads(POINTS_FILE.read_text())
 
 
-def _migrate_v1_entry(ent: dict) -> dict | None:
-    """v1 cache rows ({tile, wb, packed}) become v2 rows so they can
-    still SEED candidate ordering; their keys carry the old kernel
-    generation, so they never satisfy a lookup directly."""
-    if "tile" not in ent or "wb" not in ent:
-        return None
-    return {"tile": ent["tile"], "wb": ent["wb"],
-            "extract": "packed" if ent.get("packed") else "planar",
-            "combine": "xla", "gbps": ent.get("gbps", 0.0),
-            "when": ent.get("when", "")}
+def fused_operating_point(k: int, m: int) -> dict:
+    """The (tile, wb, combine) point the fused encode+crc path runs at
+    on THIS device for a (k, m) code, plus `source`: the committed
+    file entry, or the default when the file has none for this device
+    kind and geometry (and always on the CPU twin, which never runs
+    the hier kernels outside interpret-mode tests)."""
+    if device.on_cpu():
+        return {**default_point(), "source": "default (cpu)"}
+    kind = device.describe()["kind"]
+    ent = _load_points().get(kind, {}).get(f"k{k}m{m}")
+    if ent is None:
+        return {**default_point(),
+                "source": f"default (no {kind!r} k{k}m{m} entry in "
+                          f"{POINTS_FILE.name})"}
+    return {**{kk: ent[kk] for kk in _POINT_KEYS},
+            "source": f"{POINTS_FILE.name}[{kind}][k{k}m{m}]"}
 
 
-def _load_cache() -> dict:
-    try:
-        data = json.loads(_cache_path().read_text())
-    except (OSError, ValueError):
-        return {"version": 2, "entries": {}}
-    if data.get("version") == 2:
-        return data
-    if data.get("version") == 1:
-        entries = {}
-        for key, ent in data.get("entries", {}).items():
-            migrated = _migrate_v1_entry(ent)
-            if migrated is not None:
-                entries[key] = migrated
-        return {"version": 2, "entries": entries}
-    return {"version": 2, "entries": {}}
-
-
-def _save_cache(data: dict) -> None:
-    """Atomic, best-effort: a read-only home dir must not break init."""
-    try:
-        path = _cache_path()
-        path.parent.mkdir(parents=True, exist_ok=True)
-        tmp = path.with_suffix(".tmp")
-        tmp.write_text(json.dumps(data, indent=1, sort_keys=True))
-        os.replace(tmp, path)
-    except OSError:
-        pass
-
-
-def _device_prefix() -> str:
-    import jax
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", "?")
-    return f"{dev.platform}/{kind}/"
-
-
-def _device_key(k: int, m: int) -> str:
-    import jax
-    # the jax/jaxlib version is part of the key: the packed/wide
-    # variants' lowering is Mosaic-generation-dependent, so a point
-    # validated on one runtime must NOT be trusted (unvalidated) on
-    # another — an upgrade simply re-sweeps
-    return (f"{_device_prefix()}jax{jax.__version__}"
-            f"/{KERNEL_GEN}/k{k}m{m}")
-
-
-def _nearest_point(cache: dict, k: int, m: int) -> dict | None:
-    """Seed for a cold (k, m): the cached winner whose key shares this
-    device's (platform, device_kind) prefix — any geometry, jax
-    version or kernel generation.  A cold k=4,m=2 plugin init on a
-    device that already swept k=8,m=3 starts from that winner's
-    neighborhood instead of the static best-guess order, so a
-    budget-capped sweep measures the likely-best region first.  Seeds
-    only ORDER candidates; every candidate still validates."""
-    import jax
-    prefix = _device_prefix()
-    ver_tag = f"/jax{jax.__version__}/"
-    best, best_rank = None, None
-    for key, ent in cache.get("entries", {}).items():
-        if not key.startswith(prefix):
-            continue
-        point = {kk: ent.get(kk) for kk in
-                 ("tile", "wb", "extract", "combine")}
-        if point["tile"] is None or point["wb"] is None:
-            continue
-        # prefer: same jax version, then same kernel generation, then
-        # the fastest measured winner (gbps 0.0 = failure sentinel)
-        rank = (ver_tag not in key, f"/{KERNEL_GEN}/" not in key,
-                -float(ent.get("gbps") or 0.0))
-        if best_rank is None or rank < best_rank:
-            best, best_rank = point, rank
-    return best
-
-
-def candidates(k: int, m: int, tiles=None, wbs=None,
-               seed: dict | None = None) -> list[dict]:
-    """Legal (tile, wb, extract, combine) points, best-guess-first:
-    the `seed` point (a cached neighbor's winner) leads when given,
-    then the frozen default, then the seed's (tile, wb) neighborhood —
-    so a budget-capped sweep still measures a meaningful baseline."""
+def candidates(k: int, m: int, tiles=None, wbs=None) -> list[dict]:
+    """Legal (tile, wb, combine) points, the default first."""
     r = k + m
     out = []
     for tile in tiles or SWEEP_TILES:
@@ -191,33 +115,20 @@ def candidates(k: int, m: int, tiles=None, wbs=None,
             if (r * s) % 8:      # lsub/lacc out-block sublane alignment
                 continue
             for combine in SWEEP_COMBINES:
-                for extract in SWEEP_EXTRACTS:
-                    out.append({"tile": tile, "wb": wb,
-                                "extract": extract, "combine": combine})
+                out.append({"tile": tile, "wb": wb, "combine": combine})
     dflt = default_point()
-
-    def _match(c: dict, p: dict | None) -> bool:
-        return p is not None and \
-            all(c[kk] == p.get(kk) for kk in c)
-
-    out.sort(key=lambda c: (
-        not _match(c, seed),
-        not _match(c, dflt),
-        seed is None or c["tile"] != seed.get("tile"),
-        seed is None or c["wb"] != seed.get("wb"),
-        c["tile"] != dflt["tile"], c["wb"] != dflt["wb"],
-        c["extract"] != "planar", c["combine"] != "xla"))
+    out.sort(key=lambda c: (c != dflt, c["tile"] != dflt["tile"],
+                            c["wb"] != dflt["wb"]))
     return out
 
 
-def _validate(mat: np.ndarray, bitmat32, cand: dict,
-              interpret: bool = False) -> bool:
+def validate(mat: np.ndarray, bitmat32, cand: dict,
+             interpret: bool = False) -> str | None:
     """Bit-exactness gate: one small fused launch (TWO grid steps, so
     the accumulator's cross-step advance fold is exercised) vs the
-    host parity and crc32c oracles.  A candidate that fails to
-    compile, lower, or match (e.g. the packed/wide extraction's
-    strided slice on an older Mosaic, or the accumulator kernel's
-    scalar-prefetch grid) is rejected here — never silently shipped.
+    host parity and crc32c oracles.  Returns None when the candidate
+    compiles and matches, else what went wrong — a lowering/compile
+    error is reported with the compiler's message, never swallowed.
     `interpret` runs the same check through the Pallas interpreter
     (the CPU tier-1 gate, fused_tile_sweep --validate-only)."""
     import jax.numpy as jnp
@@ -235,25 +146,27 @@ def _validate(mat: np.ndarray, bitmat32, cand: dict,
     try:
         par_w, lbits = bs.gf_encode_with_crc_w32_fold(
             bitmat32, cmat_sub, words, m_, tile=tile, wb=wb,
-            interpret=interpret, extract=cand["extract"],
-            combine=cand["combine"])
+            interpret=interpret, combine=cand["combine"])
         parity = np.asarray(par_w).view("<u4").view(np.uint8) \
             .reshape(m_, 2 * tile)
         ls = cl.bits_to_u32(np.asarray(lbits))
-    except Exception:  # noqa: BLE001 — any lowering/compile failure
-        return False
+    except Exception as e:  # noqa: BLE001 — the finding to report
+        return f"compile/launch failed: {type(e).__name__}: {e}"
     if not np.array_equal(parity, gf.gf_matvec(mat, chunks)):
-        return False
+        return "parity differs from gf_matvec"
     allsh = np.concatenate([chunks, parity], axis=0)
-    return all(
-        cl.fold_run_crc(int(ls[s]), 2 * tile, 0xFFFFFFFF)
-        == _crc.crc32c(allsh[s].tobytes(), 0xFFFFFFFF)
-        for s in range(k + m_))
+    for s in range(k + m_):
+        if cl.fold_run_crc(int(ls[s]), 2 * tile, 0xFFFFFFFF) != \
+                _crc.crc32c(allsh[s].tobytes(), 0xFFFFFFFF):
+            return f"crc of shard {s} differs from host crc32c"
+    return None
 
 
-def _measure(bitmat32, k: int, m: int, cand: dict) -> float:
-    """Short chained-fori slope timing (bench.py's anti-elision method,
-    scaled down): returns input bytes/sec, 0.0 on a gated sample."""
+def measure(bitmat32, k: int, m: int, cand: dict) -> float:
+    """Short chained-loop slope timing: input bytes/sec on the live
+    device (the median of two slopes; a slope above the device's HBM
+    peak is an elided dispatch and is dropped — 0.0 when none
+    survives).  ONE compile: the trip count is a traced argument."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -261,6 +174,7 @@ def _measure(bitmat32, k: int, m: int, cand: dict) -> float:
     from . import bitsliced as bs
     from . import crc32c_linear as cl
     tile, wb = cand["tile"], cand["wb"]
+    hbm_peak = device.peaks()["hbm_bytes_per_s"]
     rng = np.random.default_rng(0x7E5)
     flat = rng.integers(0, 256, (k, MEASURE_BYTES), dtype=np.uint8)
     x0 = jnp.asarray(flat.view(np.int32))
@@ -269,106 +183,80 @@ def _measure(bitmat32, k: int, m: int, cand: dict) -> float:
     def step(x):
         par, lbits = bs.gf_encode_with_crc_w32_fold(
             bitmat32, cmat_sub, x, m, tile=tile, wb=wb,
-            extract=cand["extract"], combine=cand["combine"])
+            combine=cand["combine"])
         return par ^ jnp.sum(lbits)      # crc feeds the chain: no DCE
 
-    def make(iters):
-        @jax.jit
-        def f(x):
-            def body(i, x):
-                return x.at[:m, :].set(x[:m, :] ^ step(x))
-            return lax.fori_loop(0, iters, body, x)
-        return f
+    @jax.jit
+    def f(x, iters):
+        def body(i, x):
+            return x.at[:m, :].set(x[:m, :] ^ step(x))
+        return lax.fori_loop(0, iters, body, x)
 
     lo_i, hi_i = MEASURE_ITERS
-    f_lo, f_hi = make(lo_i), make(hi_i)
-    jax.block_until_ready(f_lo(x0))
-    jax.block_until_ready(f_hi(x0))
-    best = []
+    jax.block_until_ready(f(x0, lo_i))              # compile
+    rates = []
     for rep in range(2):
         v = jax.block_until_ready(x0 ^ (rep + 1))
         t0 = time.perf_counter()
-        jax.block_until_ready(f_lo(v))
+        jax.block_until_ready(f(v, lo_i))
         lo = time.perf_counter() - t0
         t0 = time.perf_counter()
-        jax.block_until_ready(f_hi(v))
+        jax.block_until_ready(f(v, hi_i))
         hi = time.perf_counter() - t0
         dt = (hi - lo) / (hi_i - lo_i)
-        if dt > 0 and k * MEASURE_BYTES / dt < ROOFLINE_BPS:
-            best.append(k * MEASURE_BYTES / dt)
-    best.sort()
-    return best[len(best) // 2] if best else 0.0
+        if dt > 0 and k * MEASURE_BYTES / dt < hbm_peak:
+            rates.append(k * MEASURE_BYTES / dt)
+    rates.sort()
+    return rates[len(rates) // 2] if rates else 0.0
 
 
-def fused_operating_point(k: int, m: int, mat: np.ndarray | None = None,
-                          bitmat32=None, tiles=None, wbs=None,
-                          force: bool = False,
-                          report: list | None = None,
-                          interpret: bool = False) -> dict:
-    """The (tile, wb, extract, combine) point the fused encode+crc
-    path should run at on THIS device, sweeping and caching on first
-    use.
-
-    `mat` (m, k) GF(2^8) generator rows and `bitmat32` (its
-    _w32_bitmat device array) enable the sweep; without them (or on
-    CPU, or with CEPH_TPU_AUTOTUNE=0) the cached or default point is
-    returned as-is.  `report`, when given, collects per-candidate
-    (cand, gbps|None) tuples for the sweep CLI; `interpret` runs
-    candidate validation through the Pallas interpreter and waives
-    the accelerator-backend requirement (tests and the CPU validate
-    gate — measurement still runs on whatever backend is live)."""
-    import jax
-    if jax.default_backend() == "cpu" and not interpret:
-        return default_point()
-    with _lock:
-        key = _device_key(k, m)
-        cache = _load_cache()
-        hit = cache["entries"].get(key)
-        if hit is not None and not force:
-            return {kk: hit[kk]
-                    for kk in ("tile", "wb", "extract", "combine")}
-        if os.environ.get("CEPH_TPU_AUTOTUNE", "1") == "0" or \
-                mat is None or bitmat32 is None:
-            return default_point()
-        budget = float(os.environ.get("CEPH_TPU_AUTOTUNE_BUDGET_S", "75"))
-        seed = _nearest_point(cache, k, m)
+def sweep(k: int, m: int, mat: np.ndarray, bitmat32,
+          tiles=None, wbs=None):
+    """Validate and time every candidate on the live accelerator,
+    yielding one report row per candidate as it finishes: (cand,
+    bytes/sec | None, error | None, wall seconds spent on it —
+    compiles dominate).  Raises when the default point does not
+    validate: the served path has no other point to fall back to."""
+    device.require_accelerator("fused operating-point sweep")
+    for cand in candidates(k, m, tiles, wbs):
         t0 = time.perf_counter()
-        best, best_rate = None, 0.0
-        tried = 0
-        for cand in candidates(k, m, tiles, wbs, seed=seed):
-            # honor the budget once ANY candidate has been attempted —
-            # even if every sample so far was roofline-gated to 0.0 —
-            # so a noisy/elision-prone runtime cannot turn plugin init
-            # into an unbounded 72-candidate sweep
-            if tried and time.perf_counter() - t0 > budget:
-                break
-            tried += 1
-            if not _validate(mat, bitmat32, cand, interpret=interpret):
-                if report is not None:
-                    report.append((cand, None))
-                continue
-            try:
-                rate = _measure(bitmat32, k, m, cand)
-            except Exception:  # noqa: BLE001 — e.g. interpret-mode
-                # validation on a CPU backend, where the compiled
-                # measurement kernel cannot lower: a candidate that
-                # validates but cannot be timed scores 0.0 instead of
-                # crashing the sweep out of plugin init
-                rate = 0.0
-            if report is not None:
-                report.append((cand, rate))
-            if rate > best_rate:
-                best, best_rate = cand, rate
-        if best is None:
-            # nothing validated/measured: cache the DEFAULT as this
-            # device's point so every later init doesn't re-pay the
-            # full failed sweep ("only the first init pays" must hold
-            # exactly where the sweep is most expensive); gbps 0.0
-            # marks it as a failure sentinel, and --force re-sweeps
-            best, best_rate = default_point(), 0.0
-        cache["entries"][key] = {**best,
-                                 "gbps": round(best_rate / 1e9, 3),
-                                 "when": time.strftime(
-                                     "%Y-%m-%dT%H:%M:%S")}
-        _save_cache(cache)
-        return best
+        err = validate(mat, bitmat32, cand)
+        if err is not None:
+            yield cand, None, err, time.perf_counter() - t0
+            if cand == default_point():
+                raise RuntimeError(
+                    f"default fused point {cand} failed on "
+                    f"{device.describe()['kind']}: {err}")
+            continue
+        rate = measure(bitmat32, k, m, cand)
+        yield cand, rate, None, time.perf_counter() - t0
+
+
+def winner(report: list) -> dict:
+    """The points-file entry a sweep's report selects: of the valid
+    candidates whose rate is within RATE_TIE of the fastest, the one
+    that was cheapest to compile.  The entry keeps the fastest rate
+    seen (`best_gbps`) beside its own, so the trade stays readable."""
+    timed = [(rate, wall, cand) for cand, rate, _, wall in report
+             if rate]
+    if not timed:
+        raise RuntimeError(f"no candidate could be timed: {report}")
+    import jax
+    best_rate = max(rate for rate, _, _ in timed)
+    rate, wall, cand = min(
+        (t for t in timed if t[0] >= (1 - RATE_TIE) * best_rate),
+        key=lambda t: t[1])
+    return {**cand, "gbps": round(rate / 1e9, 3),
+            "best_gbps": round(best_rate / 1e9, 3),
+            "compile_wall_s": round(wall, 1), "jax": jax.__version__,
+            "when": time.strftime("%Y-%m-%dT%H:%M:%S")}
+
+
+def write_point(k: int, m: int, entry: dict) -> None:
+    """Record a sweep's winner for this device kind in POINTS_FILE (the
+    builder commits the file)."""
+    data = json.loads(POINTS_FILE.read_text())
+    data.setdefault(device.describe()["kind"], {})[f"k{k}m{m}"] = entry
+    POINTS_FILE.write_text(json.dumps(data, indent=1, sort_keys=True)
+                           + "\n")
+    _load_points.cache_clear()

@@ -35,14 +35,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # pltpu imports fail off-TPU for some symbols; guard for CPU tests
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 from ..common.util import next_pow2
 from ..ec import gf
+from . import device
 
 LANE = 128           # TPU lane width: byte-axis tiles must be multiples
 DEFAULT_TILE = 8192  # bytes of each chunk processed per grid step
@@ -51,9 +48,10 @@ DEFAULT_TILE = 8192  # bytes of each chunk processed per grid step
 def _parallel_grid(n_dims: int, interpret: bool):
     """compiler_params marking every grid axis parallel: byte-axis grid
     steps are independent, and telling Mosaic so lets it double-buffer
-    across steps (measured: up to ~1.7x encode on v5e vs the default
-    sequential assumption; see BASELINE.md round-3 notes)."""
-    if interpret or pltpu is None:
+    across steps (a pre-PR-1 kernel-only reading put it at up to
+    ~1.7x encode on v5e vs the default sequential assumption; not
+    re-measured since)."""
+    if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel",) * n_dims)}
@@ -250,7 +248,7 @@ def _make_gf_kernel_w32_stream(interpret: bool, k: int, g: int):
     (a&1)^(b&1) over GF(2), so the accumulator is i8).  Neither the
     full concatenated (32k, W) plane buffer (8x the input tile) nor
     more than one group's matmul product is ever live — the VMEM cut
-    the BASELINE.md tile-sweep finding calls for.  (An unrolled
+    larger tiles need.  (An unrolled
     in-kernel sum chain OOMs VMEM — every partial stays allocated on
     the kernel stack — and lax.dynamic_slice on the matrix doesn't
     lower in Pallas TPU, so the grid axis IS the plane loop.)"""
@@ -326,9 +324,6 @@ def gf_bitmatmul_pallas_w32(bitmat32: jnp.ndarray, words: jnp.ndarray,
         raise ValueError(
             f"streaming w32 kernel needs 128 %% (4k) == 0 (k={k}); "
             "use stream=False")
-    if pltpu is None:
-        raise ValueError("streaming w32 kernel unavailable: "
-                         "pallas tpu module not importable")
     scratch = pltpu.VMEM((32 * r, wt), jnp.int8)
     return pl.pallas_call(
         _make_gf_kernel_w32_stream(interpret, k, g),
@@ -439,8 +434,7 @@ def _make_gf_crc_kernel_w32(interpret: bool):
         """w32 twin of _gf_crc_kernel: word-packed unpack feeds the MXU
         parity matmul AND the crc32c L-vector matmul from the same VMEM
         residency — the north-star fusion at the headline kernel's
-        speed (the byte-path fused kernel runs ~4x slower, VERDICT
-        round-1 Weak #1)."""
+        speed (the byte-path fused kernel runs ~4x slower)."""
         from . import crc32c_linear as cl
         w = in_ref[:]                                  # (k, Wt) i32
         par_words = _w32_parity_words(bitmat_ref[:], w, interpret)
@@ -494,7 +488,7 @@ FUSED_TILE_HIER = W32_TILE   # hier matrices are tile-size-independent
 
 
 def _hier_crc_step(bitmat_ref, cmat_sub_ref, in_ref, par_ref, wb: int,
-                   extract: str, interpret: bool):
+                   interpret: bool):
     """Shared per-grid-step body of the hier fused kernels: parity +
     per-sub-block L-bits, with the crc extraction OVERLAPPED against
     the parity MXU work instead of run as a tail.
@@ -511,33 +505,28 @@ def _hier_crc_step(bitmat_ref, cmat_sub_ref, in_ref, par_ref, wb: int,
     result is unchanged (shard*S + si, data shards first)."""
     from . import crc32c_linear as cl
     w = in_ref[:]                                      # (k, Wt) i32
-    lsub_data = cl.subblock_crc_bits_w32_extract(
-        w, cmat_sub_ref[:], wb, extract, interpret)    # (k*S, 32)
+    lsub_data = cl.subblock_crc_bits_w32(
+        w, cmat_sub_ref[:], wb)                        # (k*S, 32)
     par_words = _w32_parity_words(bitmat_ref[:], w, interpret)
     par_ref[:] = par_words
-    lsub_par = cl.subblock_crc_bits_w32_extract(
-        par_words, cmat_sub_ref[:], wb, extract, interpret)  # (m*S, 32)
+    lsub_par = cl.subblock_crc_bits_w32(
+        par_words, cmat_sub_ref[:], wb)                # (m*S, 32)
     return jnp.concatenate([lsub_data, lsub_par], axis=0)
 
 
-def _make_gf_crc_kernel_w32_hier(interpret: bool, wb: int,
-                                 extract: str = "planar"):
+def _make_gf_crc_kernel_w32_hier(interpret: bool, wb: int):
     def _kern(bitmat_ref, cmat_sub_ref, in_ref, par_ref, lsub_ref):
         """Fused parity + level-1 hierarchical crc at the headline
         kernel's tile: the same VMEM-resident words feed the MXU parity
         matmul and the sub-block crc matmuls (see
         crc32c_linear.subblock_crc_bits_w32 for why the flat crc matmul
-        capped the fused tile at 2 KiB).  `extract` selects the crc
-        bit-extraction variant (planar / packed / wide) — non-planar
-        variants are autotune-gated, as their strided sublane slice is
-        generation-dependent in Mosaic."""
+        capped the fused tile at 2 KiB)."""
         lsub_ref[:] = _hier_crc_step(bitmat_ref, cmat_sub_ref, in_ref,
-                                     par_ref, wb, extract, interpret)
+                                     par_ref, wb, interpret)
     return _kern
 
 
-def _make_gf_crc_kernel_w32_hier_acc(interpret: bool, wb: int,
-                                     extract: str):
+def _make_gf_crc_kernel_w32_hier_acc(interpret: bool, wb: int):
     """The VMEM-resident L accumulator kernel (the tentpole of the
     overlapped fused path): instead of writing every grid step's
     (r*S, 32) sub-block L-block to HBM and re-laying it out in XLA
@@ -567,7 +556,7 @@ def _make_gf_crc_kernel_w32_hier_acc(interpret: bool, wb: int,
               in_ref, par_ref, lacc_ref):
         t = pl.program_id(0)
         lsub = _hier_crc_step(bitmat_ref, cmat_sub_ref, in_ref,
-                              par_ref, wb, extract, interpret)
+                              par_ref, wb, interpret)
 
         @pl.when(first_ref[t] == 1)
         def _init():
@@ -584,7 +573,7 @@ def _make_gf_crc_kernel_w32_hier_acc(interpret: bool, wb: int,
 
 
 def _fused_hier_call(bitmat32, cmat_sub, words, m: int, tile: int,
-                     wb: int, interpret: bool, extract: str = "planar"):
+                     wb: int, interpret: bool):
     """Raw pallas_call of the hier fused kernel over a byte-axis grid
     with double-buffered input blocks (the `parallel` dimension
     semantics let Mosaic overlap each block's HBM->VMEM DMA with the
@@ -603,7 +592,7 @@ def _fused_hier_call(bitmat32, cmat_sub, words, m: int, tile: int,
     assert (r * s) % 8 == 0, (r, s)     # lsub out-block sublane align
     grid = (wtot // wt,)
     return pl.pallas_call(
-        _make_gf_crc_kernel_w32_hier(interpret, wb, extract),
+        _make_gf_crc_kernel_w32_hier(interpret, wb),
         grid=grid,
         in_specs=[
             pl.BlockSpec((32 * m, 32 * k), lambda t: (0, 0)),
@@ -625,7 +614,7 @@ def _fused_hier_call(bitmat32, cmat_sub, words, m: int, tile: int,
 
 def _fused_hier_acc_call(bitmat32, cmat_sub, adv, run_map, first_map,
                          words, m: int, tile: int, wb: int, nruns: int,
-                         interpret: bool, extract: str):
+                         interpret: bool):
     """Raw pallas_call of the accumulator hier kernel: sequential
     byte-axis grid, per-run VMEM-resident L accumulation (see
     _make_gf_crc_kernel_w32_hier_acc).  run_map/first_map are (ntiles,)
@@ -640,9 +629,6 @@ def _fused_hier_acc_call(bitmat32, cmat_sub, adv, run_map, first_map,
     s = wt // wb
     r = k + m
     assert (r * s) % 8 == 0, (r, s)     # lacc out-block sublane align
-    if pltpu is None:
-        raise ValueError("accumulator hier kernel unavailable: "
-                         "pallas tpu module not importable")
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(wtot // wt,),
@@ -658,7 +644,7 @@ def _fused_hier_acc_call(bitmat32, cmat_sub, adv, run_map, first_map,
         ],
     )
     return pl.pallas_call(
-        _make_gf_crc_kernel_w32_hier_acc(interpret, wb, extract),
+        _make_gf_crc_kernel_w32_hier_acc(interpret, wb),
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((m, wtot), jnp.int32),
@@ -694,7 +680,7 @@ def _acc_launch_args(ntiles_run, tile: int, wb: int):
 
 def _hier_acc_core(bitmat32, cmat_sub, adv, combine, run_map, first_map,
                    words, m: int, tile: int, wb: int, nruns: int,
-                   interpret: bool, extract: str):
+                   interpret: bool):
     """Accumulator launch + the per-run si-position fold: returns
     (parity (m, W) i32, L-bits (nruns, k+m, 32) i32 — one combined L
     per shard per run, covering every byte of the run including the
@@ -707,17 +693,17 @@ def _hier_acc_core(bitmat32, cmat_sub, adv, combine, run_map, first_map,
     s = (tile // 4) // wb
     parity, lacc = _fused_hier_acc_call(
         bitmat32, cmat_sub, adv, run_map, first_map, words, m, tile,
-        wb, nruns, interpret, extract)
+        wb, nruns, interpret)
     return parity, cl.combine_subblock_crcs(lacc, combine, k + m, s)
 
 
 _hier_acc = functools.partial(jax.jit, static_argnames=(
-    "m", "tile", "wb", "nruns", "interpret", "extract"))(_hier_acc_core)
+    "m", "tile", "wb", "nruns", "interpret"))(_hier_acc_core)
 
 # donated twin (see _fused_hier_lsub_donate): the staged drain words
 # are single-use, so real accelerators may reuse their HBM for parity
 _hier_acc_donate = functools.partial(jax.jit, static_argnames=(
-    "m", "tile", "wb", "nruns", "interpret", "extract"),
+    "m", "tile", "wb", "nruns", "interpret"),
     donate_argnums=(6,))(_hier_acc_core)
 
 
@@ -751,22 +737,19 @@ def gf_encode_with_crc_pallas_w32_hier(bitmat32, cmat_sub, combine,
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tile", "wb",
-                                             "interpret", "extract",
-                                             "combine"))
+                                             "interpret", "combine"))
 def gf_encode_with_crc_w32_fold(bitmat32, cmat_sub, words, m: int,
                                 tile: int = FUSED_TILE_HIER,
                                 wb: int = FUSED_WB,
                                 interpret: bool = False,
-                                extract: str = "planar",
                                 combine: str = "xla"):
     """The device-side-combine fused launch: parity AND one 32-bit
     crc32c L-vector per shard from a single dispatch.
 
     words (k, W) i32, W bytes a `tile` multiple; cmat_sub from
     crc_tile_matrix_w32(wb).  Returns (parity (m, W) i32, L-bits
-    (k+m, 32) i32).  `extract` picks the crc bit-extraction variant
-    (planar/packed/wide) and `combine` the combine depth — both
-    autotuner axes:
+    (k+m, 32) i32).  `combine` picks the combine depth (an axis of
+    the operating-point sweep, ops/autotune.py):
 
       * combine="kernel": the accumulator kernel folds per-tile Ls in
         VMEM across grid steps (A_tile advance matmul per step, see
@@ -785,12 +768,12 @@ def gf_encode_with_crc_w32_fold(bitmat32, cmat_sub, words, m: int,
             [wtot // (tile // 4)], tile, wb)
         parity, lb = _hier_acc_core(
             bitmat32, cmat_sub, adv, comb, run_map, first_map, words,
-            m, tile, wb, 1, interpret, extract)
+            m, tile, wb, 1, interpret)
         return parity, lb[0]
     if combine != "xla":
         raise ValueError(f"unknown combine depth {combine!r}")
     parity, lb = _hier_lsub_core(bitmat32, cmat_sub, words, m,
-                                 tile, wb, interpret, extract)
+                                 tile, wb, interpret)
     # fold the whole extent's sub-block Ls in log2(nsub) matmuls
     return parity, cl.combine_crcs_pow2(lb, 4 * wb)
 
@@ -827,7 +810,7 @@ def gf_encode_with_crc_xla(bitmat, cmat, chunks, m: int,
 
 
 def _hier_lsub_core(bitmat32, cmat_sub, words, m: int, tile: int,
-                    wb: int, interpret: bool, extract: str):
+                    wb: int, interpret: bool):
     """Hier launch + re-layout: (parity, per-sub-block L-bits reordered
     [tile, shard, sub] -> (k+m, total_sub_blocks, 32) stream order).
     Shared by the single-extent fold entry and the extents path."""
@@ -837,21 +820,21 @@ def _hier_lsub_core(bitmat32, cmat_sub, words, m: int, tile: int,
     r = k + m
     nt = wtot // wt
     parity, lsub = _fused_hier_call(bitmat32, cmat_sub, words, m,
-                                    tile, wb, interpret, extract)
+                                    tile, wb, interpret)
     lb = lsub.reshape(nt, r, s, 32).transpose(1, 0, 2, 3) \
         .reshape(r, nt * s, 32)
     return parity, lb
 
 
 _fused_hier_lsub = functools.partial(jax.jit, static_argnames=(
-    "m", "tile", "wb", "interpret", "extract"))(_hier_lsub_core)
+    "m", "tile", "wb", "interpret"))(_hier_lsub_core)
 
 # donated twin for the dispatch-ahead pipeline: the staged device input
 # words are single-use (one drain's concatenated runs), so XLA may
 # reuse their HBM for the parity output instead of allocating fresh —
 # only selected on real accelerators (CPU ignores donation and warns)
 _fused_hier_lsub_donate = functools.partial(jax.jit, static_argnames=(
-    "m", "tile", "wb", "interpret", "extract"),
+    "m", "tile", "wb", "interpret"),
     donate_argnums=(2,))(_hier_lsub_core)
 
 
@@ -861,7 +844,6 @@ def gf_encode_extents_with_crc(bitmat, bitmat32, runs, m: int,
                                interpret: bool = False,
                                tile: int | None = None,
                                wb: int | None = None,
-                               extract: str = "planar",
                                combine: str = "xla"):
     """Multi-extent fused launch: parity + ONE device-combined crc
     L-vector per shard per run, for a whole pipeline drain in one
@@ -878,10 +860,9 @@ def gf_encode_extents_with_crc(bitmat, bitmat32, runs, m: int,
     padding is benign for parity (linear code) and the padded block's
     L-row is simply unused.
 
-    `tile`/`wb`/`extract`/`combine` override the hier kernel's
-    operating point (fed by ops/autotune via the plugin); defaults
-    keep the static FUSED_TILE_HIER/FUSED_WB constants with the
-    planar/xla variants.
+    `tile`/`wb`/`combine` override the hier kernel's operating point
+    (fed by ops/autotune via the plugin); defaults keep the static
+    FUSED_TILE_HIER/FUSED_WB constants with the XLA combine.
 
     Returns a list of (parity (m, Wi) uint8, l (k+m,) uint32 over the
     run's body, tail_bytes (k+m, tail_len) uint8, body_bytes) per run —
@@ -894,7 +875,7 @@ def gf_encode_extents_with_crc(bitmat, bitmat32, runs, m: int,
         gf_encode_extents_with_crc_submit(
             bitmat, bitmat32, runs, m, use_w32=use_w32,
             force_xla=force_xla, interpret=interpret, tile=tile,
-            wb=wb, extract=extract, combine=combine))
+            wb=wb, combine=combine))
 
 
 def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
@@ -903,7 +884,6 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
                                       interpret: bool = False,
                                       tile: int | None = None,
                                       wb: int | None = None,
-                                      extract: str = "planar",
                                       combine: str = "xla",
                                       donate: bool | None = None):
     """Dispatch half of gf_encode_extents_with_crc: stages the drain's
@@ -923,11 +903,11 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         # the backend still counts the drain as kernel-served
         raise ValueError(f"unknown combine depth {combine!r}")
     if force_xla is None:
-        force_xla = jax.default_backend() == "cpu"
+        force_xla = device.on_cpu()
     if use_w32 is None:
         use_w32 = not force_xla
     if donate is None:
-        donate = jax.default_backend() != "cpu"
+        donate = not device.on_cpu()
     runs = [np.ascontiguousarray(r, dtype=np.uint8) for r in runs]
     k = runs[0].shape[0]
     assert all(r.shape[0] == k for r in runs), \
@@ -954,7 +934,7 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
                     bitmat, bitmat32, [runs[i] for i in idxs], m,
                     use_w32=use_w32, force_xla=force_xla,
                     interpret=interpret, tile=tile, wb=wb,
-                    extract=extract, combine=combine, donate=donate)))
+                    combine=combine, donate=donate)))
             return {"split": parts, "n_runs": len(runs),
                     "path": "+".join(h["path"] for _, h in parts)}
     # operating point: big sequential drains ride the hier-crc kernel at
@@ -1047,8 +1027,7 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
             (bitmat32, cmat_sub, adv, comb, run_map, first_map,
              jnp.asarray(words)),
             {"m": m, "tile": tile, "wb": wb, "nruns": nruns_acc,
-             "interpret": interpret,
-             "extract": extract})                      # (nruns, r, 32)
+             "interpret": interpret})                  # (nruns, r, 32)
         lbits_devs = [lb[i] for i in range(len(runs))]
         block_bytes = 4 * wb
         w32_out = True
@@ -1060,8 +1039,8 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         parity_dev, lb_all = _aot_dispatch(
             "hier_lsub_donate" if donate else "hier_lsub", hier_fn,
             (bitmat32, cmat_sub, jnp.asarray(words)),
-            {"m": m, "tile": tile, "wb": wb, "interpret": interpret,
-             "extract": extract})                      # (r, nsub, 32)
+            {"m": m, "tile": tile, "wb": wb,
+             "interpret": interpret})                  # (r, nsub, 32)
         block_bytes = 4 * wb
         w32_out = True
         path = "hier_lsub"
@@ -1174,8 +1153,7 @@ def gf_bitmatmul(bitmat: jnp.ndarray, chunks: jnp.ndarray, r: int,
     multiple and strips the pad (zero bytes encode to zero parity, so
     padding is benign for linear codes)."""
     k, n = chunks.shape
-    use_xla = force_xla if force_xla is not None \
-        else jax.default_backend() == "cpu"
+    use_xla = force_xla if force_xla is not None else device.on_cpu()
     npad = -n % LANE
     if npad:
         chunks = jnp.pad(chunks, ((0, 0), (0, npad)))

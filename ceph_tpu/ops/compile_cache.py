@@ -1,28 +1,29 @@
 """Persistent XLA compile cache: one compile per host LIFETIME.
 
-PR 15's flight recorder made compile stalls visible; this module (with
-ops/prewarm.py) removes them.  JAX's persistent compilation cache
-serializes every compiled executable to disk keyed by the (HLO,
-compile options, backend) fingerprint, so a RESTARTED daemon re-traces
-its jit buckets but never re-compiles them — the multi-second XLA/
-Mosaic compile that used to flap heartbeats on every revive becomes a
-millisecond disk read.  The cache directory sits alongside the
-autotune v2 cache under ~/.cache/ceph_tpu/ and is configured through
-`osd_ec_compile_cache_dir` (conf, not env-only; the CEPH_TPU_* env
-layer of common/options.py reaches it anyway).
+JAX's persistent compilation cache serializes every compiled
+executable to disk keyed by the (HLO, compile options, backend)
+fingerprint, so a RESTARTED daemon re-traces its jit buckets but never
+re-compiles them — the multi-second XLA/Mosaic compile that used to
+flap heartbeats on every revive becomes a millisecond disk read.
 
-Hit/miss attribution rides jax.monitoring: the backend records a
-'/jax/compilation_cache/cache_hits' event every time a compile is
-served from disk.  This module keeps a process-global hit counter;
-the flight recorder (ops/profiler.py) snapshots it around each
+Placement is decided OUTSIDE the program.  When
+`JAX_COMPILATION_CACHE_DIR` is set JAX already uses that directory and
+this module sets no other; when it is unset the cache lives at one
+fixed path inside the checkout (`.jax_cache/` beside `native/`,
+git-ignored).  The path is part of the cache key's neighbourhood — a
+directory that moves between runs never hits — so there is no option,
+no program-specific environment variable and no temp name.
+
+Hit/miss attribution rides jax.monitoring: the backend records
+'/jax/compilation_cache/cache_hits' when a compile is served from
+disk, '/jax/compilation_cache/cache_misses' when it was compiled and
+stored, and a '/jax/core/compile/backend_compile_duration' duration
+around both.  This module keeps process-global counters; the flight
+recorder (ops/profiler.py) snapshots the hit counter around each
 first-seen submit, so a persistent-cache hit records as a fast
 first-launch with `cache_hit: true` in the launch ledger — NOT as a
-compile stall (before this PR the two were indistinguishable).
-
-Everything degrades gracefully: a jax without the persistent cache
-knobs, an unwritable directory, or a backend that never emits the
-monitoring events leaves the module disabled and every query cheap
-(`enabled()` one bool, `hit_count()` one int).
+compile stall — and chip_smoke.py deltas `counters()` around its
+serving window to count compilations that happened inside it.
 """
 
 from __future__ import annotations
@@ -31,92 +32,79 @@ import os
 import threading
 from pathlib import Path
 
+ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
+# resolved from __file__ like common/native.py's library path: the
+# same checkout always gets the same directory
+CHECKOUT_DIR = Path(__file__).resolve().parent.parent.parent / ".jax_cache"
+
 _lock = threading.Lock()
 _enabled = False
 _dir: str | None = None
-_error: str | None = None
-_listener_ok = False
-# process-global persistent-cache hit counter (bumped by the
-# jax.monitoring listener; int reads are atomic under the GIL, so the
-# profiler's per-launch snapshots never take _lock)
+# process-global counters (bumped by the jax.monitoring listeners; int
+# reads are atomic under the GIL, so the profiler's per-launch
+# snapshots never take _lock)
 _hits = 0
-
-
-def default_cache_dir() -> Path:
-    """~/.cache/ceph_tpu/xla — beside the autotune v2 cache
-    (ops/autotune._cache_path), honoring the same style of env
-    override for hermetic CI."""
-    env = os.environ.get("CEPH_TPU_COMPILE_CACHE")
-    if env:
-        return Path(env)
-    return Path.home() / ".cache" / "ceph_tpu" / "xla"
+_misses = 0
+_requests = 0
+_compile_s = 0.0
 
 
 def _on_event(event: str, **kw) -> None:
-    global _hits
-    if "compilation_cache" in event and "hit" in event:
+    global _hits, _misses
+    if event == "/jax/compilation_cache/cache_hits":
         _hits += 1
+    elif event == "/jax/compilation_cache/cache_misses":
+        _misses += 1
 
 
-def enable(cache_dir: str | os.PathLike | None = None) -> bool:
-    """Point jax's persistent compilation cache at `cache_dir`
-    (default: default_cache_dir()) and register the hit listener.
-    Idempotent per process — the first caller's directory wins (one
-    cache per host, like the mesh shape); returns whether the cache is
-    live.  Must run before the first jit COMPILE to cover it, but is
-    safe (and still effective for later compiles) at any point."""
-    global _enabled, _dir, _error, _listener_ok
+def _on_duration(event: str, duration: float, **kw) -> None:
+    global _requests, _compile_s
+    if event == "/jax/core/compile/backend_compile_duration":
+        _requests += 1
+        _compile_s += duration
+
+
+def enable() -> str:
+    """Turn the persistent cache on for this process and register the
+    listeners; returns the directory in use.  Idempotent.  Must run
+    before the first jit COMPILE to cover it, but is safe (and still
+    effective for later compiles) at any point."""
+    global _enabled, _dir
     with _lock:
         if _enabled:
-            return True
-        path = Path(cache_dir) if cache_dir else default_cache_dir()
-        try:
-            path.mkdir(parents=True, exist_ok=True)
-        except OSError as e:
-            _error = f"mkdir {path}: {e}"
-            return False
-        try:
-            import jax
-            jax.config.update("jax_compilation_cache_dir", str(path))
-            # jax memoizes "is the cache in use" at the process's FIRST
-            # compile (compilation_cache._cache_checked); if anything
-            # compiled before enable() — a test, an import-time trace —
-            # that latch reads "disabled" forever.  Drop it so the next
+            return _dir
+        import jax
+        path = os.environ.get(ENV_DIR)
+        if path:
+            # placed from outside: jax read the variable at import;
+            # setting the config here would override the caller
+            if jax.config.jax_compilation_cache_dir != path:
+                raise RuntimeError(
+                    f"{ENV_DIR}={path!r} was set after jax was "
+                    f"imported (jax uses "
+                    f"{jax.config.jax_compilation_cache_dir!r})")
+        else:
+            path = str(CHECKOUT_DIR)
+            jax.config.update("jax_compilation_cache_dir", path)
+            # jax memoizes "is the cache in use" at the process's
+            # FIRST compile; if anything compiled before enable() that
+            # latch reads "disabled" forever.  Drop it so the next
             # compile re-evaluates against the directory just set.
-            try:
-                from jax._src import compilation_cache as _jcc
-                _jcc.reset_cache()
-            except Exception:  # noqa: BLE001 — private API; best-effort
-                pass
-            # daemon workloads are many SMALL programs: cache every
-            # compile regardless of size or compile time (the defaults
-            # skip sub-second compiles — exactly the ones whose sum
-            # makes a revive storm)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception as e:  # noqa: BLE001 — old jax / no knob
-            _error = f"jax persistent cache unavailable: {e!r}"
-            return False
-        try:
-            from jax._src import monitoring
-            monitoring.register_event_listener(_on_event)
-            _listener_ok = True
-        except Exception:  # noqa: BLE001 — hit attribution degrades,
-            _listener_ok = False       # the cache itself still works
+            from jax._src import compilation_cache as _jcc
+            _jcc.reset_cache()
+        Path(path).mkdir(parents=True, exist_ok=True)
+        # daemon workloads are many SMALL programs: cache every
+        # compile regardless of size or compile time (the defaults
+        # skip sub-second compiles — exactly the ones whose sum makes
+        # a revive storm)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          0.0)
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
         _enabled = True
-        _dir = str(path)
-        _error = None
-        return True
-
-
-def enabled() -> bool:
-    return _enabled
-
-
-def cache_dir() -> str | None:
-    return _dir
+        _dir = path
+        return path
 
 
 def hit_count() -> int:
@@ -125,33 +113,24 @@ def hit_count() -> int:
     return _hits
 
 
+def counters() -> dict:
+    """Monotonic compile counters of this process: `requests` backend
+    compile requests (each is a `hits` disk read or a `misses` real
+    compile) and the wall seconds they took together."""
+    return {"requests": _requests, "hits": _hits, "misses": _misses,
+            "compile_s": round(_compile_s, 3)}
+
+
 def status() -> dict:
     """The `prewarm status` / `compile ledger` asok block."""
-    out = {
-        "enabled": _enabled,
-        "dir": _dir,
-        "hits": _hits,
-        "hit_listener": _listener_ok,
-    }
-    if _error:
-        out["error"] = _error
-    if _enabled and _dir:
+    out = {"enabled": _enabled, "dir": _dir,
+           "placed_by": "env" if os.environ.get(ENV_DIR) else "checkout",
+           **counters()}
+    if _enabled:
         try:
             files = [f for f in Path(_dir).iterdir() if f.is_file()]
             out["entries"] = len(files)
             out["bytes"] = sum(f.stat().st_size for f in files)
-        except OSError:
+        except OSError:     # jax renames entries into place under us
             pass
     return out
-
-
-def reset_for_tests() -> None:
-    """Tests only: forget the enabled state so a test can re-point the
-    cache at its own tmpdir.  jax's own config keeps the LAST enabled
-    directory until the next enable() — callers pair this with
-    jax.clear_caches() when simulating a daemon restart."""
-    global _enabled, _dir, _error
-    with _lock:
-        _enabled = False
-        _dir = None
-        _error = None

@@ -210,7 +210,9 @@ def crc32c_rows_device(row_list, seeds,
     their power-of-two size bucket (L(0^n || B) = L(B), so prefix
     zeros are free AND the pow2 rounding bounds the jit-cache key
     space), one launch per bucket emits one L per row, and the host
-    pays one seed-advance + tail fold per row (fold_run_crc)."""
+    pays one seed-advance + tail fold per row (fold_run_crc).  Each
+    launch's row count is padded to a power of two as well, so the jit
+    key space is ~log2(rows) x log2(width)."""
     import jax
     import jax.numpy as jnp
     global _rows_l_jit
@@ -235,13 +237,17 @@ def crc32c_rows_device(row_list, seeds,
             buckets.setdefault(next_pow2(nb), []).append(i)
     for nb2, idxs in sorted(buckets.items()):
         w = block_bytes * nb2
-        mat = np.zeros((len(idxs), w), dtype=np.uint8)
+        # the row COUNT is a jit axis too: pad it to a power of two
+        # with zero rows (their L is never read) so a scrub of PGs
+        # holding 11, 22, 33... shards compiles ~log2 programs per
+        # width instead of one per distinct count
+        mat = np.zeros((next_pow2(len(idxs)), w), dtype=np.uint8)
         for j, i in enumerate(idxs):
             mat[j, w - bodies[i]:] = rows[i][:bodies[i]]
         words = mat.view("<u4").view(np.int32)
         cmat_sub = jnp.asarray(crc_tile_matrix_w32(wb))
         lbits = _rows_l_jit(jnp.asarray(words), cmat_sub, wb)
-        ls[idxs] = bits_to_u32(np.asarray(lbits))
+        ls[idxs] = bits_to_u32(np.asarray(lbits))[:len(idxs)]
     return [fold_run_crc(int(ls[i]), bodies[i], int(seeds[i]),
                          rows[i][bodies[i]:].tobytes())
             for i in range(len(rows))]
@@ -287,105 +293,6 @@ def subblock_crc_bits_w32(words, cmat_sub, wb: int):
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
     return acc & 1
-
-
-def subblock_crc_bits_w32_packed(words, cmat_sub, wb: int,
-                                 interpret: bool = False):
-    """Packed-extraction twin of subblock_crc_bits_w32: same output,
-    1/4 the VPU bit-extraction work.
-
-    The planar variant extracts the 32 word-bits one at a time (32
-    shift+mask passes over the full (r*S, wb) block).  Here the crc
-    reuses the parity path's packed-mask trick: `(w >> i) & 0x01010101`
-    pulls bit i of all FOUR bytes per word in one pass, and the free
-    Mosaic sublane bitcast exposes them as byte rows — 8 passes total.
-    The bitcast row 4q+b holds bit i of byte b of sub-block q, i.e.
-    word-bit 8b+i, whose crc contribution at word position t is
-    cmat_sub row (8b+i)*wb + t: de-interleaving the byte offset with a
-    strided sublane slice and re-stacking the four slices along the
-    contraction axis makes the matmul shapes identical to the planar
-    variant ((r*S, 4wb) x (4wb, 32) per bit-of-byte i).
-
-    The strided sublane slice is the lowering risk (Mosaic support for
-    stride-4 second-minor slices varies by generation), so this
-    variant is only selected by the autotuner after a bit-exactness
-    check against the host crc on real hardware."""
-    import jax
-    import jax.numpy as jnp
-    from .bitsliced import _words_to_bytes
-    r, wt = words.shape
-    s = wt // wb
-    w2 = words.reshape(r * s, wb)
-    mask = jnp.int32(0x01010101)
-    acc = jnp.zeros((r * s, 32), dtype=jnp.int32)
-    for i in range(8):
-        plane = _words_to_bytes((w2 >> i) & mask, interpret)  # (4rS, wb)
-        cat = jnp.concatenate(
-            [plane[b::4] for b in range(4)], axis=1)          # (rS, 4wb)
-        ccat = jnp.concatenate(
-            [cmat_sub[(8 * b + i) * wb:(8 * b + i + 1) * wb]
-             for b in range(4)], axis=0)                      # (4wb, 32)
-        acc = acc + jax.lax.dot_general(
-            cat, ccat,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-    return acc & 1
-
-
-def subblock_crc_bits_w32_wide(words, cmat_sub, wb: int,
-                               interpret: bool = False):
-    """Widest extraction variant: the mask drops out entirely — 8
-    shift-only passes (pass 0 is the raw words), half the packed
-    variant's VPU work and a quarter of planar's.
-
-    Why no mask is needed: the matmul already reduces mod 2 (`& 1`
-    after int32 accumulation), and every non-LSB bit of an operand
-    byte contributes an EVEN multiple (bit p of a byte weighs 2^p in
-    the int8 product), so it self-cancels.  Byte b of `(w >> i)` holds
-    word-bit 8b+i in its LSB plus junk above it; matching it against
-    cmat_sub's rows for bit 8b+i therefore yields exactly that
-    bit-plane's contribution mod 2.  Signed int8 wrap (bytes >= 0x80
-    read as v-256) is a multiple of 256 — also even — and the int32
-    accumulator cannot overflow (|sum| <= 128 * 4wb * 8 passes << 2^31).
-
-    Same matmul shapes and strided sublane slice as the packed
-    variant, so it carries the same Mosaic-generation risk and ships
-    only through the autotuner's bit-exactness gate."""
-    import jax
-    import jax.numpy as jnp
-    from .bitsliced import _words_to_bytes
-    r, wt = words.shape
-    s = wt // wb
-    w2 = words.reshape(r * s, wb)
-    acc = jnp.zeros((r * s, 32), dtype=jnp.int32)
-    for i in range(8):
-        plane = _words_to_bytes(w2 >> i if i else w2, interpret)  # (4rS, wb)
-        cat = jnp.concatenate(
-            [plane[b::4] for b in range(4)], axis=1)              # (rS, 4wb)
-        ccat = jnp.concatenate(
-            [cmat_sub[(8 * b + i) * wb:(8 * b + i + 1) * wb]
-             for b in range(4)], axis=0)                          # (4wb, 32)
-        acc = acc + jax.lax.dot_general(
-            cat, ccat,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-    return acc & 1
-
-
-def subblock_crc_bits_w32_extract(words, cmat_sub, wb: int, extract: str,
-                                  interpret: bool = False):
-    """Single dispatch point for the level-1 crc extraction variants
-    (the autotuner's `extract` axis): "planar" (32 single-bit passes,
-    lowers everywhere), "packed" (4 bits per masked pass), "wide"
-    (mask-free, mod-2 junk cancellation).  Looked up at call time so
-    tests can substitute a deliberately-miscompiling variant."""
-    if extract == "packed":
-        return subblock_crc_bits_w32_packed(words, cmat_sub, wb, interpret)
-    if extract == "wide":
-        return subblock_crc_bits_w32_wide(words, cmat_sub, wb, interpret)
-    if extract != "planar":
-        raise ValueError(f"unknown crc extraction variant {extract!r}")
-    return subblock_crc_bits_w32(words, cmat_sub, wb)
 
 
 def combine_subblock_crcs(lsub, combine, r: int, s: int):

@@ -62,14 +62,18 @@ class PrewarmPlan:
     widths: total fused-drain byte widths (pow2 multiples of the flat
     fused tile); for each, every pow2 run count r with r <= W/tile is
     an entry (r runs of W/r bytes — the depth-r pipelined write-storm
-    shape).  plain_widths: plain (no-crc) encode widths.
-    decode_widths x decode_erasures: recovery/reconstruct shapes.
+    shape).  run_shapes: explicit fused drains (a tuple of per-run
+    byte widths each) IN PLACE of the widths x run_counts product,
+    for a caller that knows its object geometry (chip_smoke.py: n
+    concurrent 4 MiB writes are n runs of 512 KiB).  plain_widths:
+    plain (no-crc) encode widths.  decode_widths x decode_erasures:
+    recovery/reconstruct shapes.
     """
 
     def __init__(self, plugin, widths=None, run_counts=None,
                  plain_widths=None, decode_widths=None,
                  decode_erasures=None, budget_s: float = 8.0,
-                 profiler=None):
+                 profiler=None, run_shapes=None):
         from .bitsliced import FUSED_TILE
         self.plugin = plugin
         self.budget_s = float(budget_s)
@@ -109,17 +113,22 @@ class PrewarmPlan:
         for w, era in [(w, e) for w in sorted(set(decode_widths))
                        for e in decode_erasures]:
             entries.append(("d", int(w), tuple(era)))
-        for w in sorted(set(widths)):
-            for r in sorted(set(run_counts)):
-                if r >= 1 and w % r == 0 and w // r >= tile:
-                    entries.append(("x", (int(w // r),) * int(r)))
-                elif r == 1:
-                    entries.append(("x", (int(w),)))
+        if run_shapes is not None:
+            entries.extend(("x", tuple(int(w) for w in shape))
+                           for shape in run_shapes)
+        else:
+            for w in sorted(set(widths)):
+                for r in sorted(set(run_counts)):
+                    if r >= 1 and w % r == 0 and w // r >= tile:
+                        entries.append(("x", (int(w // r),) * int(r)))
+                    elif r == 1:
+                        entries.append(("x", (int(w),)))
         self.entries = entries
         self.status: dict = {
             "planned": len(entries), "done": 0, "skipped": 0,
             "truncated": False, "total_s": 0.0, "budget_s": self.budget_s,
             "compiles": 0, "cache_hits": 0, "buckets": [],
+            "errors": [],
         }
 
     # -- plan prediction (for tests / status, no execution) -------------
@@ -154,8 +163,8 @@ class PrewarmPlan:
                     f":r{next_pow2(max(1, len(run_ws)))}")
             point = getattr(plugin, "_fused_point", None)
             if point and getattr(plugin, "_use_w32", False):
-                base += (f":t{point.get('tile')}:wb{point.get('wb')}"
-                         f":{point.get('extract')}.{point.get('combine')}")
+                base += (f":t{point['tile']}:wb{point['wb']}"
+                         f":{point['combine']}")
             return [base]
         if kind == "c":
             w = entry[1]
@@ -220,7 +229,9 @@ class PrewarmPlan:
     def run(self) -> dict:
         """Execute the plan within budget; returns (and stores) the
         `prewarm status` dict.  Failures of individual entries are
-        counted and skipped — prewarm must never fail a boot."""
+        counted, kept in `errors` and skipped — prewarm must never
+        fail a boot, but a shape that does not compile must be
+        readable in `prewarm status` (chip_smoke.py fails on one)."""
         t0 = time.perf_counter()
         st = self.status
         for entry in self.entries:
@@ -233,8 +244,9 @@ class PrewarmPlan:
             te = time.perf_counter()
             try:
                 handle = self._run_entry(entry)
-            except Exception:  # noqa: BLE001 — warm what we can
+            except Exception as e:  # noqa: BLE001 — warm what we can
                 st["skipped"] += 1
+                st["errors"].append(f"{entry}: {e!r}"[:2000])
                 continue
             warm_s = time.perf_counter() - te
             buckets = self._buckets_of(entry, handle)
@@ -285,8 +297,7 @@ def last_status() -> dict | None:
 
 def reset_for_tests() -> None:
     """Tests only: allow another run_once (paired with
-    compile_cache.reset_for_tests + jax.clear_caches when simulating a
-    daemon restart)."""
+    jax.clear_caches when simulating a daemon restart)."""
     global _ran, _last_status
     with _guard_lock:
         _ran = False

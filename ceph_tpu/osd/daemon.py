@@ -473,17 +473,18 @@ class OSDDaemon:
             self._profiler.set_ring_size(
                 int(_tconf.get("osd_ec_profiler_ring")))
         # persistent XLA compile cache (ops/compile_cache.py, docs/
-        # PIPELINE.md "Compile lifecycle"): point jax at the on-disk
-        # cache BEFORE any jit compile this daemon triggers — a
-        # restarted daemon re-traces but never re-compiles.  One
-        # directory per host (first enabler wins, like the profiler
-        # perf owner); failures leave the cache off, never fail boot
+        # PIPELINE.md "Compile lifecycle"): turn the on-disk cache on
+        # BEFORE any jit compile this daemon triggers — a restarted
+        # daemon re-traces but never re-compiles.  The directory is
+        # JAX_COMPILATION_CACHE_DIR or the fixed in-checkout path.
         self._prewarm_status: dict | None = None
+        # platform facts (ops/device.py), filled and logged when this
+        # daemon first builds a jax-backed codec — never before, so a
+        # CPU-plugin daemon does not initialise a JAX backend
+        self._device: dict | None = None
         if bool(_tconf.get("osd_ec_compile_cache")):
             from ..ops import compile_cache
-            compile_cache.enable(
-                str(_tconf.get("osd_ec_compile_cache_dir") or "")
-                or None)
+            compile_cache.enable()
 
         def _apply_prof(_k=None, _v=None):
             p = self._profiler
@@ -2763,6 +2764,13 @@ class OSDDaemon:
                         pool.erasure_code_profile]
                     codec = ErasureCodePluginRegistry.instance().factory(
                         prof["plugin"], Profile(dict(prof)))
+                    if self._device is None and \
+                            getattr(codec, "jit_backed", False):
+                        from ..ops import device
+                        self._device = device.describe()
+                        self.cct.dout(
+                            "osd", 1, f"osd.{self.osd_id} EC data "
+                            f"plane runs on {self._device}")
                     k = codec.get_data_chunk_count()
                     sinfo = StripeInfo(pool.stripe_width,
                                        pool.stripe_width // k)
@@ -4020,6 +4028,7 @@ class OSDDaemon:
         out = {
             "osd": self.osd_id,
             "enabled": bool(self.cct.conf.get("osd_ec_prewarm")),
+            "device": self._device,
             "boot": self._prewarm_status or prewarm.last_status(),
             "host": self._profiler.prewarm_summary(),
             "persistent_cache": compile_cache.status(),

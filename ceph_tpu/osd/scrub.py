@@ -19,7 +19,7 @@ fan-out per object instead of n sequential RPCs, every object's reads
 in flight together), and every shard of the chunk is checksummed by ONE
 device launch (crc32c_linear.crc32c_rows_device — the same GF(2) L
 formulation the fused write kernel uses) instead of per-object host
-crc32c.  CPU-only platforms fall back to the host hash; the split is
+crc32c.  CPU-only processes hash on the host; the split is
 surfaced as scrub_device_bytes / scrub_host_bytes perf counters.
 
 Works against the ShardBackend seam, so the same code scrubs a local
@@ -80,13 +80,14 @@ class _ObjMeta:
 
 
 def _use_device_default() -> bool:
-    """Device crc only off the CPU backend (the formulation itself is
-    pure jnp and CPU-capable — tests force it — but on CPU-only
-    platforms the host table/native path is the faster fallback)."""
+    """Device crc only off the CPU platform (the formulation itself is
+    pure jnp and CPU-capable — tests force it — but on a CPU-only
+    process the host native path is the faster one).  The split is
+    visible per scrub as device_bytes / host_bytes."""
+    from ..ops import device
     try:
-        import jax
-        return jax.default_backend() != "cpu"
-    except Exception:  # noqa: BLE001 — no jax at all: host fallback
+        return not device.on_cpu()
+    except ImportError:     # numpy-only deployment (no jax extra)
         return False
 
 

@@ -1,8 +1,9 @@
 """Per-host EC launch queue: cross-PG continuous batching.
 
-The single-PG bench numbers (BENCH_r05: ~147 GB/s bare encode) come
-from large, full-occupancy device launches; a loaded OSD host with
-hundreds of post-split PGs issues hundreds of partial-occupancy
+The single-PG kernel numbers (the last record before PR 1 read
+~147 GB/s bare encode, device-resident) come from large,
+full-occupancy device launches; a loaded OSD host with hundreds of
+post-split PGs issues hundreds of partial-occupancy
 launches instead, because every ECBackend drains per-PG.  This module
 is the fix ROADMAP item 2 names: one per-device launch queue per host,
 owned by the same `MeshService` seam that already owns the device
@@ -634,7 +635,7 @@ class ECLaunchQueue:
                     else bigs[0]
                 sig = abs(hash(tuple(plugin.signature))) & 0xFFFFFF
                 bucket = f"r:{sig:x}:w{big.shape[1]}"
-                handle = ("np", np.asarray(plugin.apply(big)))
+                handle = ("np", np.asarray(plugin.apply_device(big)))
             elif kind == "d":
                 # recovery/reconstruct decode: erasure patterns match
                 # within a key, so the concatenated dense array decodes
@@ -728,7 +729,7 @@ class ECLaunchQueue:
                             s.runs)
                     elif kind == "r":
                         h = ("np", np.asarray(
-                            s.plugin.apply(s.runs[0])))
+                            s.plugin.apply_device(s.runs[0])))
                     elif kind == "d":
                         h = ("np", np.asarray(s.plugin.decode_chunks(
                             s.runs[0], list(s.extra))))

@@ -43,21 +43,10 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common.util import concat_columns, split_columns
 from ..ec import gf
-from ..ops import bitsliced
+from ..ops import bitsliced, device
 from ..ops.profiler import device_profiler
 
 LANE = bitsliced.LANE
-
-# jax.shard_map (with check_vma) landed after 0.4.x; older runtimes
-# expose it as jax.experimental.shard_map with the check_rep kwarg.
-# Same semantics for this module's use (the replication checker can't
-# statically infer the XOR-of-all_gather fold either way).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_NOCHECK = {"check_vma": False}
-else:  # pragma: no cover - exercised on older jax runtimes
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_NOCHECK = {"check_rep": False}
 
 
 def make_mesh(n_shard: int, n_data: int, devices=None) -> Mesh:
@@ -92,7 +81,7 @@ class DistributedStripeCodec:
                  use_w32: bool | None = None,
                  interpret: bool | None = None):
         self.k, self.m, self.mesh = k, m, mesh
-        on_cpu = jax.default_backend() == "cpu"
+        on_cpu = device.on_cpu()
         self.use_w32 = use_w32 if use_w32 is not None else not on_cpu
         self.interpret = interpret if interpret is not None else on_cpu
         self.n_shard = mesh.shape["shard"]
@@ -151,13 +140,13 @@ class DistributedStripeCodec:
             return functools.reduce(
                 jnp.bitwise_xor, [gath[i] for i in range(n_shard)])
 
-        # no-check: the checker can't statically infer that the
+        # check_vma off: the checker can't statically infer that the
         # XOR fold of an all_gather over 'shard' is 'shard'-replicated
         # (it is: every member folds the same gathered operands)
-        fn = jax.jit(_shard_map(
+        fn = jax.jit(jax.shard_map(
             local, mesh=self.mesh,
             in_specs=(P("shard", None, None), P("shard", "data")),
-            out_specs=P(None, "data"), **_SM_NOCHECK))
+            out_specs=P(None, "data"), check_vma=False))
         self._apply_cache[r] = fn
         return fn
 
@@ -429,13 +418,13 @@ class ClayRepairPlan:
 
     # -- host oracle ---------------------------------------------------------
 
-    # flight-recorder hint (ops/profiler.py): apply() runs the jitted
-    # XLA bitmatmul, so a first-seen width IS a compile
+    # flight-recorder hint (ops/profiler.py): apply_device() runs the
+    # jitted XLA bitmatmul, so a first-seen width IS a compile
     jit_backed = True
 
     def apply_host(self, rows: np.ndarray) -> np.ndarray:
         """(in_rows, W) helper rows -> (out_rows, W) rebuilt sub-chunk
-        rows via the host GF matvec (the fallback/oracle path)."""
+        rows via the host GF matvec (the tests' oracle)."""
         return gf.gf_matvec(self.matrix, rows)
 
     # -- single-device path (the launch-queue / smoke configuration) --------
@@ -453,14 +442,6 @@ class ClayRepairPlan:
         return np.asarray(bitsliced.gf_bitmatmul_xla(
             self._bitmat, jnp.asarray(rows), self.out_rows))
 
-    def apply(self, rows: np.ndarray) -> np.ndarray:
-        """Device contraction with host fallback (a dead/absent
-        accelerator must never fail a repair)."""
-        try:
-            return self.apply_device(rows)
-        except Exception:  # noqa: BLE001 — device unavailable
-            return self.apply_host(rows)
-
     def apply_batch(self, rows_list) -> list[np.ndarray]:
         """Batched single-device apply: objects' byte axes concatenate
         into one launch, results demux per object (the non-mesh analog
@@ -468,4 +449,4 @@ class ClayRepairPlan:
         if not rows_list:
             return []
         big, widths = concat_columns(rows_list)
-        return split_columns(self.apply(big), widths)
+        return split_columns(self.apply_device(big), widths)

@@ -243,8 +243,8 @@ def main(argv=None) -> int:
     ap.add_argument("--prewarm", action="store_true",
                     help="--scale only: boot with the jit-bucket "
                          "prewarm + persistent compile cache "
-                         "(CEPH_TPU_COMPILE_CACHE for a hermetic "
-                         "dir), drive an EC pool through the churn "
+                         "(JAX_COMPILATION_CACHE_DIR places "
+                         "it), drive an EC pool through the churn "
                          "with compile-stall injection armed, and "
                          "gate ec_compile_stalls == 0 / no "
                          "COMPILE_STORM (ISSUE 16)")
@@ -394,7 +394,7 @@ def _main_scale(args) -> int:
     prewarm_ec = bool(getattr(args, "prewarm", False)) and n >= 12
     if prewarm_ec:
         # ISSUE 16 churn gate: boot prewarmed (persistent cache dir
-        # from CEPH_TPU_COMPILE_CACHE when hermetic CI points one),
+        # from JAX_COMPILATION_CACHE_DIR when hermetic CI points one),
         # and ARM the compile-stall injection — any EC launch on a
         # bucket the prewarm failed to cover sleeps 0.5 s in its
         # submit and fails the zero-stall gate below.  Deterministic:

@@ -1,145 +1,108 @@
 """Sweep CLI for the fused parity+crc kernel's operating point.
 
-This used to be a hand-run script whose winners were frozen into
-bitsliced.FUSED_TILE_HIER / FUSED_WB; the machinery now lives in
-ops/autotune.py, which the jax plugin consults at init (validated,
-measured, cached per device).  This CLI drives the same sweep
-explicitly, prints the per-candidate table, and refreshes the cache —
-use it to inspect WHY the plugin picked its point, or to re-tune after
-a runtime/hardware change.
+The served path never sweeps: it reads its (tile, wb, combine) point
+from ceph_tpu/ops/fused_points.json (ops/autotune.py).  This tool is
+what produces that file.  Run on the chip, it validates every
+candidate bit-exactly, times the valid ones, prints the per-candidate
+table — a candidate that fails to compile prints with the compiler's
+error, and the default point failing is fatal — and writes the winner
+for this device kind and geometry into the file, which the builder
+then commits.
 
 Usage: python -m ceph_tpu.tools.fused_tile_sweep
-           [--keep-cache | --validate-only] [tiles...]
+           [--show | --validate-only] [--km K,M] [tiles...]
 
-By default the sweep is forced (the cache entry is refreshed); pass
---keep-cache to only print the cached point without re-measuring.
-Candidates that fail the bit-exactness validation (e.g. the packed or
-wide extraction on a Mosaic generation without strided sublane slices,
-or the accumulator kernel's scalar-prefetch grid) print as INVALID.
+--show prints the point the served path would use here and where it
+comes from, without measuring.
 
 --validate-only runs ONLY the bit-exactness gate over every kernel
-variant (no measurement, no cache writes), through the Pallas
-interpreter when the backend is CPU — the tier-1 hook
-(scripts/tier1.sh): a structural regression in any shipped variant
-fails the gate instead of silently falling back at plugin init.
-Exits nonzero on any invalid candidate.  Defaults to one small tile
-(the variant grid is what matters); pass tiles to widen.  Budget-
-capped by CEPH_TPU_AUTOTUNE_BUDGET_S like the init sweep.
+variant (no measurement, no file write) — compiled on an accelerator,
+through the Pallas interpreter when the platform is CPU (the tier-1
+hook, scripts/tier1.sh): a structural regression in any shipped
+variant fails the gate.  Exits nonzero on any invalid candidate.
+Defaults to one small tile (the variant grid is what matters); pass
+tiles to widen.
 """
-import os
 import sys
-import time
 
 from ..ec.registry import ErasureCodePluginRegistry
-from ..ops import autotune
+from ..ops import autotune, device
 
-K, M = 8, 3
 VALIDATE_TILES = (32768,)
 
 
 def _cand_tag(cand: dict) -> str:
     return (f"tile={cand['tile']:6d} wb={cand['wb']:5d} "
-            f"extract={cand['extract']:6s} combine={cand['combine']:6s}")
+            f"combine={cand['combine']:6s}")
 
 
-def validate_only(codec, tiles) -> int:
-    import jax
-    import jax.numpy as jnp
-
-    from ..ops import bitsliced as bs
-    interpret = jax.default_backend() == "cpu"
-    # the CPU plugin skips the w32 matrix build (no w32 kernel runs in
-    # production there) — the interpret gate needs it regardless
-    bitmat32 = codec._enc_bitmat32
-    if bitmat32 is None:
-        bitmat32 = jnp.asarray(bs._w32_bitmat(codec.matrix[K:]),
-                               dtype=jnp.int8)
-    budget = float(os.environ.get("CEPH_TPU_AUTOTUNE_BUDGET_S", "75"))
-    mode = "interpret" if interpret else "compiled"
-    print(f"# validate-only ({mode}, budget {budget:.0f}s): every "
-          f"kernel variant must stay bit-exact vs gf_matvec + host "
-          f"crc32c")
-    t0 = time.perf_counter()
-    bad, checked, skipped = [], 0, 0
-    # variant-diverse order: one candidate of EVERY (extract, combine)
-    # kernel variant before any repeats at other (tile, wb) shapes —
-    # a budget-capped run on a loaded box must still have checked each
-    # variant once (the autotuner's best-guess order would leave the
-    # accumulator variants, the likeliest to regress, for last)
-    cands = autotune.candidates(K, M, tiles=tiles or VALIDATE_TILES)
-    seen_variants: dict = {}
-    for c in cands:
-        seen_variants.setdefault((c["extract"], c["combine"]),
-                                 []).append(c)
-    rounds = max(len(v) for v in seen_variants.values())
-    ordered = [v[i] for i in range(rounds)
-               for v in seen_variants.values() if i < len(v)]
-    # round 0 (the first candidate of every variant class) is exempt
-    # from the budget: the gate's guarantee is that NO shipped kernel
-    # variant goes unvalidated, so budget pressure may only drop
-    # repeats at other (tile, wb) shapes, never a whole variant class
-    for i, cand in enumerate(ordered):
-        if i >= len(seen_variants) and \
-                time.perf_counter() - t0 > budget:
-            skipped += 1
-            continue
-        checked += 1
-        ok = autotune._validate(codec.matrix[K:], bitmat32,
-                                cand, interpret=interpret)
+def validate_only(codec, bitmat32, tiles) -> int:
+    k = codec.get_data_chunk_count()
+    interpret = device.on_cpu()
+    print(f"# validate-only ({'interpret' if interpret else 'compiled'}"
+          f" on {device.describe()}): every kernel variant must stay "
+          f"bit-exact vs gf_matvec + host crc32c")
+    bad = 0
+    cands = autotune.candidates(k, codec.get_coding_chunk_count(),
+                                tiles=tiles or VALIDATE_TILES)
+    for cand in cands:
+        err = autotune.validate(codec.matrix[k:], bitmat32, cand,
+                                interpret=interpret)
         print(f"{_cand_tag(cand)}  "
-              f"{'ok' if ok else 'INVALID (failed bit-exactness)'}")
-        if not ok:
-            bad.append(cand)
-    if skipped:
-        print(f"# budget exhausted: {skipped} candidate(s) unchecked")
+              + ("ok" if err is None else f"INVALID: {err}"))
+        bad += err is not None
     if bad:
-        print(f"# {len(bad)}/{checked} variants INVALID")
+        print(f"# {bad}/{len(cands)} variants INVALID")
         return 1
-    print(f"# all {checked} checked variants bit-exact")
+    print(f"# all {len(cands)} variants bit-exact")
     return 0
 
 
 def main():
-    known = {"--keep-cache", "--validate-only"}
-    unknown = [a for a in sys.argv[1:]
-               if a.startswith("-") and a not in known]
+    argv = sys.argv[1:]
+    known = {"--show", "--validate-only", "--km"}
+    unknown = [a for a in argv if a.startswith("-") and a not in known]
     if unknown:
-        print(f"unknown option(s): {' '.join(unknown)} — this tool now "
-              "drives ops/autotune (the old --flat mode is gone; the "
-              "flat 2 KiB kernel is not a tuning candidate).  Usage: "
-              "fused_tile_sweep [--keep-cache | --validate-only] "
+        print(f"unknown option(s): {' '.join(unknown)}.  Usage: "
+              "fused_tile_sweep [--show | --validate-only] [--km K,M] "
               "[tiles...]")
         raise SystemExit(2)
-    tiles = [int(t) for t in sys.argv[1:]
-             if not t.startswith("-")] or None
-    reg = ErasureCodePluginRegistry.instance()
-    codec = reg.factory("jax", {"k": str(K), "m": str(M),
-                                "technique": "cauchy"})
-    import jax
-    if "--validate-only" in sys.argv:
-        raise SystemExit(validate_only(codec, tiles))
-    if jax.default_backend() == "cpu":
-        print("backend is cpu: the fused w32 kernel is TPU-only; "
-              f"static default point = {autotune.default_point()} "
-              "(use --validate-only for the interpret-mode "
-              "bit-exactness gate)")
+    k, m = 8, 3
+    if "--km" in argv:
+        i = argv.index("--km")
+        k, m = (int(v) for v in argv[i + 1].split(","))
+        del argv[i:i + 2]
+    tiles = [int(t) for t in argv if not t.startswith("-")] or None
+    codec = ErasureCodePluginRegistry.instance().factory(
+        "jax", {"k": str(k), "m": str(m), "technique": "cauchy"})
+    if "--show" in argv:
+        print(f"{device.describe()}: {codec.fused_point()}")
         return
-    if "--keep-cache" in sys.argv:
-        print(f"cached/current point: {codec.fused_point()}")
-        print(f"cache file: {autotune._cache_path()}")
-        return
-    report: list = []
-    best = autotune.fused_operating_point(
-        K, M, mat=codec.matrix[K:], bitmat32=codec._enc_bitmat32,
-        tiles=tiles, force=True, report=report)
-    for cand, rate in report:
-        if rate is None:
-            print(f"{_cand_tag(cand)}  INVALID (failed compile or "
-                  f"bit-exactness)")
-        else:
-            print(f"{_cand_tag(cand)}  {rate / 1e9:7.2f} GB/s")
-    print(f"best: {best}")
-    print(f"cache file: {autotune._cache_path()}")
+    import jax.numpy as jnp
+
+    from ..ops import bitsliced as bs
+
+    # the CPU plugin skips the w32 matrix build (no w32 kernel runs in
+    # production there) — the interpret gate needs it regardless
+    bitmat32 = codec._enc_bitmat32
+    if bitmat32 is None:
+        bitmat32 = jnp.asarray(bs._w32_bitmat(codec.matrix[k:]),
+                               dtype=jnp.int8)
+    if "--validate-only" in argv:
+        raise SystemExit(validate_only(codec, bitmat32, tiles))
+    report = []
+    for row in autotune.sweep(k, m, codec.matrix[k:], bitmat32,
+                              tiles=tiles):
+        cand, rate, err, wall = row
+        report.append(row)
+        print(f"{_cand_tag(cand)}  "
+              + (f"INVALID: {err}" if err is not None
+                 else f"{rate / 1e9:7.2f} GB/s")
+              + f"  ({wall:.0f}s incl. compiles)", flush=True)
+    entry = autotune.winner(report)
+    autotune.write_point(k, m, entry)
+    print(f"best for {device.describe()['kind']} k{k}m{m}: {entry}")
+    print(f"written to {autotune.POINTS_FILE}")
 
 
 if __name__ == "__main__":

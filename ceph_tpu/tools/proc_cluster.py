@@ -85,21 +85,16 @@ class ProcCluster:
                  conf: dict | None = None,
                  boot_timeout: float = 120.0,
                  mesh_devices: str | None = None,
-                 prewarm: bool = False,
-                 compile_cache_dir: str | None = None):
+                 prewarm: bool = False):
         # compile lifecycle (docs/PIPELINE.md): in the process
         # topology EVERY OSD process prewarms its own interpreter's
         # jit caches, so the shared persistent compile cache does the
         # cross-process heavy lifting (first booter compiles to disk,
-        # the rest read).  compile_cache_dir points it at a private
-        # dir for hermetic CI.
-        if prewarm or compile_cache_dir is not None:
+        # the rest read; the children inherit
+        # JAX_COMPILATION_CACHE_DIR with the rest of the environment)
+        if prewarm:
             conf = dict(conf or {})
-            if prewarm:
-                conf.setdefault("osd_ec_prewarm", True)
-            if compile_cache_dir is not None:
-                conf.setdefault("osd_ec_compile_cache_dir",
-                                str(compile_cache_dir))
+            conf.setdefault("osd_ec_prewarm", True)
         self.n_osds = n_osds
         self.n_mons = n_mons
         self.objectstore = objectstore

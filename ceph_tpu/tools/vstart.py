@@ -34,19 +34,13 @@ class Cluster:
                  conf: dict | None = None,
                  mesh_devices: str | None = None,
                  boot_parallel: bool = False,
-                 prewarm: bool = False,
-                 compile_cache_dir: str | None = None):
+                 prewarm: bool = False):
         self.conf = dict(conf or {})   # applied to every OSD pre-boot
         # compile lifecycle (docs/PIPELINE.md): prewarm=True boots
         # every OSD with the jit-bucket prewarm pass (the first
-        # in-process booter warms for the host); compile_cache_dir
-        # points the persistent compile cache at a private directory
-        # (hermetic CI: a tmpdir instead of ~/.cache/ceph_tpu/xla)
+        # in-process booter warms for the host)
         if prewarm:
             self.conf.setdefault("osd_ec_prewarm", True)
-        if compile_cache_dir is not None:
-            self.conf.setdefault("osd_ec_compile_cache_dir",
-                                 str(compile_cache_dir))
         # multichip deployment mode (docs/MULTICHIP.md): every OSD in
         # this (one-host) cluster shares the process-wide MeshService,
         # so EC PGs drain and repair on the device mesh.  '' = all

@@ -89,7 +89,8 @@ if [ "$rc" -eq 0 ]; then
 fi
 # Compile-stall kill gate (ISSUE 16, docs/PIPELINE.md "Compile
 # lifecycle"): a prewarmed 16-OSD churn row with the stall injection
-# ARMED and the persistent compile cache pointed at a throwaway dir —
+# ARMED (the persistent compile cache sits wherever the caller's
+# JAX_COMPILATION_CACHE_DIR put it, else in the checkout's .jax_cache/) —
 # EC writes must ack through kill/revive churn with ec_compile_stalls
 # == 0 and no COMPILE_STORM (any bucket the boot-time PrewarmPlan
 # missed trips the injected stall and fails the row).  The
@@ -97,11 +98,9 @@ fi
 # + kill/revive unit scenarios run in the pytest tier above
 # (tests/test_prewarm.py).
 if [ "$rc" -eq 0 ]; then
-  _cc_dir=$(mktemp -d) && \
-  timeout -k 10 540 env JAX_PLATFORMS=cpu CEPH_TPU_COMPILE_CACHE="$_cc_dir" \
+  timeout -k 10 540 env JAX_PLATFORMS=cpu \
     python -m ceph_tpu.tools.cluster_bench \
     --scale 16 --prewarm --seconds 2 --size 16384 || rc=$?
-  rm -rf "$_cc_dir"
 fi
 # Sharded bucket-index gate (ISSUE 17, docs/ARCHITECTURE.md "Bucket
 # index sharding"): dir_merge-prefilled buckets at 1/4/8 index shards
@@ -117,14 +116,12 @@ if [ "$rc" -eq 0 ]; then
     --scenario s3-shard-sweep || rc=$?
 fi
 # Fused-kernel variant gate (ISSUE 11, docs/FUSED_CRC.md): every
-# shipped (extract, combine) variant of the fused parity+crc kernel —
-# planar/packed/wide extraction through the XLA log-fold AND the
-# in-kernel VMEM accumulator — must stay bit-exact vs gf_matvec + host
-# crc32c on the Pallas interpret path (no measurement, budget-capped).
-# A structural kernel regression fails tier-1 here instead of silently
-# falling back at plugin init on the next TPU round.
+# shipped (wb, combine) variant of the fused parity+crc kernel — the
+# XLA log-fold AND the in-kernel VMEM accumulator — must stay bit-exact
+# vs gf_matvec + host crc32c on the Pallas interpret path (no
+# measurement).  A structural kernel regression fails tier-1 here.
 if [ "$rc" -eq 0 ]; then
-  timeout -k 10 240 env JAX_PLATFORMS=cpu CEPH_TPU_AUTOTUNE_BUDGET_S=120 \
+  timeout -k 10 240 env JAX_PLATFORMS=cpu \
     python -m ceph_tpu.tools.fused_tile_sweep --validate-only || rc=$?
 fi
 exit $rc

@@ -57,6 +57,52 @@ def test_native_gf8_matvec_matches_numpy():
     np.testing.assert_array_equal(got, ref)
 
 
+def _native_in(tmp_path, monkeypatch):
+    """A private copy of native/ (sources + Makefile, no objects) that
+    ceph_tpu.common.native loads from, with its load state reset."""
+    import shutil
+    src = native._NATIVE_DIR
+    for name in ("Makefile", "crc32c.c", "gf8.c"):
+        shutil.copy(src / name, tmp_path / name)
+    monkeypatch.setattr(native, "_NATIVE_DIR", tmp_path)
+    monkeypatch.setattr(native, "_LIB_PATH",
+                        tmp_path / "libceph_tpu_native.so")
+    for attr, val in (("_lib", None), ("_tried", False),
+                      ("_build_error", None)):
+        monkeypatch.setattr(native, attr, val)
+    return tmp_path
+
+
+def test_native_is_built_from_sources_and_rebuilt_when_stale(
+        tmp_path, monkeypatch):
+    """First load runs make: a checkout holding only the .c files gets
+    its library built, and a source newer than the library rebuilds it
+    (make decides staleness, not `exists()`)."""
+    import os
+    d = _native_in(tmp_path, monkeypatch)
+    assert native.available() and native.build_error() is None
+    lib = d / "libceph_tpu_native.so"
+    built = lib.stat().st_mtime_ns
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_tried", False)
+    stamp = lib.stat().st_mtime + 10
+    os.utime(d / "crc32c.c", (stamp, stamp))
+    assert native.available()
+    assert lib.stat().st_mtime_ns != built
+
+
+def test_native_build_failure_is_loud(tmp_path, monkeypatch):
+    """A source that does not compile: load() warns with the
+    compiler's output and build_error() keeps it — the python crc
+    fallback must never be entered silently."""
+    d = _native_in(tmp_path, monkeypatch)
+    (d / "gf8.c").write_text("this is not C\n")
+    with pytest.warns(RuntimeWarning, match="native library unavailable"):
+        assert not native.available()
+    assert "CalledProcessError" in native.build_error()
+    assert "gf8.c" in native.build_error()
+
+
 def test_bufferlist_append_substr():
     bl = BufferList()
     bl.append(b"hello ")

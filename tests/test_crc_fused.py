@@ -309,16 +309,13 @@ def test_fold_run_crc_degenerate_cases():
         C.crc32c(b"", 0x1234)
 
 
-@pytest.mark.parametrize("extract,combine",
-                         [("planar", "xla"), ("packed", "xla"),
-                          ("packed", "kernel"), ("wide", "kernel")])
-def test_device_fold_launch_interpret(extract, combine):
+@pytest.mark.parametrize("combine", ["xla", "kernel"])
+def test_device_fold_launch_interpret(combine):
     """gf_encode_with_crc_w32_fold (the bench/write-path launch): one
-    L per shard per dispatch, multi-tile extents, the crc extraction
-    variants (planar / packed / wide) through both combine depths (the
-    XLA log-fold and the in-kernel VMEM accumulator), bit-exact
-    against the host crc32c with a caller seed.  (The full 18-point
-    extract x combine x wb grid runs in tier-1 via
+    L per shard per dispatch, multi-tile extents, through both combine
+    depths (the XLA log-fold and the in-kernel VMEM accumulator),
+    bit-exact against the host crc32c with a caller seed.  (The
+    combine x wb grid at a real tile runs in tier-1 via
     `fused_tile_sweep --validate-only` — outside the pytest budget.)"""
     import jax.numpy as jnp
     from ceph_tpu.ops import bitsliced as bs
@@ -335,7 +332,7 @@ def test_device_fold_launch_interpret(extract, combine):
     words = jnp.asarray(chunks.view("<u4").view(np.int32))
     par_w, lbits = bs.gf_encode_with_crc_w32_fold(
         bitmat32, cmat_sub, words, m, tile=tile, wb=wb,
-        interpret=True, extract=extract, combine=combine)
+        interpret=True, combine=combine)
     assert lbits.shape == (k + m, 32)     # ONE L per shard per launch
     parity = np.asarray(par_w).view("<u4").view(np.uint8).reshape(m, n)
     np.testing.assert_array_equal(parity, gf.gf_matvec(mat, chunks))
@@ -346,42 +343,6 @@ def test_device_fold_launch_interpret(extract, combine):
             got = cl.fold_run_crc(int(ls[s]), n, seed)
             assert got == C.crc32c(allsh[s].tobytes(), seed), \
                 f"shard {s} seed {seed:#x}"
-
-
-def test_packed_subblock_extraction_matches_planar():
-    """subblock_crc_bits_w32_packed (4 bits per VPU pass) must produce
-    exactly the planar variant's L-bit matrix."""
-    import jax.numpy as jnp
-    rng = np.random.default_rng(14)
-    r, wb, s = 5, 32, 4
-    wt = wb * s
-    chunks = rng.integers(0, 256, (r, 4 * wt), dtype=np.uint8)
-    words = jnp.asarray(chunks.view("<u4").view(np.int32))
-    cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
-    planar = np.asarray(cl.subblock_crc_bits_w32(words, cmat_sub, wb))
-    packed = np.asarray(cl.subblock_crc_bits_w32_packed(
-        words, cmat_sub, wb, interpret=True))
-    np.testing.assert_array_equal(planar, packed)
-
-
-def test_wide_subblock_extraction_matches_planar():
-    """subblock_crc_bits_w32_wide (mask-free shift-only passes; every
-    non-LSB operand bit contributes an even multiple that the mod-2
-    reduction cancels) must produce exactly the planar variant's
-    L-bit matrix — including operand bytes >= 0x80, whose signed int8
-    reading differs by a multiple of 256 (also even)."""
-    import jax.numpy as jnp
-    rng = np.random.default_rng(16)
-    r, wb, s = 5, 32, 4
-    wt = wb * s
-    chunks = rng.integers(0, 256, (r, 4 * wt), dtype=np.uint8)
-    chunks[0, :64] = 0xFF          # force the signed-wrap corner
-    words = jnp.asarray(chunks.view("<u4").view(np.int32))
-    cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
-    planar = np.asarray(cl.subblock_crc_bits_w32(words, cmat_sub, wb))
-    wide = np.asarray(cl.subblock_crc_bits_w32_wide(
-        words, cmat_sub, wb, interpret=True))
-    np.testing.assert_array_equal(planar, wide)
 
 
 def _legal_points(k, m, tiles, wbs):
@@ -419,7 +380,7 @@ def test_acc_kernel_every_legal_alignment_edge():
         cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
         par_w, lbits = bs.gf_encode_with_crc_w32_fold(
             bitmat32, cmat_sub, words, m, tile=tile, wb=wb,
-            interpret=True, extract="wide", combine="kernel")
+            interpret=True, combine="kernel")
         parity = np.asarray(par_w).view("<u4").view(np.uint8) \
             .reshape(m, n)
         np.testing.assert_array_equal(parity, gf.gf_matvec(mat, chunks))
@@ -454,8 +415,7 @@ def test_multi_extent_acc_kernel_interpret():
             for w in widths]
     handle = bs.gf_encode_extents_with_crc_submit(
         bitmat, bitmat32, runs, m, use_w32=True, force_xla=False,
-        interpret=True, tile=tile, wb=wb, extract="wide",
-        combine="kernel")
+        interpret=True, tile=tile, wb=wb, combine="kernel")
     assert handle["path"] == "hier_acc"
     results = bs.gf_encode_extents_with_crc_finalize(handle)
     seeds = [0xFFFFFFFF] * (k + m)
@@ -493,7 +453,7 @@ def test_acc_chained_seeds_across_pipelined_drains():
               [rng.integers(0, 256, (k, tile * 2 + 99), dtype=np.uint8)]]
     handles = [bs.gf_encode_extents_with_crc_submit(
         bitmat, bitmat32, d, m, use_w32=True, force_xla=False,
-        interpret=True, tile=tile, wb=wb, extract="planar",
+        interpret=True, tile=tile, wb=wb,
         combine="kernel") for d in drains]       # both launched first
     seeds = [0xFFFFFFFF] * (k + m)
     streams = [b""] * (k + m)

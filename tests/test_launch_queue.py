@@ -428,8 +428,7 @@ def test_mixed_width_batch_keeps_hier_kernel_interpret():
             for w in widths]
     handle = bs.gf_encode_extents_with_crc_submit(
         bitmat, bitmat32, runs, m, use_w32=True, force_xla=False,
-        interpret=True, tile=tile, wb=wb, extract="planar",
-        combine="kernel")
+        interpret=True, tile=tile, wb=wb, combine="kernel")
     assert "split" in handle
     assert handle["path"].startswith("hier_acc")
     results = bs.gf_encode_extents_with_crc_finalize(handle)

@@ -15,6 +15,9 @@ injection at zero stalls and no COMPILE_STORM, its first launches
 ledgered as cache hits.
 """
 
+import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -32,6 +35,7 @@ from ceph_tpu.parallel.launch_queue import ECLaunchQueue
 from ceph_tpu.store import MemStore
 
 REG = ErasureCodePluginRegistry.instance()
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def oid(name):
@@ -58,7 +62,6 @@ def _reset_all():
     DeviceProfiler.reset_host()
     ECLaunchQueue.reset_host()
     prewarm.reset_for_tests()
-    compile_cache.reset_for_tests()
 
 
 def _storm(codec, n=4):
@@ -117,21 +120,25 @@ def test_plan_covers_depth2_write_storm_exactly():
 
 # -- persistent cache round-trip across an in-process restart ---------------
 
-def test_persistent_cache_roundtrip_restart(tmp_path):
-    """Boot 1 against an empty cache dir compiles to disk; a simulated
-    daemon restart (cleared jit caches + reset singletons) re-runs the
-    prewarm and hits the persistent cache: ec_prewarm_cache_hits > 0
-    and zero compile stalls on the second boot's write path."""
+def test_persistent_cache_roundtrip_restart():
+    """Boot 1 compiles to (or already finds its programs in) the
+    persistent cache directory the environment placed (conftest:
+    JAX_COMPILATION_CACHE_DIR); a simulated daemon restart (cleared
+    jit caches + reset singletons) re-runs the prewarm and hits the
+    persistent cache: ec_prewarm_cache_hits > 0 and zero compile
+    stalls on the second boot's write path."""
     _reset_all()
     small = dict(widths=[2048, 4096], run_counts=[1, 2],
                  plain_widths=[2048], decode_widths=[2048])
     try:
         # cold process for boot 1 too: earlier tests may have compiled
         # these very programs in-memory, which would let boot 1 skip
-        # compiling — and an empty cache dir can't be hit on boot 2
+        # the persistent cache — and nothing on disk can't be hit on
+        # boot 2
         jax.clear_caches()
         bs.aot_reset_for_tests()
-        assert compile_cache.enable(str(tmp_path))
+        assert compile_cache.enable() == \
+            os.environ["JAX_COMPILATION_CACHE_DIR"]
         codec = make_codec()
         host = device_profiler()
         st1 = prewarm.run_once(codec, profiler=host, budget_s=60.0,
@@ -143,7 +150,6 @@ def test_persistent_cache_roundtrip_restart(tmp_path):
         jax.clear_caches()
         bs.aot_reset_for_tests()
         _reset_all()
-        assert compile_cache.enable(str(tmp_path))
         codec2 = make_codec()
         host2 = device_profiler()
         st2 = prewarm.run_once(codec2, profiler=host2, budget_s=60.0,
@@ -165,7 +171,7 @@ def test_persistent_cache_roundtrip_restart(tmp_path):
 
 # -- budget cutoff: prewarm is never a boot dependency ----------------------
 
-def test_budget_cutoff_leaves_daemon_bootable(tmp_path):
+def test_budget_cutoff_leaves_daemon_bootable():
     """budget_s=0 truncates the plan before the first entry, and a
     cluster booted that way still comes up and serves writes — the
     asok reports the truncation instead of the boot hanging."""
@@ -178,7 +184,6 @@ def test_budget_cutoff_leaves_daemon_bootable(tmp_path):
         assert st["done"] == 0 and st["skipped"] == st["planned"]
 
         with Cluster(n_osds=2, prewarm=True,
-                     compile_cache_dir=str(tmp_path),
                      conf={"osd_ec_prewarm_budget_s": 0.0}) as c:
             client = c.client()
             client.create_pool("bp", pg_num=4)
@@ -195,7 +200,7 @@ def test_budget_cutoff_leaves_daemon_bootable(tmp_path):
 
 # -- kill/revive storm: zero stalls, no COMPILE_STORM -----------------------
 
-def test_kill_revive_storm_zero_stalls(tmp_path):
+def test_kill_revive_storm_zero_stalls():
     """The headline gate, in miniature: a prewarmed EC cluster with
     the stall injection ARMED takes writes, loses an OSD, writes
     degraded, revives it (recovery decodes), writes again — and the
@@ -216,8 +221,7 @@ def test_kill_revive_storm_zero_stalls(tmp_path):
             "osd_ec_inject_compile_stall": 0.5,
             "osd_ec_prewarm_budget_s": 60.0,
         }
-        with Cluster(n_osds=4, prewarm=True,
-                     compile_cache_dir=str(tmp_path), conf=conf) as c:
+        with Cluster(n_osds=4, prewarm=True, conf=conf) as c:
             host = device_profiler()
             assert any(e.get("prewarmed")
                        for e in host._buckets.values()), \
@@ -258,3 +262,46 @@ def test_kill_revive_storm_zero_stalls(tmp_path):
             assert st["boot"].get("reused") or st["boot"].get("done")
     finally:
         _reset_all()
+
+
+# -- cache placement: from outside, or one fixed in-checkout path -----------
+
+_PLACEMENT_PROBE = (
+    "import jax\n"
+    "from ceph_tpu.ops import compile_cache\n"
+    "d = compile_cache.enable()\n"
+    "assert jax.config.jax_compilation_cache_dir == d\n"
+    "assert compile_cache.status()['dir'] == d\n"
+    "print(d)\n")
+
+
+def _fresh_interpreter_cache_dir(env_dir):
+    """compile_cache.enable() in a FRESH interpreter (jax reads the
+    variable at import), from the repo root; returns the directory."""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_PROBE],
+                         cwd=ROOT, env=env, capture_output=True,
+                         text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_cache_dir_comes_from_environment(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: enable() leaves jax's configured
+    directory equal to it (and sets no other)."""
+    want = str(tmp_path / "placed")
+    assert _fresh_interpreter_cache_dir(want) == want
+    assert os.path.isdir(want)
+
+
+def test_cache_dir_unset_is_fixed_in_checkout_path():
+    """Unset: the one fixed directory inside the checkout, identical
+    across two fresh interpreters (a directory that moves never
+    hits)."""
+    a = _fresh_interpreter_cache_dir(None)
+    b = _fresh_interpreter_cache_dir(None)
+    assert a == b == str(compile_cache.CHECKOUT_DIR)
+    assert a == os.path.join(ROOT, ".jax_cache")
