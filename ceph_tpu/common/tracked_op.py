@@ -83,6 +83,38 @@ class TraceContext:
                    w.get("parent"), w.get("ts"))
 
 
+# Phase anchors: at unregister an op's OWN timeline is cut at the FIRST
+# occurrence of each anchor event, whatever finer events (msgr_send,
+# launch(<id>), per-shard acks) are interleaved, and each piece feeds
+# `lat_phase_<op_type>_<phase>`.  The phases partition
+# [earliest stamp, last anchor] exactly; an anchor of None is
+# completed_at.  "initiated" is the op's creation at this daemon (the
+# message handler's start), so the osd_op phases after wire_in sum to
+# lat_total_osd_op.  A phase whose anchor never occurred (a read has
+# no encode) is skipped and its time falls to the next one present.
+#
+# One clock: objecter_submit (TraceContext.new's origin_ts), the
+# messenger's recv_stamp, initiated_at and every mark_event are all
+# time.time() — the same clock inside one host, NTP-close across
+# hosts (a negative piece clamps to 0).
+PHASE_ANCHORS: dict[str, tuple] = {
+    "osd_op": (
+        ("wire_in", "initiated"),       # objecter_submit -> handler
+        #                                 start, through msgr_recv_lag
+        ("queue_wait", "dequeued"),     # op queue / op pool
+        ("prepare", "ec_encode_launch"),    # decode, metadata probe,
+        #                                 locks, assemble, launch submit
+        ("encode", "ec_encode_materialize"),    # launch-queue wait +
+        #                                 H2D + device + D2H
+        ("fanout_commit", None),        # k+m sub-writes, acks, commit,
+        #                                 reply
+    ),
+    "ec_sub_write": (
+        ("apply", "sub_op_applied"),    # store transaction + shard log
+    ),
+}
+
+
 def canonical_stage(event: str) -> str:
     """Histogram key for an event: per-shard detail stripped, so
     sub_write_ack(2) and sub_write_ack(0) share one latency series."""
@@ -164,6 +196,34 @@ class TrackedOp:
             prev = ts
         return out
 
+    def phase_durations(self) -> list[tuple[str, float]]:
+        """[(phase, seconds)] of a COMPLETED op by its type's
+        PHASE_ANCHORS; [] for a type that declares none."""
+        anchors = PHASE_ANCHORS.get(self.op_type)
+        if anchors is None or self.completed_at is None:
+            return []
+        first = {"initiated": self.initiated_at}
+        for ts, name in self.events:
+            if name not in first:
+                first[name] = ts
+        prev = self.initiated_at
+        if self.events and self.events[0][0] < prev:
+            prev = self.events[0][0]
+        out = []
+        for phase, anchor in anchors:
+            if anchor is None:
+                ts = self.completed_at
+            elif anchor in first:
+                ts = first[anchor]
+            else:
+                continue
+            if ts > prev:
+                out.append((phase, ts - prev))
+                prev = ts
+            else:
+                out.append((phase, 0.0))
+        return out
+
     def blame(self, now: float | None = None) -> str:
         """The stage that ate the op's wall time (see module doc)."""
         now = now if now is not None else time.time()
@@ -237,6 +297,9 @@ class _NullTrackedOp:
         return ""
 
     def stage_durations(self) -> list:
+        return []
+
+    def phase_durations(self) -> list:
         return []
 
     def blame(self, now: float | None = None) -> str:
@@ -334,6 +397,11 @@ class OpTracker:
             # series decomposes (dump_latencies / the exporter's
             # precomputed tail gauges read it like any stage)
             self.perf.hinc(f"lat_total_{top.op_type}", top.duration())
+            # the same timeline as a partition (PHASE_ANCHORS): the
+            # per-event series above blame an interval on whichever
+            # event ends it, so they cannot be read as a split
+            for phase, dt in top.phase_durations():
+                self.perf.hinc(f"lat_phase_{top.op_type}_{phase}", dt)
 
     # -- slow-op surveillance ------------------------------------------------
 

@@ -205,13 +205,20 @@ class MOSDOpReply(Message):
     type_id = 43
 
     def __init__(self, tid: int, result: int, data: bytes = b"",
-                 epoch: int = 0):
+                 epoch: int = 0, sent_ts: float | None = None):
         super().__init__()
         self.tid, self.result, self.data, self.epoch = \
             tid, result, data, epoch
+        # the primary's `reply_sent` stamp (time.time()): the objecter
+        # closes `lat_reply_leg` against it when the waiter wakes
+        self.sent_ts = sent_ts
 
     def to_meta(self):
-        return {"tid": self.tid, "result": self.result, "epoch": self.epoch}
+        meta = {"tid": self.tid, "result": self.result,
+                "epoch": self.epoch}
+        if self.sent_ts is not None:
+            meta["ts"] = self.sent_ts
+        return meta
 
     def data_segment(self):
         return self.data
@@ -219,6 +226,7 @@ class MOSDOpReply(Message):
     def decode_wire(self, meta, data):
         self.tid, self.result = meta["tid"], meta["result"]
         self.epoch = meta["epoch"]
+        self.sent_ts = meta.get("ts")
         self.data = data
 
 

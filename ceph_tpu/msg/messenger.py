@@ -49,6 +49,7 @@ from typing import Callable
 
 from .message import (CTRL_ACK, CTRL_COMP, CTRL_ENC, CTRL_HELLO, Message,
                       encode_frame)
+from ..common import spans
 from .msgr_ledger import MsgrLedger, msgr_ledger
 
 Dispatcher = Callable[["Connection", Message], None]
@@ -332,8 +333,19 @@ class Connection:
                     return
                 sess.reset_epoch()
             sess.out_seq += 1
-            raw = msg.encode_parts(sess.out_seq)
-            sess.record_out(sess.out_seq, raw)
+            # trace-only (common/spans.py: the reactors' CPU is
+            # accounted by thread) and never across an await, so
+            # msgr.send is two rows a frame — encode here, socket
+            # write in _write_raw
+            row = spans.annotation(
+                "msgr.send", type=type(msg).__name__) \
+                if spans.tracing_now else None
+            try:
+                raw = msg.encode_parts(sess.out_seq)
+                sess.record_out(sess.out_seq, raw)
+            finally:
+                if row is not None:
+                    row.__exit__(None, None, None)
             if sess.broken:       # overflow tripped by this very frame
                 if not self.can_reconnect:
                     return
@@ -405,24 +417,32 @@ class Connection:
             # wire dropped while we slept in the injected delay (the
             # accepted-conn read loop nulls it without the send lock)
             raise ConnectionResetError("wire dropped during delayed write")
-        sess = self.session
-        parts = raw if isinstance(raw, tuple) else (raw,)
-        if sess.comp is not None or (sess.secure and sess.conn_key):
-            # compression/encryption wrap the whole frame: join first
-            joined = b"".join(parts)
-            wired = sess.wire_prepare(joined)
-            if m.ledger.enabled:
-                m.stats.note_wrapped(
-                    self._peer_label(), len(wired),
-                    compressed=sess.comp is not None and
-                    len(joined) >= sess.comp_min,
-                    encrypted=bool(sess.secure and sess.conn_key))
-            writer.write(wired)
-        else:
-            # writev-style: payload buffers go to the transport as-is,
-            # never copied into one frame buffer
-            for p in parts:
-                writer.write(p)
+        row = spans.annotation("msgr.send") \
+            if spans.tracing_now else None
+        try:
+            sess = self.session
+            parts = raw if isinstance(raw, tuple) else (raw,)
+            if sess.comp is not None or \
+                    (sess.secure and sess.conn_key):
+                # compression/encryption wrap the whole frame: join
+                # first
+                joined = b"".join(parts)
+                wired = sess.wire_prepare(joined)
+                if m.ledger.enabled:
+                    m.stats.note_wrapped(
+                        self._peer_label(), len(wired),
+                        compressed=sess.comp is not None and
+                        len(joined) >= sess.comp_min,
+                        encrypted=bool(sess.secure and sess.conn_key))
+                writer.write(wired)
+            else:
+                # writev-style: payload buffers go to the transport
+                # as-is, never copied into one frame buffer
+                for p in parts:
+                    writer.write(p)
+        finally:
+            if row is not None:
+                row.__exit__(None, None, None)
         await writer.drain()
 
     async def _connect(self) -> None:
@@ -674,6 +694,10 @@ class Messenger:
                 # arm the per-reactor loop-lag probe on the fresh pool
                 # (wire-plane flight recorder, msg/msgr_ledger.py)
                 msgr_ledger().attach_reactors(cls._loops)
+                # the reactor threads' whole CPU (wire work and the
+                # handlers fast-dispatched inline on them) is the
+                # `host_spans` set's `msgr.reactor_cpu`
+                spans.account_threads("msgr.reactor", "msgr-reactor-")
             return cls._loops
 
     @classmethod
@@ -691,18 +715,20 @@ class Messenger:
         return cls._executor
 
     @classmethod
-    def submit_dispatch(cls, fn, *args) -> None:
+    def submit_dispatch(cls, span: str, fn, *args) -> None:
         """dispatch_executor().submit with the exception fence the
         bare Future lacks: a pipeline continuation that raises must
         surface a traceback, not die unobserved in the Future.  Queue
         wait and run time land in the wire-plane ledger's
-        lat_msgr_qwait / lat_msgr_dispatch histograms."""
+        lat_msgr_qwait / lat_msgr_dispatch histograms; `span` is the
+        continuation's own name in the span table (the caller's layer,
+        not `msgr.`: what runs is the caller's work)."""
         led = msgr_ledger()
         t_sub = led.dispatch_submit() if led.enabled else None
 
         def run():
-            t_run = led.dispatch_run(t_sub) if t_sub is not None \
-                else None
+            t_run = led.dispatch_run(t_sub, span) \
+                if t_sub is not None else None
             try:
                 fn(*args)
             except Exception:  # noqa: BLE001
@@ -966,7 +992,13 @@ class Messenger:
                     # (reference ProtocolV2 in_seq dedup on session resume)
                     await conn._send_ack()
                     continue
-                msg = Message.decode(tid, seq, meta_raw, data, pcrc)
+                row = spans.annotation("msgr.decode") \
+                    if spans.tracing_now else None
+                try:
+                    msg = Message.decode(tid, seq, meta_raw, data, pcrc)
+                finally:
+                    if row is not None:
+                        row.__exit__(None, None, None)
                 # ingest stamp for op tracking (reference
                 # Message::recv_stamp set by the messenger): dispatch
                 # latency is attributable even when the executor queues
@@ -990,11 +1022,19 @@ class Messenger:
                         # inline on the reactor (handler is declared
                         # non-blocking); fence exceptions so a handler
                         # bug cannot kill the read loop
+                        # (a trace row only: lat_msgr_dispatch and
+                        # the span table keep to executor-run handlers)
+                        row = spans.annotation(
+                            "msgr.dispatch." + type(msg).__name__) \
+                            if spans.tracing_now else None
                         try:
                             self.dispatcher(conn, msg)
                         except Exception:  # noqa: BLE001
                             import traceback
                             traceback.print_exc()
+                        finally:
+                            if row is not None:
+                                row.__exit__(None, None, None)
                     else:
                         # dispatch off-reactor so handlers may send
                         # synchronously / block on nested RPCs; the
@@ -1006,7 +1046,9 @@ class Messenger:
 
                             def _timed(d=self.dispatcher, c=conn,
                                        mm=msg, t=t_sub):
-                                t_run = led.dispatch_run(t)
+                                t_run = led.dispatch_run(
+                                    t, "msgr.dispatch."
+                                    + type(mm).__name__)
                                 try:
                                     d(c, mm)
                                 finally:

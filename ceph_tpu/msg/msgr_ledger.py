@@ -47,8 +47,8 @@ surface on the asyncio reactor pool:
 
 * **Always on, null when off** — enabled by default (conf ms_ledger);
   disabled, every entry point returns after ONE attribute check and
-  allocates nothing (the NULL_TRACKED rule).  On-path overhead is
-  gated <= 2% in bench.py --smoke like the device/control planes.
+  allocates nothing (the NULL_TRACKED rule).  What the on path costs
+  is measured on the chip (PERF.md section 6).
 
 Perf-owner rule: the process-wide ledger's perf set (reactor lag +
 dispatch histograms — the reactors and executor are shared by every
@@ -65,6 +65,7 @@ import collections
 import threading
 import time
 
+from ..common import spans
 from ..common.perf_counters import PerfCountersBuilder
 
 # per-peer by-type maps are bounded: past this many distinct message
@@ -394,15 +395,24 @@ class MsgrLedger:
             self.perf.set("msgr_dispatch_queued_hwm", n)
         return time.perf_counter()
 
-    def dispatch_run(self, t_submit: float) -> float:
-        """The handler started running: close the queue-wait clock."""
-        now = time.perf_counter()
-        self.perf.hinc("lat_msgr_qwait", max(0.0, now - t_submit))
-        return now
+    def dispatch_run(self, t_submit: float, span: str):
+        """The handler started running: close the queue-wait clock and
+        open its span (common/spans.py: `msgr.dispatch.<MsgType>` for
+        a message handler, the caller's own name for a continuation
+        handed to Messenger.submit_dispatch).  Returns the open span;
+        hand it to dispatch_done in a `finally`."""
+        sp = spans.begin(span)
+        self.perf.hinc("lat_msgr_qwait",
+                       max(0.0, sp.t0 * 1e-9 - t_submit))
+        return sp
 
-    def dispatch_done(self, t_start: float) -> None:
-        self.perf.hinc("lat_msgr_dispatch",
-                       max(0.0, time.perf_counter() - t_start))
+    def dispatch_done(self, sp) -> None:
+        """Close the handler's span; its whole duration (children
+        included) is the `lat_msgr_dispatch` sample — handler RUN time
+        here, not the op tracker's `lat_msgr_dispatch` (that one is an
+        osd_op's client-submit -> frame-at-the-primary interval)."""
+        sp.end()
+        self.perf.hinc("lat_msgr_dispatch", sp.wall_s)
         self.dispatches_total += 1
         self.perf.inc("msgr_dispatches")
         n = self._dispatch_pending - 1

@@ -37,9 +37,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import spans
 from ..common.util import next_pow2
 from ..ec import gf
 from . import device
+from .profiler import device_profiler
 
 LANE = 128           # TPU lane width: byte-axis tiles must be multiples
 DEFAULT_TILE = 8192  # bytes of each chunk processed per grid step
@@ -462,6 +464,14 @@ def gf_encode_with_crc_pallas_w32(bitmat32, cmat32, words, m: int,
     assert wtot % wt == 0, (wtot, wt)
     grid = (wtot // wt,)
     rows = _crc_rows(k + m)
+    with jax.named_scope("ec.parity_crc_kernel"):
+        return _fused_flat_w32_call(bitmat32, cmat32, words, m, wt,
+                                    grid, rows, interpret)
+
+
+def _fused_flat_w32_call(bitmat32, cmat32, words, m: int, wt: int, grid,
+                         rows: int, interpret: bool):
+    k, wtot = words.shape
     return pl.pallas_call(
         _make_gf_crc_kernel_w32(interpret),
         grid=grid,
@@ -691,10 +701,14 @@ def _hier_acc_core(bitmat32, cmat_sub, adv, combine, run_map, first_map,
     from . import crc32c_linear as cl
     k = words.shape[0]
     s = (tile // 4) // wb
-    parity, lacc = _fused_hier_acc_call(
-        bitmat32, cmat_sub, adv, run_map, first_map, words, m, tile,
-        wb, nruns, interpret)
-    return parity, cl.combine_subblock_crcs(lacc, combine, k + m, s)
+    # named scopes: the module names (kernel_families.json matches
+    # those) stay; the scopes name the phases on the trace's XLA Ops
+    with jax.named_scope("ec.parity_crc_kernel"):
+        parity, lacc = _fused_hier_acc_call(
+            bitmat32, cmat_sub, adv, run_map, first_map, words, m, tile,
+            wb, nruns, interpret)
+    with jax.named_scope("ec.crc_combine"):
+        return parity, cl.combine_subblock_crcs(lacc, combine, k + m, s)
 
 
 _hier_acc = functools.partial(jax.jit, static_argnames=(
@@ -783,7 +797,8 @@ def _combine_run(lbits, block_bytes: int):
     """jit shell over combine_crcs_pow2 for the per-run folds of the
     extents path (cached per (shape, block_bytes))."""
     from . import crc32c_linear as cl
-    return cl.combine_crcs_pow2(lbits, block_bytes)
+    with jax.named_scope("ec.crc_combine"):
+        return cl.combine_crcs_pow2(lbits, block_bytes)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tile"))
@@ -793,20 +808,22 @@ def gf_encode_with_crc_xla(bitmat, cmat, chunks, m: int,
     from . import crc32c_linear as cl
     k, n = chunks.shape
     ntiles = n // tile
-    bits = _unpack_bits(chunks)                       # (8k, N)
-    prod = jax.lax.dot_general(
-        bitmat.astype(jnp.int8), bits,
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    ) & 1
-    parity = _pack_bits(prod, m)
+    with jax.named_scope("ec.parity_matmul"):
+        bits = _unpack_bits(chunks)                   # (8k, N)
+        prod = jax.lax.dot_general(
+            bitmat.astype(jnp.int8), bits,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        ) & 1
+        parity = _pack_bits(prod, m)
     # one batched crc contraction over every tile (program size
     # independent of ntiles — the old per-tile loop unrolled into the
     # program and made compile time scale with launch width)
-    d = cl.tile_crc_bits_tiled(bits, cmat, tile)             # (nt,k,32)
-    p = cl.tile_crc_bits_tiled(prod.astype(jnp.int8), cmat,
-                               tile)                         # (nt,m,32)
-    return parity, jnp.concatenate([d, p], axis=1)
+    with jax.named_scope("ec.crc_extract"):
+        d = cl.tile_crc_bits_tiled(bits, cmat, tile)         # (nt,k,32)
+        p = cl.tile_crc_bits_tiled(prod.astype(jnp.int8), cmat,
+                                   tile)                     # (nt,m,32)
+        return parity, jnp.concatenate([d, p], axis=1)
 
 
 def _hier_lsub_core(bitmat32, cmat_sub, words, m: int, tile: int,
@@ -819,10 +836,12 @@ def _hier_lsub_core(bitmat32, cmat_sub, words, m: int, tile: int,
     s = wt // wb
     r = k + m
     nt = wtot // wt
-    parity, lsub = _fused_hier_call(bitmat32, cmat_sub, words, m,
-                                    tile, wb, interpret)
-    lb = lsub.reshape(nt, r, s, 32).transpose(1, 0, 2, 3) \
-        .reshape(r, nt * s, 32)
+    with jax.named_scope("ec.parity_crc_kernel"):
+        parity, lsub = _fused_hier_call(bitmat32, cmat_sub, words, m,
+                                        tile, wb, interpret)
+    with jax.named_scope("ec.crc_relayout"):
+        lb = lsub.reshape(nt, r, s, 32).transpose(1, 0, 2, 3) \
+            .reshape(r, nt * s, 32)
     return parity, lb
 
 
@@ -936,7 +955,13 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
                     interpret=interpret, tile=tile, wb=wb,
                     combine=combine, donate=donate)))
             return {"split": parts, "n_runs": len(runs),
-                    "path": "+".join(h["path"] for _, h in parts)}
+                    "path": "+".join(h["path"] for _, h in parts),
+                    "padded_bytes": sum(h["padded_bytes"]
+                                        for _, h in parts),
+                    "h2d_bytes": sum(h["h2d_bytes"]
+                                     for _, h in parts),
+                    "d2h_bytes": sum(h["d2h_bytes"]
+                                     for _, h in parts)}
     # operating point: big sequential drains ride the hier-crc kernel at
     # the autotuned tile; small/mixed drains keep the flat 2 KiB tile
     # where padding waste would dominate
@@ -950,6 +975,12 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
     meta = []           # width per run
     pads = []           # front pad per run (accumulator path only)
     padded = []
+    # ec.h2d: the host-side pad/concatenate and the hand-over of the
+    # staged words to the device (jnp.asarray returns once the runtime
+    # holds the buffer; the DMA itself may still be in flight).  Like
+    # the launch's own span, on when the device profiler is
+    spans_on = device_profiler().enabled
+    h2d = spans.begin("ec.h2d", spans_on, runs=len(runs))
     for r in runs:
         w = r.shape[1]
         pad = -w % tile
@@ -982,14 +1013,24 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
             [big, np.zeros((k, pad_tiles * tile), dtype=np.uint8)],
             axis=1)
         ntiles_total = ntiles2
+    staged = jnp.asarray(
+        big.view("<u4").view(np.int32) if use_w32 and not force_xla
+        else big)
+    h2d.end()
     rows = _crc_rows(r_tot)
     w32_out = False
     lbits_devs = None
+    consts = []         # per-launch constant uploads (counted as H2D)
+    # ec.dispatch: constant matrices, the jitted call and the per-run
+    # combine dispatches — no host sync anywhere inside
+    dispatch = spans.begin("ec.dispatch", spans_on,
+                           padded_bytes=int(big.size))
     if force_xla:
         cmat = jnp.asarray(cl.crc_tile_matrix(tile))
+        consts.append(cmat)
         parity_dev, crc_bits = _aot_dispatch(
             "fused_xla", gf_encode_with_crc_xla,
-            (bitmat, cmat, jnp.asarray(big)), {"m": m, "tile": tile})
+            (bitmat, cmat, staged), {"m": m, "tile": tile})
         lb_all = jnp.transpose(crc_bits, (1, 0, 2))    # (r, ntiles, 32)
         block_bytes = tile
         path = "xla"
@@ -997,8 +1038,9 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         # byte-path Pallas kernel (TPU without the w32 layout): per-tile
         # L rows, device-combined per run below like the flat w32 path
         cmat = jnp.asarray(cl.crc_tile_matrix(tile))
+        consts.append(cmat)
         parity_dev, crc_flat = gf_encode_with_crc_pallas(
-            bitmat, cmat, jnp.asarray(big), m)
+            bitmat, cmat, staged, m)
         lb_all = jnp.transpose(
             crc_flat.reshape(ntiles_total, rows, 32)[:, :r_tot],
             (1, 0, 2))                                 # (r, ntiles, 32)
@@ -1009,7 +1051,6 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         # the launch itself — no per-step lsub round-trip, no per-run
         # combine dispatches, no sub-block host tail
         cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
-        words = big.view("<u4").view(np.int32)
         # the L out-block is keyed by run count: bucket it to a power
         # of two as well (pad tiles ride a dummy trailing run, empty
         # filler runs contribute no grid steps), so (tiles, runs) jit
@@ -1021,11 +1062,12 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         ntiles_run += [0] * (nruns_acc - len(ntiles_run))
         run_map, first_map, adv, comb = _acc_launch_args(
             ntiles_run, tile, wb)
+        consts += [cmat_sub, run_map, first_map, adv, comb]
         acc_fn = _hier_acc_donate if donate else _hier_acc
         parity_dev, lb = _aot_dispatch(
             "hier_acc_donate" if donate else "hier_acc", acc_fn,
             (bitmat32, cmat_sub, adv, comb, run_map, first_map,
-             jnp.asarray(words)),
+             staged),
             {"m": m, "tile": tile, "wb": wb, "nruns": nruns_acc,
              "interpret": interpret})                  # (nruns, r, 32)
         lbits_devs = [lb[i] for i in range(len(runs))]
@@ -1034,11 +1076,11 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         path = "hier_acc"
     elif hier:
         cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
-        words = big.view("<u4").view(np.int32)
+        consts.append(cmat_sub)
         hier_fn = _fused_hier_lsub_donate if donate else _fused_hier_lsub
         parity_dev, lb_all = _aot_dispatch(
             "hier_lsub_donate" if donate else "hier_lsub", hier_fn,
-            (bitmat32, cmat_sub, jnp.asarray(words)),
+            (bitmat32, cmat_sub, staged),
             {"m": m, "tile": tile, "wb": wb,
              "interpret": interpret})                  # (r, nsub, 32)
         block_bytes = 4 * wb
@@ -1047,10 +1089,10 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
     else:
         wt = tile // 4
         cmat32 = jnp.asarray(cl.crc_tile_matrix_w32(wt))
-        words = big.view("<u4").view(np.int32)
+        consts.append(cmat32)
         parity_dev, crc_flat = _aot_dispatch(
             "fused_w32", gf_encode_with_crc_pallas_w32,
-            (bitmat32, cmat32, jnp.asarray(words)),
+            (bitmat32, cmat32, staged),
             {"m": m, "interpret": interpret})
         lb_all = jnp.transpose(
             crc_flat.reshape(ntiles_total, rows, 32)[:, :r_tot],
@@ -1082,11 +1124,18 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
             else:
                 lbits_devs.append(None)
             coff += pr.shape[1]
+    dispatch.end()
     return {"meta": meta, "padded": padded, "pads": pads,
             "parity_dev": parity_dev, "lbits_devs": lbits_devs,
             "block_bytes": block_bytes, "r_tot": r_tot, "m": m,
             "w32_out": w32_out, "big_width": big.shape[1],
-            "path": path, "acc": acc}
+            "path": path, "acc": acc,
+            # exact counts for the launch queue's transfer counters
+            "padded_bytes": int(big.size),
+            "h2d_bytes": int(big.size)
+            + sum(int(c.nbytes) for c in consts),
+            "d2h_bytes": int(parity_dev.nbytes) + sum(
+                int(lb.nbytes) for lb in lbits_devs if lb is not None)}
 
 
 def gf_encode_extents_with_crc_finalize(handle):
@@ -1112,14 +1161,18 @@ def gf_encode_extents_with_crc_finalize(handle):
     r_tot = handle["r_tot"]
     block_bytes = handle["block_bytes"]
     acc = handle.get("acc", False)
-    parity_big = np.asarray(handle["parity_dev"])
+    # ec.d2h_wait: blocks until the device is done, then copies to the
+    # host — named for both, the host clock cannot part them
+    with spans.span("ec.d2h_wait", device_profiler().enabled):
+        parity_big = np.asarray(handle["parity_dev"])
+        lbits_host = [None if lb is None else np.asarray(lb)
+                      for lb in handle["lbits_devs"]]
     if handle["w32_out"]:
         parity_big = parity_big.view("<u4").view(np.uint8) \
             .reshape(handle["m"], handle["big_width"])
     out = []
     coff = 0
-    for w, pr, pad, lbits in zip(meta, padded, pads,
-                                 handle["lbits_devs"]):
+    for w, pr, pad, lbits in zip(meta, padded, pads, lbits_host):
         par = parity_big[:, coff + pad:coff + pad + w]
         if acc:
             body = w                     # kernel L covers the full run
@@ -1127,7 +1180,7 @@ def gf_encode_extents_with_crc_finalize(handle):
             nb = w // block_bytes        # full blocks = run body
             body = nb * block_bytes
         if lbits is not None:
-            l = cl.bits_to_u32(np.asarray(lbits))      # (k+m,) u32
+            l = cl.bits_to_u32(lbits)                  # (k+m,) u32
         else:
             l = np.zeros(r_tot, dtype=np.uint32)
         tail_data = pr[:, pad + body:pad + w]

@@ -114,9 +114,19 @@ def hit_count() -> int:
 
 
 def counters() -> dict:
-    """Monotonic compile counters of this process: `requests` backend
-    compile requests (each is a `hits` disk read or a `misses` real
-    compile) and the wall seconds they took together."""
+    """Monotonic compile counters of this process.
+
+    `requests` and `compile_s` come from jax.monitoring's duration
+    event '/jax/core/compile/backend_compile_duration', which jax
+    records around the WHOLE of compile_or_get_cached (pxla, both the
+    jit and the AOT `.lower().compile()` path): every backend compile
+    request adds its wall seconds, whether the persistent cache then
+    called it a hit (a disk read plus deserialization — which on
+    another machine's cache can itself recompile), a miss (a real
+    compile, stored) or was not consulted.  So `compile_s` cannot be
+    fooled by a "hit" that still compiles; `hits` / `misses` (the
+    events '/jax/compilation_cache/cache_hits' / 'cache_misses') only
+    say what the cache called each request."""
     return {"requests": _requests, "hits": _hits, "misses": _misses,
             "compile_s": round(_compile_s, 3)}
 

@@ -14,7 +14,9 @@ inference serving stack keeps for its accelerators:
   encode, recovery decode, CLAY repair, mesh batch, deep-scrub CRC)
   gets a monotonic launch id and a `LaunchRecord`: kind, codec label,
   jit-bucket key, runs, input bytes, queue wait, submit wall time,
-  submit->materialize device time, PG mix, and the trace ids of the
+  the submit->materialize wait on the host clock (`device_ms`: it
+  blocks on the device and copies back — device time proper is in a
+  profiler trace, where `lq.finalize` is a row), PG mix, and the trace ids of the
   contributing ops (PR 4 stitching).  Completed records live in a
   bounded ring (`launch profile` asok); `lat_launch_submit` /
   `lat_launch_device` / `lat_launch_queue_wait` histograms share
@@ -40,8 +42,8 @@ inference serving stack keeps for its accelerators:
   (conf osd_ec_profiler); disabled, `begin()` returns None after one
   attribute check and every other entry point no-ops on a None
   record, so the off path allocates nothing (the NULL_TRACKED rule).
-  The on-path cost is one record per LAUNCH (not per op) and is gated
-  ≤2% in bench.py --smoke like PR 4's tracking overhead.
+  The on-path cost is one record per LAUNCH (not per op); what it
+  costs is measured on the chip (PERF.md §6).
 
 `inject_stall_s` (conf osd_ec_inject_compile_stall) is the fault
 injection the gates use: a positive value sleeps that long inside the
@@ -86,7 +88,9 @@ def _build_prof_perf(name: str = "device_profiler"):
                            "launch dispatch wall time (includes the "
                            "compile on a bucket's first hit)")
             .add_histogram("lat_launch_device",
-                           "submit -> materialize device time")
+                           "submit -> materialize wait on the HOST "
+                           "clock (blocks on the device, then copies); "
+                           "device time is in a profiler trace")
             .add_histogram("lat_launch_queue_wait",
                            "host-queue batching wait before launch")
             .add_histogram("lat_prewarm",

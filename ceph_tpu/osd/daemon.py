@@ -28,6 +28,8 @@ import time
 
 import numpy as np
 
+from ..common import spans
+from ..common.spans import span
 from ..common.tracked_op import NULL_TRACKED, OpTracker, TraceContext
 from ..crush.hash import crush_hash32
 from ..ec import ErasureCodeError, ErasureCodePluginRegistry, Profile
@@ -122,7 +124,7 @@ class MessengerShardBackend(ShardBackend):
             # (try_finish_rmw -> check_ops -> possibly a BLOCKING
             # probe() whose stat replies must be delivered by this
             # very loop) — always punt to the dispatch executor.
-            Messenger.submit_dispatch(on_commit, shard)
+            Messenger.submit_dispatch("ec.on_commit", on_commit, shard)
 
     # -- reads --------------------------------------------------------------
 
@@ -182,7 +184,8 @@ class MessengerShardBackend(ShardBackend):
             else:
                 # RMW pre-reads continue the write pipeline (decode +
                 # encode + possibly blocking probe()): off the loop
-                Messenger.submit_dispatch(on_done, shard, data)
+                Messenger.submit_dispatch("ec.on_read_done", on_done,
+                                          shard, data)
 
     # -- sync metadata RPCs -------------------------------------------------
 
@@ -723,6 +726,12 @@ class OSDDaemon:
             _mled._perf_registered = True
             self._msgr_reporter = True
             self.cct.perf.add(_mled.perf)
+        # thread-executed spans (common/spans.py): one table per
+        # process, exported by ONE daemon under the same rule
+        _hs = spans.host_spans()
+        if not getattr(_hs, "_perf_registered", False):
+            _hs._perf_registered = True
+            self.cct.perf.add(_hs)
         # fast dispatch (reference ms_fast_dispatch): the EC data-path
         # RPCs run inline on the reactor — their handlers never block
         # on nested RPCs (shard read = store read + async send; the
@@ -2612,7 +2621,8 @@ class OSDDaemon:
 
     def apply_shard_txn(self, spg: spg_t, txn: Transaction) -> None:
         with self._split_lock:
-            self.store.queue_transactions(self._cid(spg), [txn])
+            with span("store.commit", self.op_tracker.enabled, pgid=spg):
+                self.store.queue_transactions(self._cid(spg), [txn])
             self._migrate_misplaced(spg, self._txn_hobjs(txn))
 
     def _shard_log(self, spg: spg_t):
@@ -2630,6 +2640,14 @@ class OSDDaemon:
         """Shard write + atomic log persistence (reference
         ECBackend::handle_sub_write, ECBackend.cc:915: the log entries
         ride the same ObjectStore transaction as the data)."""
+        # osd.* / store.* spans are on when the op tracker is
+        with span("osd.sub_write_apply", self.op_tracker.enabled,
+                  pgid=spg):
+            self._apply_sub_write(spg, txn, wire_entries, at_version,
+                                  rollforward_to)
+
+    def _apply_sub_write(self, spg, txn, wire_entries, at_version,
+                         rollforward_to) -> None:
         from .pg_log import entry_from_wire
         if not wire_entries:
             self.apply_shard_txn(spg, txn)
@@ -2638,7 +2656,8 @@ class OSDDaemon:
         with self._split_lock:
             slog = self._shard_log(spg)
             slog.append_to_txn(txn, entries, at_version)
-            self.store.queue_transactions(self._cid(spg), [txn])
+            with span("store.commit", self.op_tracker.enabled, pgid=spg):
+                self.store.queue_transactions(self._cid(spg), [txn])
             slog.record(entries, at_version)
             from .ec_util import refresh_chunk_crcs
             refresh_chunk_crcs(self.store, self._cid(spg), spg.shard,
@@ -3023,11 +3042,18 @@ class OSDDaemon:
         every failure path)."""
         top = getattr(msg, "top", NULL_TRACKED)
         top.mark_event("dequeued")
+        # osd.op_prepare: from here to the pipeline entry (be.enqueue /
+        # submit_transaction close it); an op that never gets there
+        # (read, rejection, error) closes it in the finally
+        msg.prep_span = spans.begin(
+            "osd.op_prepare", trace_id=top.trace.trace_id) \
+            if top.is_tracked else None
         try:
             self._handle_client_op(conn, msg)
         except Exception as e:  # noqa: BLE001 - must reply, not die
             self._reply_op_error(conn, msg, e)
         finally:
+            spans.end(msg.prep_span)
             # idempotent: the write/read paths unregister with their
             # result; this net catches early-return paths (snap
             # reads, watch control ops, caps/blacklist rejections)
@@ -3464,6 +3490,7 @@ class OSDDaemon:
             with state.lock:
                 version = state.next_version(self.osdmap.epoch)
                 top.set_info("version", str(version))
+                spans.end(getattr(msg, "prep_span", None))
                 if staged is not None:
                     be.enqueue(staged, version)
                 else:
@@ -3483,9 +3510,11 @@ class OSDDaemon:
         elif result == 0:
             self.perf.inc("op_r")
         self.perf.tinc("op_latency", time.perf_counter() - _t0)
-        top.mark_event("reply_sent")
+        sent_ts = time.time()
+        top.mark_event("reply_sent", sent_ts)
         conn.send_message(M.MOSDOpReply(msg.tid, result, read_payload,
-                                        self.osdmap.epoch))
+                                        self.osdmap.epoch,
+                                        sent_ts=sent_ts))
         self.op_tracker.unregister(top, result)
 
     def _arm_batch_drain(self, be, window_ms: float) -> None:
@@ -3987,7 +4016,10 @@ class OSDDaemon:
         recorder's launch ledger — aggregates, lat_launch_* percentile
         summaries, and the bounded ring of recent launches (each with
         launch id, jit bucket, runs/bytes/pg-mix, queue-wait, submit
-        and device times, and the contributing ops' trace ids)."""
+        time, `device_ms` — the submit -> materialize wait on the HOST
+        clock; device time proper is in a profiler trace, where the
+        launch is the `lq.launch` / `lq.finalize` rows with the same
+        id — and the contributing ops' trace ids)."""
         out = self._profiler.profile(
             last=int(cmd["last"]) if "last" in cmd else None)
         out["osd"] = self.osd_id
@@ -4129,7 +4161,7 @@ class OSDDaemon:
         conf = self.cct.conf
         last_deep = time.time()
         interval = float(conf.get("osd_scrub_interval"))
-        while not self._hb_stop.wait(interval):
+        for _ in self._ticks("scrub", lambda: interval):
             try:
                 interval = float(conf.get("osd_scrub_interval"))
                 deep_iv = float(conf.get("osd_deep_scrub_interval"))
@@ -4176,7 +4208,7 @@ class OSDDaemon:
         and send one clearing report when the last slow op ages out so
         the warning retires."""
         last = 0
-        while not self._hb_stop.wait(self._optrack_interval()):
+        for _ in self._ticks("optrack", self._optrack_interval):
             try:
                 if not self.op_tracker.enabled:
                     if last:
@@ -4295,8 +4327,8 @@ class OSDDaemon:
 
     def _pgstats_loop(self) -> None:
         conf = self.cct.conf
-        while not self._hb_stop.wait(
-                float(conf.get("osd_pg_stat_interval") or 0.5)):
+        for _ in self._ticks("pgstats", lambda: float(
+                conf.get("osd_pg_stat_interval") or 0.5)):
             try:
                 rep = self._compile_pg_stats()
                 self.perf.set("pg_degraded", rep["degraded_pgs"])
@@ -4360,8 +4392,18 @@ class OSDDaemon:
                 f"(loop starved: first-bucket compile / load?)")
         return lag
 
+    def _ticks(self, loop: str, interval):
+        """One `yield` a period until shutdown, the body it drives
+        running inside the span `osd.tick.<loop>`; `interval()` is
+        asked anew before every wait."""
+        name = "osd.tick." + loop
+        while not self._hb_stop.wait(interval()):
+            with span(name, self.op_tracker.enabled):
+                yield
+
     def _heartbeat_loop(self) -> None:
-        while not self._hb_stop.wait(self.heartbeat_interval):
+        for _ in self._ticks("heartbeat",
+                             lambda: self.heartbeat_interval):
             self._note_hb_tick_lag(time.perf_counter())
             now = time.time()
             # mon keepalive + hunting: no map traffic for too long means

@@ -45,6 +45,7 @@ from typing import Callable
 
 import numpy as np
 
+from ..common.spans import span
 from ..common.tracked_op import NULL_TRACKED
 from ..ec.interface import ErasureCodeError, ErasureCodeInterface
 from ..ops.profiler import device_profiler
@@ -465,6 +466,10 @@ class ECBackend:
                 "ec_fused_kernel_drains"
                 if path and path.startswith("hier")
                 else "ec_fused_fallback_drains")
+            # the same drain under the kernel's own name: a 4 KiB
+            # drain on the flat w32 kernel is a fused drain too, not
+            # a fallback to anything
+            self.perf.dinc(f"ec_drains_by_path.{path or 'none'}")
 
     def repair_status(self) -> dict:
         """Per-PG repair state (surfaced by the OSD's `repair status`
@@ -834,13 +839,26 @@ class ECBackend:
     # -- submit half: assemble + launch, NO host sync -----------------------
 
     def _submit_drain(self, ready: list[ECOp]) -> _Drain:
+        """The submit half inside its span (`ec.assemble`, whose
+        duration is also the `ec_drain_assemble` sample)."""
+        # ec.* spans are on when the device profiler is; off, `sp`
+        # still times the `ec_drain_assemble` sample
+        with span("ec.assemble", device_profiler().enabled,
+                  pgid=self.perf.name) as sp:
+            drain = self._assemble_and_launch(ready)
+        if drain.work:
+            drain.t_assemble = sp.wall_s
+            if self.perf:
+                self.perf.inc("ec_drain_extents", len(drain.work))
+                self.perf.tinc("ec_drain_assemble", drain.t_assemble)
+        return drain
+
+    def _assemble_and_launch(self, ready: list[ECOp]) -> _Drain:
         """Gather every extent of every ready op, encode the whole
         drain with launches that return device futures (one fused
         launch for appends + one plain launch for overwrites), and
         record the in-flight drain.  Nothing here blocks on the
         device; materialization happens in _complete_drain."""
-        import time as _time
-        t0 = _time.perf_counter()
         k = self.k
         work: list[tuple] = []
         runs: list[np.ndarray] = []
@@ -1051,10 +1069,6 @@ class ECBackend:
         self.batched_launches += 1 + (1 if fused_idx and plain_idx
                                       else 0)
         self.batched_extents += len(work)
-        drain.t_assemble = _time.perf_counter() - t0
-        if self.perf:
-            self.perf.inc("ec_drain_extents", len(work))
-            self.perf.tinc("ec_drain_assemble", drain.t_assemble)
         return drain
 
     def _drain_pipeline(self) -> None:
@@ -1098,6 +1112,11 @@ class ECBackend:
                 self._sim_chunk.pop(oid, None)
 
     def _complete_drain(self, drain: _Drain) -> None:
+        with span("ec.complete", device_profiler().enabled,
+                  pgid=self.perf.name):
+            self._materialize_and_commit(drain)
+
+    def _materialize_and_commit(self, drain: _Drain) -> None:
         import time as _time
         t0 = _time.perf_counter()
         prof = device_profiler()
@@ -1339,9 +1358,16 @@ class ECBackend:
                 op.version, oid,
                 LogOp.DELETE if objop.delete else LogOp.MODIFY, rb))
             entries.append(self.log.entries[-1])
-        txns, _ = ect.generate_transactions(
-            self.sinfo, self.n, op.plan, op.txn, encoded, crcs,
-            gen=op.version.version, gen_oids=gen_oids)
+        with span("ec.fanout", device_profiler().enabled,
+                  trace_id=op.top.trace.trace_id
+                  if op.top.is_tracked else ""):
+            txns, _ = ect.generate_transactions(
+                self.sinfo, self.n, op.plan, op.txn, encoded, crcs,
+                gen=op.version.version, gen_oids=gen_oids)
+            self._fan_out(op, entries, txns)
+
+    def _fan_out(self, op: ECOp, entries: list, txns: list) -> None:
+        """Send the k+m shard transactions (`sub_write_sent`)."""
         op.state = "committing"
         op.pending_commits = self.n
         self.waiting_commit.append(op)

@@ -15,6 +15,7 @@ import errno
 import threading
 import time
 
+from ..common.perf_counters import PerfCountersBuilder
 from ..common.tracked_op import OpTracker, TraceContext
 from ..msg import Messenger
 from ..msg import messages as M
@@ -55,6 +56,24 @@ class Objecter:
         # sub-ops branch children) — `dump_historic_ops` on this
         # tracker shows client-observed latency per op
         self.op_tracker = OpTracker(complaint_time=30.0)
+        # the client's own counters (reference l_osdc_*): the only
+        # place the client-side half of an op's latency can be read
+        self.perf = (
+            PerfCountersBuilder("objecter")
+            .add_u64_counter("op_send", "MOSDOp frames handed to the "
+                             "messenger (first sends and resends)")
+            .add_u64_counter("op_resend", "sends after an op's first "
+                             "(EAGAIN retarget, attempt timeout)")
+            .add_u64_counter("op_reply", "ops that returned a reply "
+                             "to the caller")
+            .add_u64_counter("op_timeout", "attempts that got no reply "
+                             "within the op timeout")
+            .add_histogram("lat_op", "op_submit entry -> reply in the "
+                           "caller's hands")
+            .add_histogram("lat_reply_leg", "the primary's reply_sent "
+                           "stamp -> the waiter awake (wire + reactor "
+                           "+ thread wake-up; time.time() both ends)")
+            .create_perf_counters())
         self._waiters: dict[int, dict] = {}
         self._mon_waiters: dict[int, dict] = {}
         self._auth_waiters: dict[int, dict] = {}
@@ -240,6 +259,8 @@ class Objecter:
                             attempts, snapc, oid, trace, top,
                             qos_class=None) -> M.MOSDOpReply:
         last_err = None
+        t_submit = time.perf_counter()
+        sends = 0
         # EAGAIN (not-primary / peering-incomplete) replies arrive in
         # milliseconds now that the OSD fences every op path; they ride
         # a short backoff BUDGET instead of the attempt counter, or a
@@ -275,8 +296,15 @@ class Objecter:
                                        self.osdmap.epoch, snapc=snapc,
                                        trace=trace.to_wire(),
                                        qos=qos_class))
+            self.perf.inc("op_send")
+            if sends:
+                self.perf.inc("op_resend")
+            sends += 1
             if w["event"].wait(timeout):
                 reply = w["reply"]
+                if reply.sent_ts is not None:
+                    self.perf.hinc("lat_reply_leg", max(
+                        0.0, time.time() - reply.sent_ts))
                 if reply.epoch > self.osdmap.epoch and \
                         not self._map_nudge_pending:
                     # the OSD is on a newer map (e.g. a pool's pg_num
@@ -313,10 +341,14 @@ class Objecter:
                     continue
                 top.mark_event("reply")
                 self.op_tracker.unregister(top, reply.result)
+                self.perf.inc("op_reply")
+                self.perf.hinc("lat_op",
+                               time.perf_counter() - t_submit)
                 return reply
             with self._lock:
                 self._waiters.pop(tid, None)
             top.mark_event("attempt_timeout")
+            self.perf.inc("op_timeout")
             self.refresh_map()
             last_err = -errno.ETIMEDOUT
             attempt += 1

@@ -60,6 +60,7 @@ import time
 
 import numpy as np
 
+from ..common import spans
 from ..common.util import next_pow2
 from ..ops.profiler import device_profiler
 
@@ -265,6 +266,18 @@ def _build_queue_perf(name: str):
                              "extent runs coalesced into launches")
             .add_u64_counter("ec_host_launch_bytes",
                              "input bytes coalesced into launches")
+            .add_u64_counter("ec_host_launch_padded_bytes",
+                             "bytes of the shapes actually handed to "
+                             "the fused jit: after per-run tile padding "
+                             "and the pow2 tile-count bucket")
+            .add_u64_counter("ec_h2d_bytes",
+                             "host bytes handed to the device by fused "
+                             "launches (staged data + per-launch "
+                             "constant matrices)")
+            .add_u64_counter("ec_d2h_bytes",
+                             "bytes fused launches read back to the "
+                             "host (parity + crc L-bits), counted at "
+                             "submit from the result shapes")
             .add_u64_counter("ec_host_launch_pg_mix",
                              "sum of distinct submitters per launch")
             .add_u64_counter("ec_host_cross_pg_launches",
@@ -613,6 +626,11 @@ class ECLaunchQueue:
             pg_mix=len({s.owner for s in subs}),
             traces=[t for s in subs for t in s.traces],
             queue_wait_s=batch.queue_wait)
+        # the launch's span rides the recorder's begin (on when it is);
+        # the retries of the containment path stay inside it
+        sp = None if rec is None else spans.begin(
+            "lq.launch", launch=rec.launch_id, runs=rec.runs,
+            bytes=rec.nbytes)
         bucket = None
         try:
             plugin = subs[0].plugin
@@ -621,6 +639,17 @@ class ECLaunchQueue:
                 handle = plugin.encode_extents_with_crc_submit(all_runs)
                 batch.path = handle.get("path") \
                     if isinstance(handle, dict) else None
+                if isinstance(handle, dict) and "padded_bytes" in handle:
+                    padded = handle["padded_bytes"]
+                    if self.perf:
+                        # what the launch hands over and will read
+                        # back, known from the shapes at submit
+                        self.perf.inc("ec_h2d_bytes",
+                                      handle["h2d_bytes"])
+                        self.perf.inc("ec_d2h_bytes",
+                                      handle["d2h_bytes"])
+                else:
+                    padded = sum(s.nbytes for s in subs)
                 # plugins that know their real jit-key axes (the jax
                 # plugin's autotuned operating point) refine the bucket
                 bucket = plugin.launch_bucket(handle) \
@@ -636,6 +665,7 @@ class ECLaunchQueue:
                 sig = abs(hash(tuple(plugin.signature))) & 0xFFFFFF
                 bucket = f"r:{sig:x}:w{big.shape[1]}"
                 handle = ("np", np.asarray(plugin.apply_device(big)))
+                padded = int(big.size)
             elif kind == "d":
                 # recovery/reconstruct decode: erasure patterns match
                 # within a key, so the concatenated dense array decodes
@@ -662,6 +692,7 @@ class ECLaunchQueue:
                 bucket = f"d:e{era}:w{big.shape[1]}"
                 handle = ("np", np.asarray(plugin.decode_chunks(
                     big, list(subs[0].extra))))
+                padded = int(big.size)
             else:
                 bigs = [s.runs[0] for s in subs]
                 big = np.concatenate(bigs, axis=1) if len(bigs) > 1 \
@@ -688,6 +719,11 @@ class ECLaunchQueue:
                     # host matmuls — the CPU analog of occupancy)
                     handle = ("np", np.asarray(plugin.encode_chunks(big)))
                 bucket = f"c:{handle[0]}:w{big.shape[1]}"
+                padded = int(big.size)
+            if self.perf:
+                # beside ec_host_launch_bytes, for every kind: what
+                # the launch was handed after padding and bucketing
+                self.perf.inc("ec_host_launch_padded_bytes", padded)
             batch.combined = (plugin, handle)
             # host-synchronous launches (pure-CPU plugin encode/
             # decode: handle kind "np" on a plugin without a jitted
@@ -748,6 +784,7 @@ class ECLaunchQueue:
                     s.ticket._done = True
                     batch.per_sub.append((s, None))
         finally:
+            spans.end(sp)
             for s in subs:
                 s.runs = None   # the launch holds the staged arrays now
             batch.launch_done.set()
@@ -770,7 +807,9 @@ class ECLaunchQueue:
         with batch.lock:
             if batch.finalized:
                 return
-            t_mat = time.perf_counter()
+            rec = batch.prof_rec
+            sp = None if rec is None else spans.begin(
+                "lq.finalize", launch=rec.launch_id)
             try:
                 if batch.per_sub is not None:
                     for sub, handle in batch.per_sub:
@@ -814,10 +853,13 @@ class ECLaunchQueue:
                         sub.ticket._done = True
             finally:
                 batch.finalized = True
-                # ledger: submit -> materialize is the device time
-                # (the first finalizer blocks on the futures here)
-                device_profiler().materialized(
-                    batch.prof_rec, time.perf_counter() - t_mat)
+                # ledger: the first finalizer blocks on the device
+                # futures here, then copies to the host — a HOST-clock
+                # wait (`lat_launch_device`); device time is in the
+                # profiler trace, where this span is a row
+                if sp is not None:
+                    sp.end()
+                    device_profiler().materialized(rec, sp.wall_s)
 
     def _finalize_sub(self, kind: str, sub: _Sub, handle) -> None:
         if kind == "x":

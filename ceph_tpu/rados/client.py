@@ -35,6 +35,11 @@ class RadosClient:
         self.objecter.start()
         return self
 
+    def perf_dump(self) -> dict:
+        """The client's `perf dump`: {"objecter": {op_send, op_resend,
+        op_reply, op_timeout, lat_op, lat_reply_leg}}."""
+        return {self.objecter.perf.name: self.objecter.perf.dump()}
+
     def shutdown(self) -> None:
         self._pool.shutdown(wait=False)
         self.objecter.shutdown()

@@ -14,6 +14,7 @@ import argparse
 import glob
 import http.server
 import os
+import re
 import sys
 
 from ..common.perf_counters import (LATENCY_QUANTILES,
@@ -26,6 +27,18 @@ from ..common.perf_counters import (LATENCY_QUANTILES,
 _PROM_TYPE = {"u64": "counter", "gauge": "gauge",
               "time": "counter", "avg": "counter",
               "hist": "histogram"}
+
+# A perf key is any string (span names carry dots: `lq.launch_cpu`,
+# `ec_drains_by_path.hier_acc+w32_flat`); a prometheus metric name is
+# [a-zA-Z_:][a-zA-Z0-9_:]*, and one bad name fails the whole scrape.
+# Every other character becomes `_` (the reference module's
+# promethize()).
+_NOT_NAME = re.compile(r"[^a-zA-Z0-9_]")
+
+
+def prom_name(key: str) -> str:
+    return "ceph_tpu_" + _NOT_NAME.sub("_", key)
+
 
 # Cumulative scrape failures per daemon, for the whole exporter
 # process lifetime: a daemon whose asok stops answering must be
@@ -78,7 +91,7 @@ def collect(asok_dir: str) -> str:
             gschema = schema.get(group, {}) if isinstance(schema, dict) \
                 else {}
             for key, val in counters.items():
-                name = f"ceph_tpu_{key}"
+                name = prom_name(key)
                 ctype = gschema.get(key)
                 labels = f'{{daemon="{daemon}",group="{group}"}}'
                 if isinstance(val, dict) and "buckets" in val:
@@ -108,9 +121,9 @@ def collect(asok_dir: str) -> str:
                     emit_type(f"{name}_sum", ctype)
                     emit_type(f"{name}_count", ctype)
                     lines.append(
-                        f'ceph_tpu_{key}_sum{labels} {val.get("sum", 0)}')
+                        f'{name}_sum{labels} {val.get("sum", 0)}')
                     lines.append(
-                        f'ceph_tpu_{key}_count{labels} '
+                        f'{name}_count{labels} '
                         f'{val.get("avgcount", 0)}')
                 else:
                     emit_type(name, ctype)
