@@ -102,8 +102,10 @@ PHASE_ANCHORS: dict[str, tuple] = {
         ("wire_in", "initiated"),       # objecter_submit -> handler
         #                                 start, through msgr_recv_lag
         ("queue_wait", "dequeued"),     # op queue / op pool
-        ("prepare", "ec_encode_launch"),    # decode, metadata probe,
-        #                                 locks, assemble, launch submit
+        ("prepare", "ec_encode_launch"),    # decode, metadata probe
+        #                                 (local on a clean PG, else a
+        #                                 k+m-1 fan-out), locks,
+        #                                 assemble, launch submit
         ("encode", "ec_encode_materialize"),    # launch-queue wait +
         #                                 H2D + device + D2H
         ("fanout_commit", None),        # k+m sub-writes, acks, commit,

@@ -90,13 +90,20 @@ class MessengerShardBackend(ShardBackend):
             # min_size), mirroring the reference's split between
             # PeeringState min_size gating and degraded-write tolerance.
             self.degraded_shards.add(shard)
+            self.daemon._pg_unclean(self.pgid)
             on_commit(shard)
             return
         wire_entries = [entry_to_wire(e) for e in (log_entries or [])]
         if osd == self.daemon.osd_id:
-            self.daemon.apply_sub_write(spg, txn, wire_entries,
-                                        at_version or eversion_t(),
-                                        rollforward_to)
+            try:
+                self.daemon.apply_sub_write(spg, txn, wire_entries,
+                                            at_version or eversion_t(),
+                                            rollforward_to)
+            except Exception:
+                # peers may hold what this shard now lacks: the local
+                # shard stops answering for the PG (probe below)
+                self.daemon._pg_unclean(self.pgid)
+                raise
             on_commit(shard)
             return
         tid = self._next_tid()
@@ -124,7 +131,16 @@ class MessengerShardBackend(ShardBackend):
             # (try_finish_rmw -> check_ops -> possibly a BLOCKING
             # probe() whose stat replies must be delivered by this
             # very loop) — always punt to the dispatch executor.
-            Messenger.submit_dispatch("ec.on_commit", on_commit, shard)
+            args = (shard,)
+            if msg.result != 0:
+                # the holder fenced the write (OSDDaemon.
+                # _stale_interval_write): this OSD no longer leads the
+                # PG there — the client retries on its refreshed map
+                args += (ErasureCodeError(
+                    errno.EAGAIN, f"shard {shard} of {self.pgid} "
+                    f"refused the write ({msg.result}): interval "
+                    f"changed"),)
+            Messenger.submit_dispatch("ec.on_commit", on_commit, *args)
 
     # -- reads --------------------------------------------------------------
 
@@ -223,15 +239,21 @@ class MessengerShardBackend(ShardBackend):
         raw = reply.attrs.get(HINFO_KEY)
         return HashInfo.decode(raw) if raw else None
 
-    def probe(self, oid, n):
-        """(hinfo, shard size) in ONE metadata round: the local shard
-        answers without touching the wire (hinfo rides every shard, so
-        steady-state writes cost ZERO metadata RPCs), and only a miss
-        fans out to the remaining shards CONCURRENTLY — one RTT where
-        the sequential sweep cost n (the dominant per-op latency in
-        the end-to-end write path)."""
+    def probe(self, oid, n, repair=False):
+        """(hinfo, shard size) in at most ONE metadata round.  The
+        local shard answers first, without the wire: hinfo rides every
+        shard, so an overwrite costs zero metadata RPCs — and while the
+        PG is clean for its interval (OSDDaemon._pg_clean_for_interval)
+        so does a create or a read of a missing object, because a shard
+        this primary lacks no peer has either.  In every other state,
+        and for a caller repairing an object a listing found (`repair`:
+        the local miss may be the very damage), a local miss fans out
+        to the remaining shards CONCURRENTLY — one RTT where the
+        sequential sweep cost n."""
+        perf = self.perf
         hinfo = None
         size = None
+        local_miss = False
         remote = []
         for s in range(n):
             osd = self._osd_for(s)
@@ -246,10 +268,20 @@ class MessengerShardBackend(ShardBackend):
                         hinfo = HashInfo.decode(raw)
                     if reply.size >= 0:
                         size = reply.size
+                else:
+                    local_miss = True
             else:
                 remote.append((s, osd))
-        if hinfo is not None or not remote:
+        if hinfo is not None:
+            perf.inc("ec_probe_local_hits")
             return hinfo, size
+        if not remote:
+            return hinfo, size
+        if local_miss and size is None and not repair and \
+                self.daemon._pg_clean_for_interval(self.pgid, self, oid):
+            perf.inc("ec_probe_local_authoritative_misses")
+            return None, None
+        perf.inc("ec_probe_remote_sweeps")
         box: dict = {}
         ev = threading.Event()
         pending = {"n": len(remote)}
@@ -275,6 +307,7 @@ class MessengerShardBackend(ShardBackend):
                 self.daemon.conn_to_osd(osd).send_message(
                     M.MOSDECSubOpRead(spg, tid, oid, 0, 0,
                                       want_attrs=True))
+                perf.inc("ec_probe_remote_reads")
             except Exception:  # noqa: BLE001 - unreachable peer
                 with self.lock:
                     pending["n"] -= 1
@@ -376,10 +409,30 @@ class PGState:
         # serving (reference PeeringState: no ops until Active)
         self.needs_peer = True
         self.peer_lock = threading.Lock()
+        # clean for the current interval (docs/PIPELINE.md
+        # "Authoritative local shard"): interval_gen counts every
+        # event that starts an interval or casts doubt on this one
+        # (OSDDaemon._pg_unclean); clean_gen is the interval_gen under
+        # which a recovery pass last finished with every acting shard
+        # holding every object.  Equal = the primary's own shard
+        # answers for the PG (OSDDaemon._pg_clean_for_interval)
+        self.interval_gen = 0
+        self.clean_gen = -1
+        self._gen_lock = threading.Lock()   # leaf: guards the count
+        # every live shard acknowledged the last peering round's
+        # MPGActivate: from then on its holder refuses sub-writes of
+        # older intervals (OSDDaemon._stale_interval_write)
+        self.activated_all = False
         # head SnapSet seq cache: steady-state writes under an
         # unchanged SnapContext skip the attrs fetch (only this
         # primary mutates heads, so the cache is authoritative)
         self.snap_seqs: dict = {}
+
+    def unclean(self) -> None:
+        """Start a new interval_gen: no pass that began before this
+        call can make the PG clean."""
+        with self._gen_lock:
+            self.interval_gen += 1
 
     def next_version(self, epoch: int) -> eversion_t:
         with self.lock:
@@ -679,6 +732,10 @@ class OSDDaemon:
         # _merge_source_pgs), so an OSD that was down across the
         # shrink routes, folds, and recovers identically after revive.
         self.raw_read_waiters: dict = {}
+        # shard spg -> the OSD whose peering last activated it here
+        # (in memory: a restarted holder fences nothing until the
+        # interval's primary peers it again)
+        self._activated_by: dict[spg_t, int | None] = {}
         # shard-resident replicated PG logs (reference: pglog omap keys
         # in the pg meta collection) + peering RPC plumbing
         self.shard_logs: dict = {}
@@ -940,6 +997,14 @@ class OSDDaemon:
                         pass
                 else:
                     stop = NULL_TRACKED
+                if msg.log_entries and \
+                        self._stale_interval_write(conn, msg):
+                    stop.mark_event("failed")
+                    conn.send_message(M.MOSDECSubOpWriteReply(
+                        msg.pgid, msg.tid, msg.pgid.shard,
+                        -errno.ESTALE))
+                    self.op_tracker.unregister(stop, -errno.ESTALE)
+                    return
                 try:
                     self.apply_sub_write(msg.pgid, msg.txn,
                                          msg.log_entries,
@@ -965,7 +1030,7 @@ class OSDDaemon:
                     msg.pgid, msg.tid,
                     [M.hobj_to_json(o) for o in removed]))
             elif isinstance(msg, M.MPGActivate):
-                self._handle_activate(msg)
+                self._handle_activate(msg, self._peer_osd(conn))
                 conn.send_message(M.MPGActivateReply(msg.pgid, msg.tid))
             elif isinstance(msg, (M.MPGLogReply, M.MPGLogRollbackReply,
                                   M.MPGActivateReply)):
@@ -1146,6 +1211,7 @@ class OSDDaemon:
                 if hasattr(shards, "acting"):
                     if list(acting) != list(shards.acting):
                         state.needs_peer = True
+                        state.unclean()
                         self.pg_ledger.transition(
                             pgid, "interval_change",
                             epoch=newmap.epoch)
@@ -1210,6 +1276,7 @@ class OSDDaemon:
                         continue
                     if primary == self.osd_id:
                         self._pgs_needing_recovery.add(pgid)
+                        self._pg_unclean(pgid)
                         self.pg_ledger.transition(
                             pgid, "needs_recovery",
                             epoch=newmap.epoch)
@@ -1370,6 +1437,7 @@ class OSDDaemon:
                         cur_primary = self.osd_id
                     if cur_primary == self.osd_id or cur_primary < 0:
                         self._pgs_needing_recovery.add(pgid)
+                        self._pg_unclean(pgid)
                     self.cct.dout("osd", 2,
                                   f"recovery of {pgid} deferred: {e}")
 
@@ -1498,6 +1566,8 @@ class OSDDaemon:
         try:
             self.conn_to_osd(osd).send_message(M.MPGList(spg, tid))
         except Exception:  # noqa: BLE001
+            if unreachable is not None:
+                unreachable.add(osd)
             return []
         if not ev.wait(timeout) and unreachable is not None:
             unreachable.add(osd)
@@ -1630,6 +1700,7 @@ class OSDDaemon:
         if state.kind != "ec":
             return
         be = state.backend
+        clean_gen = state.interval_gen   # the interval this pass vouches for
         pool = self.osdmap.pools.get(pgid.pool)
         if pool is None:
             return
@@ -1758,20 +1829,31 @@ class OSDDaemon:
                     all_ok = False
         if all_ok:
             self._pgs_needing_recovery.discard(pgid)
-            self._note_pg_redundancy(pgid, acting, be.n)
+            if self._note_pg_redundancy(pgid, acting, be.n) and \
+                    state.activated_all and \
+                    list(acting) == list(be.shards.acting) and \
+                    not unreachable.intersection(acting):
+                # clean for the interval the pass started in: every
+                # object any acting shard listed is now on all of them,
+                # this primary's included.  An interval_gen that moved
+                # on meanwhile leaves the fact unset
+                be.shards.degraded_shards.clear()
+                state.clean_gen = clean_gen
         else:
             self._pgs_needing_recovery.add(pgid)
+            self._pg_unclean(pgid)
             self.pg_ledger.transition(pgid, "recovery_deferred",
                                       epoch=self.osdmap.epoch)
             self.pg_ledger.degraded_open(pgid)
 
     def _note_pg_redundancy(self, pgid: pg_t, acting: list[int],
-                            width: int) -> None:
+                            width: int) -> bool:
         """After a clean recovery pass: a shard slot with no live
         holder (down-not-out member) means the PG serves BELOW full
         redundancy even though nothing more is recoverable — track it
         undersized (MPGStats degraded_pgs) with an open degraded
-        window until the map gives the slot a home."""
+        window until the map gives the slot a home.  True = no hole:
+        the `clean` transition was made."""
         from ..crush.map import CRUSH_ITEM_NONE
         holes = len(acting) < width or any(
             o == CRUSH_ITEM_NONE or not self.osdmap.is_up(o)
@@ -1779,6 +1861,7 @@ class OSDDaemon:
         if holes:
             with self.pg_lock:
                 self._pgs_undersized.add(pgid)
+            self._pg_unclean(pgid)
             self.pg_ledger.transition(pgid, "active_undersized",
                                       epoch=self.osdmap.epoch)
             self.pg_ledger.degraded_open(pgid)
@@ -1788,6 +1871,43 @@ class OSDDaemon:
             self.pg_ledger.transition(pgid, "clean",
                                       epoch=self.osdmap.epoch)
             self.pg_ledger.degraded_close(pgid)
+        return not holes
+
+    def _pg_unclean(self, pgid: pg_t) -> None:
+        """Something cast doubt on what this primary knows of the PG's
+        shards (a failed or deferred recovery pass, a hole written
+        around, a failed local sub-write): its own shard stops
+        answering for the others until a recovery pass finishes clean
+        again (docs/PIPELINE.md "Authoritative local shard")."""
+        state = self.pgs.get(pgid)
+        if state is not None:
+            state.unclean()
+
+    def _pg_clean_for_interval(self, pgid: pg_t, shards,
+                               oid: hobject_t) -> bool:
+        """May a miss on this primary's own shard stand for all k+m?
+        Yes while the PG is clean for its current interval: peered, a
+        recovery pass finished with every acting shard holding every
+        object under the acting set the PG still has (clean_gen), and
+        nothing since has cast doubt on it — not pending recovery, not
+        undersized, no hole written around, every acting member placed
+        and up, and no split or merge that could still be moving `oid`
+        between collections.  Every write of the interval was issued
+        by this primary, whose own shard is applied in the same
+        fan-out, and older intervals' writers are refused by the
+        holders (_stale_interval_write) — so what this shard lacks, no
+        peer has.  Any doubt answers False: the caller fans out."""
+        state = self.pgs.get(pgid)
+        if state is None or state.kind != "ec" or \
+                state.backend.shards is not shards or \
+                state.needs_peer or \
+                state.clean_gen != state.interval_gen:
+            return False
+        if pgid in self._pgs_needing_recovery or \
+                pgid in self._pgs_undersized or shards.degraded_shards:
+            return False
+        return self._live_shards(state) == state.backend.n and \
+            not self._fallback_spgs(spg_t(pgid, 0), oid)
 
     def _recover_decode_batch(self, pgid, acting, be,
                               decode_queue: list[tuple]) -> bool:
@@ -2666,9 +2786,41 @@ class OSDDaemon:
                 slog.advance_rollforward(rollforward_to)
             self._migrate_misplaced(spg, {e.oid for e in entries})
 
-    def _handle_activate(self, msg: M.MPGActivate) -> None:
+    @staticmethod
+    def _peer_osd(conn) -> int | None:
+        """The OSD id at the other end of a connection, from the entity
+        its handshake claimed ("osd.N.<nonce>", behind "<key>/" when
+        auth is on); None for anything else."""
+        ent = (getattr(conn, "peer_entity", None) or "").rsplit("/", 1)[-1]
+        kind, _, rest = ent.partition(".")
+        num = rest.partition(".")[0]
+        return int(num) if kind == "osd" and num.isdigit() else None
+
+    def _stale_interval_write(self, conn, msg: M.MOSDECSubOpWrite
+                              ) -> bool:
+        """A client write's sub-op from the primary of an interval this
+        shard has left: versioned before the shard's last activation
+        and sent by another OSD than the one that activated it
+        (reference PG::can_discard_replica_op: replicas drop ops from
+        before same_interval_since).  Refusing it is what lets the
+        activating primary's recovery listing — taken after the
+        activation — stand for everything its peers hold."""
+        by = self._activated_by.get(msg.pgid)
+        if by is None:
+            return False
+        sender = self._peer_osd(conn)
+        if sender is None or sender == by:
+            return False
+        return msg.at_version.epoch < \
+            self._shard_log(msg.pgid).info.last_epoch_started
+
+    def _handle_activate(self, msg: M.MPGActivate,
+                         by: int | None) -> None:
         from .pg_log import entry_from_wire
         slog = self._shard_log(msg.pgid)
+        # who leads the interval this shard now serves (None = a peer
+        # this daemon cannot name: nothing is fenced on its account)
+        self._activated_by[msg.pgid] = by
         if msg.adopt:
             slog.adopt([entry_from_wire(w) for w in msg.entries],
                        msg.head, msg.les)
@@ -2959,16 +3111,19 @@ class OSDDaemon:
         # 5: activate (stale shards adopt the authoritative log)
         les_new = self.osdmap.epoch
         wire_auth = [entry_to_wire(e) for e in auth_entries]
+        activated = True
         for s in replies:
             spg = spg_t(pgid, s)
             adopt = s not in current
             if live[s] == self.osd_id:
                 self._handle_activate(M.MPGActivate(
-                    spg, 0, les_new, auth_head, wire_auth, adopt))
-            else:
-                self._peer_rpc(live[s], spg, M.MPGActivate, les=les_new,
-                               head=auth_head, entries=wire_auth,
-                               adopt=adopt)
+                    spg, 0, les_new, auth_head, wire_auth, adopt),
+                    self.osd_id)
+            elif self._peer_rpc(live[s], spg, M.MPGActivate,
+                                les=les_new, head=auth_head,
+                                entries=wire_auth, adopt=adopt) is None:
+                activated = False
+        state.activated_all = activated
         # seed the primary's in-memory log + version counter
         newlog = PGLog()
         for e in sorted(auth_entries, key=lambda e: e.version):
@@ -3501,8 +3656,12 @@ class OSDDaemon:
             elif staged is not None and staged.error is not None:
                 # pipeline failure containment acks with the error
                 # attached instead of raising (docs/PIPELINE.md) — the
-                # client must NOT see a failed write as durable
-                result = -errno.EIO
+                # client must NOT see a failed write as durable; a
+                # shard that refused it because the interval changed
+                # (EAGAIN) sends the client to its refreshed map
+                result = -errno.EAGAIN if getattr(
+                    staged.error, "errno", None) == errno.EAGAIN \
+                    else -errno.EIO
             elif staged is None:
                 # EC ops mark commit/failed inside the pipeline's
                 # in-order finisher; replicated ops commit here
@@ -3786,6 +3945,12 @@ class OSDDaemon:
                 res = scrub_mod.scrub_pg(state.backend, names, deep=deep,
                                          repair=repair,
                                          use_device=use_device)
+                if res.errors or res.repaired:
+                    # a shard was missing or wrong where the PG passed
+                    # for whole: the primary's shard stops answering
+                    # for the others until a recovery pass vouches
+                    # for them again
+                    self._pg_unclean(pgid)
                 trimmed = self._trim_snaps(state, pgid, names)
                 out[str(pgid)] = {
                     "objects": res.objects,

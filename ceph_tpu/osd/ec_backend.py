@@ -64,6 +64,11 @@ from .types import eversion_t, hobject_t, spg_t
 class ShardBackend:
     """Transport seam to one PG's shard replicas (primary's view)."""
 
+    # the owning ECBackend's counter set (ECBackend.__init__ sets it):
+    # a transport whose probe can reach the wire counts its outcomes
+    # there (ec_probe_* — docs/TRACING.md)
+    perf = None
+
     def sub_write(self, shard: int, txn: Transaction,
                   on_commit: Callable[[int], None],
                   log_entries: list | None = None,
@@ -100,12 +105,16 @@ class ShardBackend:
     def stat(self, shard: int, oid: hobject_t) -> int | None:
         raise NotImplementedError
 
-    def probe(self, oid: hobject_t, n: int
+    def probe(self, oid: hobject_t, n: int, repair: bool = False
               ) -> tuple["HashInfo | None", int | None]:
         """One metadata sweep: (hinfo, shard size).  hinfo is
         replicated on every shard, so transports override this to ask
-        their LOCAL shard first and the rest in parallel — the
-        sequential per-shard fallback here is for local stores."""
+        their LOCAL shard first and go to the others only when that
+        shard cannot answer for the PG (MessengerShardBackend.probe) —
+        the sequential per-shard fallback here is for local stores.
+        repair: the caller is rebuilding a shard of an object some
+        shard listed (recovery, scrub repair), so a miss on one shard
+        is the damage under repair and never the answer."""
         hinfo = None
         size = None
         for s in range(n):
@@ -194,9 +203,9 @@ class ECOp:
     on_commit: Callable[[], None]
     plan: WritePlan | None = None
     # metadata prefetched OUTSIDE the pipeline lock (oid -> probe
-    # result): the probe is a blocking RPC fan-out, and running it
-    # under be.lock starves every other op AND the dispatch threads
-    # that must deliver its replies
+    # result): off a clean PG the probe is a blocking RPC fan-out,
+    # and running it under be.lock starves every other op AND the
+    # dispatch threads that must deliver its replies
     meta: dict = field(default_factory=dict)
     pending_reads: int = 0
     read_data: dict[tuple[hobject_t, int], np.ndarray] = field(
@@ -246,6 +255,19 @@ def _build_ec_perf(name: str):
                              "sub-write/encode failures absorbed")
             .add_gauge("ec_inflight_depth",
                        "drains in flight after last submit")
+            # object-metadata sweeps (ShardBackend.probe): how often
+            # the primary's own shard answered, and what the rest cost
+            # on the wire (docs/PIPELINE.md "Authoritative local shard")
+            .add_u64_counter("ec_probe_sweeps", "metadata probes made")
+            .add_u64_counter("ec_probe_local_hits",
+                             "probes answered by the local shard's hinfo")
+            .add_u64_counter("ec_probe_local_authoritative_misses",
+                             "local misses a clean PG answered without "
+                             "the wire")
+            .add_u64_counter("ec_probe_remote_sweeps",
+                             "probes that went to the other shards")
+            .add_u64_counter("ec_probe_remote_reads",
+                             "MOSDECSubOpRead frames those probes sent")
             .add_time_avg("ec_drain_assemble",
                           "host assemble+launch time per drain")
             .add_time_avg("ec_drain_device",
@@ -382,6 +404,7 @@ class ECBackend:
         # whose device work is in flight, completion in submit order
         self.dispatch_depth = max(1, int(dispatch_depth))
         self.perf = perf if perf is not None else _build_ec_perf(perf_name)
+        shards.perf = self.perf
         from collections import deque
         self._inflight: "deque[_Drain]" = deque()
         self._pipeline_win = 0        # pipeline() windows currently open
@@ -602,10 +625,19 @@ class ECBackend:
 
     # -- object metadata helpers -------------------------------------------
 
+    def _probe(self, oid: hobject_t, repair: bool = False
+               ) -> tuple[HashInfo | None, int | None]:
+        """Every metadata sweep of this backend (ShardBackend.probe)."""
+        self.perf.inc("ec_probe_sweeps")
+        return self.shards.probe(oid, self.n, repair)
+
     def _fetch_hinfo(self, oid: hobject_t) -> HashInfo | None:
-        """hinfo is replicated on every shard; one probe sweep (local
-        shard first, rest in parallel — see ShardBackend.probe)."""
-        return self.shards.probe(oid, self.n)[0]
+        """The authoritative hinfo of an object under repair (recovery
+        pushes, scrub repair, CLAY plane reads): hinfo is replicated on
+        every shard, so any holder's copy serves — the local shard
+        first, and every other one if that is the shard being rebuilt
+        (see ShardBackend.probe)."""
+        return self._probe(oid, repair=True)[0]
 
     def _get_hinfo(self, oid: hobject_t) -> HashInfo:
         return self._fetch_hinfo(oid) or HashInfo.make(self.n)
@@ -613,7 +645,7 @@ class ECBackend:
     def _get_size(self, oid: hobject_t) -> int:
         """True (unpadded) object size from the hinfo xattr; falls back
         to the stripe-derived size for objects without one."""
-        hinfo, chunk = self.shards.probe(oid, self.n)
+        hinfo, chunk = self._probe(oid)
         if hinfo is not None:
             return hinfo.logical_size
         if chunk is not None:
@@ -622,7 +654,7 @@ class ECBackend:
         return 0
 
     def exists(self, oid: hobject_t) -> bool:
-        hinfo, chunk = self.shards.probe(oid, self.n)
+        hinfo, chunk = self._probe(oid)
         return hinfo is not None or chunk is not None
 
     # -- entry (reference submit_transaction :1483 / start_rmw :1839) ------
@@ -630,14 +662,15 @@ class ECBackend:
     def make_op(self, txn: PGTransaction,
                 on_commit: Callable[[], None], top=None) -> ECOp:
         """Stage an op WITHOUT entering the pipeline: prefetches object
-        metadata (a blocking RPC fan-out) so no lock is held during it.
+        metadata (a blocking RPC fan-out unless the PG is clean, see
+        ShardBackend.probe) so no lock is held during it.
         The racy peek at _projected is benign: the plan re-checks it
         under the lock and falls back to a locked probe on a miss."""
         op = ECOp(txn, eversion_t(), on_commit,
                   top=top if top is not None else NULL_TRACKED)
         for oid in txn.ops:
             if oid not in self._projected:
-                op.meta[oid] = self.shards.probe(oid, self.n)
+                op.meta[oid] = self._probe(oid)
         return op
 
     def enqueue(self, op: ECOp, version: eversion_t) -> ECOp:
@@ -683,7 +716,7 @@ class ECBackend:
                 if oid in op.meta:
                     return op.meta[oid]
                 if oid not in cache:
-                    cache[oid] = self.shards.probe(oid, self.n)
+                    cache[oid] = self._probe(oid)
                 return cache[oid]
 
             def get_hinfo(oid):
@@ -1380,10 +1413,17 @@ class ECBackend:
         if tracked:
             top.mark_event("sub_write_sent")
 
-        def on_commit(shard: int) -> None:
+        def on_commit(shard: int,
+                      error: Exception | None = None) -> None:
+            # error: the shard's holder refused the write (a sub-write
+            # of an interval it has since left, OSDDaemon._dispatch) —
+            # the op drains and carries it to the ack like a failed send
             if tracked:
                 top.mark_event(f"sub_write_ack({shard})")
             with self.lock:
+                if error is not None:
+                    op.error = op.error or error
+                    self.perf.inc("ec_drain_errors")
                 op.pending_commits -= 1
                 if op.pending_commits == 0:
                     self._try_finish_rmw()
