@@ -239,6 +239,13 @@ def end(sp: Span | None) -> None:
         sp.end()
 
 
+def inside() -> bool:
+    """Is a span open on the calling thread?  The `on=` of a span that
+    has no recorder of its own and times a part of whatever encloses
+    it (`store.clone` inside `store.commit`)."""
+    return getattr(_tls, "top", None) is not None
+
+
 # label -> thread-name prefix of the threads whose whole CPU the set
 # reports under `<label>_cpu`
 _thread_groups: dict[str, str] = {}

@@ -273,23 +273,37 @@ class ErasureCodeJax(ErasureCode):
         pipeline's path for non-append (overwrite) extents whose
         incremental crc is dead anyway."""
         import jax.numpy as jnp
+        from ...common.spans import span
+        from ...ops.profiler import device_profiler
         bs = _ops()
         chunks = np.ascontiguousarray(chunks, dtype=np.uint8)
         k, n = chunks.shape
+        # the same rows as the fused launch's: staging under `ec.h2d`,
+        # the jitted call under `ec.dispatch`
+        on = device_profiler().enabled
         if not self._use_w32:
-            return ("bytes", n,
-                    bs.gf_bitmatmul(self._enc_bitmat,
-                                    jnp.asarray(chunks), self.m))
-        pad = -n % 4
-        if pad:
-            chunks = np.pad(chunks, ((0, 0), (0, pad)))
-        words = jnp.asarray(chunks.view("<u4").view(np.int32))
-        return ("w32", n,
-                bs.gf_bitmatmul_w32(self._enc_bitmat32, words, self.m))
+            with span("ec.h2d", on):
+                staged = jnp.asarray(chunks)
+            with span("ec.dispatch", on):
+                return ("bytes", n,
+                        bs.gf_bitmatmul(self._enc_bitmat, staged,
+                                        self.m))
+        with span("ec.h2d", on):
+            pad = -n % 4
+            if pad:
+                chunks = np.pad(chunks, ((0, 0), (0, pad)))
+            words = jnp.asarray(chunks.view("<u4").view(np.int32))
+        with span("ec.dispatch", on):
+            return ("w32", n,
+                    bs.gf_bitmatmul_w32(self._enc_bitmat32, words,
+                                        self.m))
 
     def encode_chunks_finalize(self, handle) -> np.ndarray:
+        from ...common.spans import span
+        from ...ops.profiler import device_profiler
         kind, n, dev = handle
-        out = np.asarray(dev)
+        with span("ec.d2h_wait", device_profiler().enabled):
+            out = np.asarray(dev)
         if kind == "w32":
             out = out.view("<u4").view(np.uint8).reshape(self.m, -1)
         return out[:, :n] if out.shape[1] != n else out

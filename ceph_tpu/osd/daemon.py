@@ -465,6 +465,17 @@ class OSDDaemon:
             .add_u64_counter("op_r", "read ops")
             .add_u64_counter("subop_w", "shard sub-writes applied")
             .add_u64_counter("subop_r", "shard sub-reads served")
+            # what an EC overwrite costs its shard holders beyond the
+            # write itself (docs/PIPELINE.md "Overwrites")
+            .add_u64_counter("ec_shard_clone_bytes",
+                             "bytes copied into kept generations "
+                             "(the whole shard object per overwrite)")
+            .add_u64_counter("ec_shard_chunk_crc_bytes",
+                             "bytes re-read and re-hashed for the "
+                             "chunk_crc attr (refresh_chunk_crcs)")
+            .add_u64_counter("ec_shard_generations_trimmed",
+                             "kept generations removed once rolled "
+                             "forward")
             .add_time_avg("op_latency", "client op latency")
             .add_u64_counter("recovery_queued_ops",
                              "rebuild units routed through the "
@@ -2776,15 +2787,39 @@ class OSDDaemon:
         with self._split_lock:
             slog = self._shard_log(spg)
             slog.append_to_txn(txn, entries, at_version)
+            cid = self._cid(spg)
+            if any(e.rollback.kept_generation is not None
+                   for e in entries):
+                self.perf.inc("ec_shard_clone_bytes",
+                              self._clone_bytes(cid, txn))
             with span("store.commit", self.op_tracker.enabled, pgid=spg):
-                self.store.queue_transactions(self._cid(spg), [txn])
+                self.store.queue_transactions(cid, [txn])
             slog.record(entries, at_version)
             from .ec_util import refresh_chunk_crcs
-            refresh_chunk_crcs(self.store, self._cid(spg), spg.shard,
-                               entries)
+            hashed = refresh_chunk_crcs(self.store, cid, spg.shard,
+                                        entries,
+                                        self.op_tracker.enabled)
+            if hashed:
+                self.perf.inc("ec_shard_chunk_crc_bytes", hashed)
             if rollforward_to is not None:
-                slog.advance_rollforward(rollforward_to)
+                trimmed = slog.advance_rollforward(rollforward_to)
+                if trimmed:
+                    self.perf.inc("ec_shard_generations_trimmed",
+                                  trimmed)
             self._migrate_misplaced(spg, {e.oid for e in entries})
+
+    def _clone_bytes(self, cid: spg_t, txn: Transaction) -> int:
+        """Bytes the transaction's clones (an overwrite's kept
+        generations) are about to copy: the sources' sizes now."""
+        from ..store.object_store import OpClone
+        total = 0
+        for op in txn.ops:
+            if isinstance(op, OpClone):
+                try:
+                    total += self.store.stat(cid, op.src)
+                except KeyError:
+                    pass
+        return total
 
     @staticmethod
     def _peer_osd(conn) -> int | None:

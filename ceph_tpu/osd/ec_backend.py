@@ -40,6 +40,7 @@ standalone clusters on MemStore); the messenger-backed implementation
 from __future__ import annotations
 
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -222,6 +223,9 @@ class ECOp:
     # per-op trace/timeline (common/tracked_op.py); NULL_TRACKED when
     # tracking is off — every mark_event below is then a no-op
     top: object = NULL_TRACKED
+    # perf_counter at the op's first RMW sub-read (`lat_ec_rmw_read`
+    # ends when its last pre-read is answered)
+    t_rmw_read: float = 0.0
 
 
 @dataclass
@@ -268,6 +272,24 @@ def _build_ec_perf(name: str):
                              "probes that went to the other shards")
             .add_u64_counter("ec_probe_remote_reads",
                              "MOSDECSubOpRead frames those probes sent")
+            # the read-modify-write half (docs/PIPELINE.md
+            # "Overwrites"): what a partial-stripe write reads back
+            # before it can encode
+            .add_u64_counter("ec_rmw_reads",
+                             "stripe-aligned extents pre-read for "
+                             "partial-stripe writes")
+            .add_u64_counter("ec_rmw_read_bytes",
+                             "logical bytes those pre-reads returned")
+            .add_u64_counter("ec_rmw_cache_hit_bytes",
+                             "bytes the ExtentCache laid over a "
+                             "pre-read (in-flight writes of earlier "
+                             "ops)")
+            .add_histogram("lat_ec_rmw_read",
+                           "per op: first RMW sub-read sent -> last "
+                           "pre-read answered")
+            .add_u64_counter("ec_plain_drains",
+                             "drains that launched plain (no-crc) "
+                             "parity for non-append extents")
             .add_time_avg("ec_drain_assemble",
                           "host assemble+launch time per drain")
             .add_time_avg("ec_drain_device",
@@ -762,6 +784,8 @@ class ECBackend:
                 for e in extents:
                     reads.append((oid, e))
             op.pending_reads = len(reads)
+            if reads:
+                op.t_rmw_read = time.perf_counter()
             for oid, e in reads:
                 self._start_rmw_read(op, oid, e)
 
@@ -772,19 +796,28 @@ class ECBackend:
         chunk_len = e.length // self.k
         got: dict[int, np.ndarray] = {}
         failed: set[int] = set()
+        glock = threading.Lock()
 
         def on_done(shard: int, data: np.ndarray | None) -> None:
-            if data is None:
-                failed.add(shard)
-            else:
-                got[shard] = data
-            if len(got) + len(failed) == self.k and not failed:
-                logical = ec_util.decode(
-                    self.sinfo, self.ec_impl, got, e.length)
-                self._rmw_read_complete(op, oid, e, logical)
-            elif failed and len(got) < self.k:
-                self._read_with_reconstruct(op, oid, e, chunk_off,
-                                            chunk_len, got, failed)
+            # replies race on the dispatch executor's threads: count
+            # them under a lock, so that exactly ONE caller — whoever
+            # brings the k-th answer — continues the op
+            with glock:
+                if data is None:
+                    failed.add(shard)
+                else:
+                    got[shard] = data
+                if len(got) + len(failed) < self.k:
+                    return
+            with span("ec.rmw_read_done", device_profiler().enabled,
+                      pgid=self.perf.name):
+                if not failed:
+                    logical = ec_util.decode(
+                        self.sinfo, self.ec_impl, got, e.length)
+                    self._rmw_read_complete(op, oid, e, logical)
+                else:
+                    self._read_with_reconstruct(op, oid, e, chunk_off,
+                                                chunk_len, got, failed)
 
         for s in range(self.k):
             self.shards.sub_read(s, oid, chunk_off, chunk_len, on_done)
@@ -793,19 +826,24 @@ class ECBackend:
                                got, failed) -> None:
         """Degraded pre-read: pull parity shards until k available
         (reference objects_read_and_reconstruct :2345 +
-        get_remaining_shards :1633)."""
+        get_remaining_shards :1633).  Called once, after every data
+        shard has answered."""
         tried = set(got) | set(failed)
         candidates = [s for s in range(self.n) if s not in tried]
+        glock = threading.Lock()
+        done = [False]
 
         def on_done(shard, data):
-            if data is not None:
-                got[shard] = data
-            if len(got) >= self.k:
-                logical = ec_util.decode(
-                    self.sinfo, self.ec_impl,
-                    dict(list(got.items())[: self.k] if len(got) > self.k
-                         else got), e.length)
-                self._rmw_read_complete(op, oid, e, logical)
+            with glock:
+                if data is not None:
+                    got[shard] = data
+                if done[0] or len(got) < self.k:
+                    return
+                done[0] = True
+                use = dict(list(got.items())[: self.k])
+            logical = ec_util.decode(self.sinfo, self.ec_impl, use,
+                                     e.length)
+            self._rmw_read_complete(op, oid, e, logical)
 
         if len(candidates) + len(got) < self.k:
             raise ErasureCodeError(5, f"unrecoverable: {oid} extent {e}")
@@ -816,7 +854,11 @@ class ECBackend:
         with self.lock:
             op.read_data[(oid, e.off)] = logical
             op.pending_reads -= 1
+            self.perf.inc("ec_rmw_reads")
+            self.perf.inc("ec_rmw_read_bytes", int(logical.size))
             if op.pending_reads == 0:
+                self.perf.hinc("lat_ec_rmw_read",
+                               time.perf_counter() - op.t_rmw_read)
                 self._try_reads_to_commit()
 
     # -- encode + commit (reference try_reads_to_commit :1939) --------------
@@ -826,20 +868,20 @@ class ECBackend:
         """Overlay new writes on pre-read/zero background for one
         stripe-aligned extent."""
         buf = np.zeros(e.length, dtype=np.uint8)
-        rd = op.read_data.get((oid, e.off))
-        if rd is not None:
-            buf[: rd.size] = rd
-        else:
-            # partial overlap with other read extents
-            for (roid, roff), data in op.read_data.items():
-                if roid != oid:
-                    continue
-                lo = max(e.off, roff)
-                hi = min(e.end, roff + data.size)
-                if lo < hi:
-                    buf[lo - e.off:hi - e.off] = data[lo - roff:hi - roff]
+        # every pre-read that intersects: a write over three stripes
+        # or more has its head AND its tail stripe read back, two
+        # extents inside this one
+        for (roid, roff), data in op.read_data.items():
+            if roid != oid:
+                continue
+            lo = max(e.off, roff)
+            hi = min(e.end, roff + data.size)
+            if lo < hi:
+                buf[lo - e.off:hi - e.off] = data[lo - roff:hi - roff]
         # bytes assembled by earlier in-flight ops win over store reads
-        self.extent_cache.overlay(oid, e.off, buf)
+        laid = self.extent_cache.overlay(oid, e.off, buf)
+        if laid and oid in op.plan.to_read:
+            self.perf.inc("ec_rmw_cache_hit_bytes", laid)
         for w in op.txn.ops[oid].writes:
             lo = max(e.off, w.offset)
             hi = min(e.end, w.end)
@@ -865,6 +907,8 @@ class ECBackend:
                 self._inflight.append(drain)
                 if self.perf:
                     self.perf.inc("ec_drain_submits")
+                    if "plain" in drain.kinds:
+                        self.perf.inc("ec_plain_drains")
                     self.perf.set("ec_inflight_depth", len(self._inflight))
                 self._arm_auto_flush()
         self._drain_pipeline()

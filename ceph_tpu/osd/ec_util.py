@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ..common import crc32c as _crc
+from ..common.spans import span
 from ..ec.interface import ErasureCodeInterface
 
 
@@ -96,16 +97,21 @@ def recovery_attrs(hinfo: "HashInfo", data) -> dict[str, bytes]:
     return attrs
 
 
-def refresh_chunk_crcs(store, cid, shard: int, entries) -> None:
+def refresh_chunk_crcs(store, cid, shard: int, entries,
+                       spans_on: bool = False) -> int:
     """Shard-side integrity upkeep after applying a sub-write: an
     object that has entered overwrite mode (a generation was kept, or
     a chunk_crc attr already exists from an earlier overwrite) gets
     its full-chunk crc recomputed from local bytes.  Pure appends on
     never-overwritten objects skip this — their cumulative hinfo is
-    still authoritative."""
+    still authoritative.  Returns the bytes it re-read and re-hashed
+    (the WHOLE shard object per overwritten object, whatever the
+    write's size: `ec_shard_chunk_crc_bytes`); each re-hash is an
+    `ec.chunk_crc_refresh` span when `spans_on`."""
     from .pg_log import LogOp
     from .types import ghobject_t
     seen = set()
+    hashed = 0
     for e in entries:
         if e.op is not LogOp.MODIFY or e.oid in seen:
             continue
@@ -116,14 +122,17 @@ def refresh_chunk_crcs(store, cid, shard: int, entries) -> None:
                 store.getattr(cid, goid, CHUNK_CRC_KEY)
             except KeyError:
                 continue   # append-only object: hinfo covers it
-        try:
-            data = store.read(cid, goid)
-        except KeyError:
-            continue
-        from ..store.object_store import Transaction
-        txn = Transaction()
-        txn.setattr(goid, CHUNK_CRC_KEY, chunk_crc_of(data))
-        store.queue_transactions(cid, [txn])
+        with span("ec.chunk_crc_refresh", spans_on):
+            try:
+                data = store.read(cid, goid)
+            except KeyError:
+                continue
+            from ..store.object_store import Transaction
+            txn = Transaction()
+            txn.setattr(goid, CHUNK_CRC_KEY, chunk_crc_of(data))
+            store.queue_transactions(cid, [txn])
+            hashed += int(data.size)
+    return hashed
 
 
 @dataclass

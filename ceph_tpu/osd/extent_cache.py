@@ -47,17 +47,21 @@ class ExtentCache:
                                 np.asarray(data, dtype=np.uint8).copy()))
 
     def overlay(self, oid: hobject_t, off: int,
-                buf: np.ndarray) -> np.ndarray:
+                buf: np.ndarray) -> int:
         """Copy any cached bytes intersecting [off, off+len(buf)) over
-        buf (newest extents last in the list = freshest)."""
+        buf (newest extents last in the list = freshest).  Returns the
+        bytes copied (`ec_rmw_cache_hit_bytes` when buf is a
+        pre-read)."""
         with self._lock:
             exts = list(self._objs.get(oid, []))
         end = off + buf.size
+        laid = 0
         for e in exts:
             lo, hi = max(off, e.off), min(end, e.end)
             if lo < hi:
                 buf[lo - off:hi - off] = e.data[lo - e.off:hi - e.off]
-        return buf
+                laid += hi - lo
+        return laid
 
     def release(self, oid: hobject_t, off: int, length: int) -> None:
         with self._lock:

@@ -204,21 +204,23 @@ class ShardPGLog:
             if e.version >= self.log.head:
                 self.log.add(e)
 
-    def advance_rollforward(self, rf: eversion_t) -> None:
+    def advance_rollforward(self, rf: eversion_t) -> int:
         """Entries at or below rf are durable everywhere: their kept
         generations will never be rolled back to — reclaim them
         (reference trim_rollback_object on rollforward,
-        ECBackend.cc try_finish_rmw)."""
+        ECBackend.cc try_finish_rmw).  Returns the generations it
+        removed (`ec_shard_generations_trimmed`)."""
         newly = self.log.roll_forward_to(rf)
         purge = [e for e in newly
                  if e.rollback.kept_generation is not None]
         if not purge:
-            return
+            return 0
         txn = _txn()
         for e in purge:
             txn.remove(ghobject_t(e.oid, e.rollback.kept_generation,
                                   self.shard))
         self.store.queue_transactions(self.cid, [txn])
+        return len(purge)
 
     def set_les(self, les: int) -> None:
         self.info.last_epoch_started = max(

@@ -698,21 +698,31 @@ class ECLaunchQueue:
                 big = np.concatenate(bigs, axis=1) if len(bigs) > 1 \
                     else bigs[0]
                 if hasattr(plugin, "encode_chunks_submit"):
-                    if len(bigs) > 1:
-                        # launch-shape bucketing (see bitsliced.py):
-                        # a jit'd plugin would recompile per distinct
-                        # super-batch width — pad coalesced launches
-                        # to the next power of two (zero columns
-                        # encode to zero parity; the column demux
-                        # never reads them)
-                        w = big.shape[1]
-                        w2 = next_pow2(w)
-                        if w2 != w:
-                            big = np.concatenate(
-                                [big, np.zeros((big.shape[0], w2 - w),
-                                               dtype=np.uint8)],
-                                axis=1)
+                    # launch-shape bucketing (see bitsliced.py), as
+                    # for decodes UNCONDITIONAL: a jit'd plugin would
+                    # recompile per distinct width, and one drain of
+                    # three one-stripe overwrites is as odd a width
+                    # as three coalesced drains — pad to the next
+                    # power of two (zero columns encode to zero
+                    # parity; the column demux never reads them), so
+                    # {pow2 widths} is the whole plain bucket set a
+                    # prewarm can enumerate
+                    w = big.shape[1]
+                    w2 = next_pow2(w)
+                    if w2 != w:
+                        big = np.concatenate(
+                            [big, np.zeros((big.shape[0], w2 - w),
+                                           dtype=np.uint8)],
+                            axis=1)
                     handle = ("h", plugin.encode_chunks_submit(big))
+                    if self.perf:
+                        # the staged data in, the parity out (the
+                        # encode matrix is device-resident)
+                        self.perf.inc("ec_h2d_bytes", int(big.size))
+                        self.perf.inc(
+                            "ec_d2h_bytes",
+                            plugin.get_coding_chunk_count()
+                            * big.shape[1])
                 else:
                     # host-synchronous CPU plugins: ONE concatenated
                     # encode for the whole super-batch (fewer, larger

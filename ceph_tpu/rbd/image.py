@@ -16,13 +16,22 @@ records (parent image, parent snap); reads fall through to the parent
 at that snap for blocks the child has never written, and the first
 child write to such a block pulls the parent content (COW pull,
 reference CopyupRequest).
+
+Data pool (reference `rbd create --data-pool`, the way an image is put
+on an erasure-coded pool): the header records a `data_pool`; header,
+`rbd_directory`, exclusive lock, object map and journal stay on the
+image's own (replicated) pool, every `rbd_data.*` object — and so the
+snapshots' clones and their snap ids — lives on the data pool.
 """
 
 from __future__ import annotations
 
 import errno
 import json
+import threading
 
+from ..common.spans import span
+from ..msg.msgr_ledger import msgr_ledger
 from ..rados.client import IoCtx, RadosError
 
 DEFAULT_ORDER = 22  # 4 MiB objects, the reference default
@@ -36,7 +45,10 @@ class RBD:
         self.io = ioctx
 
     def create(self, name: str, size: int,
-               order: int = DEFAULT_ORDER) -> None:
+               order: int = DEFAULT_ORDER,
+               data_pool: str | None = None) -> None:
+        if data_pool is not None:
+            self.io.client.open_ioctx(data_pool)    # ENOENT: no pool
         try:
             self.io.read(_header(name), 1)
             raise RadosError(errno.EEXIST, f"image {name} exists")
@@ -45,12 +57,15 @@ class RBD:
                 raise
         header = {"size": size, "order": order, "snaps": [],
                   "snap_ids": {}, "parent": None}
+        if data_pool is not None:
+            header["data_pool"] = data_pool
         self.io.write_full(_header(name), json.dumps(header).encode())
         self._dir_add(name)
 
     def clone(self, parent: str, snap: str, child: str) -> None:
         """Layered clone from a parent snapshot (reference rbd clone;
-        the snap plays the protected-snap role)."""
+        the snap plays the protected-snap role).  The child's data
+        lies on its own pool, whatever the parent's data pool."""
         pimg = Image(self.io, parent)
         if snap not in pimg._header.get("snap_ids", {}):
             raise RadosError(errno.ENOENT,
@@ -92,7 +107,7 @@ class RBD:
         nblocks = img._nblocks()
         for b in range(nblocks):
             try:
-                self.io.remove(_data(name, b))
+                img.data_io.remove(_data(name, b))
             except RadosError:
                 pass
         from .object_map import _inval_oid, _map_oid
@@ -128,7 +143,13 @@ class Image:
     lockless writers on one image corrupt it, exactly like the
     reference with the exclusive-lock feature disabled.  steal=True
     fences a live previous owner (its handle raises ESHUTDOWN on every
-    later mutation)."""
+    later mutation).
+
+    One exclusive handle may be written by many threads at once (fio's
+    iodepth on one image): `write` holds no lock across its data op;
+    the handle's shared state — header, presence cache, object map,
+    lock re-acquire — changes under `_mu`, and a write to a block the
+    object map already knows costs exactly its one data op."""
 
     def __init__(self, ioctx: IoCtx, name: str,
                  journaling: bool = False, exclusive: bool = False,
@@ -142,6 +163,12 @@ class Image:
             self.io.read(_header(name), 0).decode())
         self._header.setdefault("snap_ids", {})
         self._header.setdefault("parent", None)
+        # rbd_data.* (and the snapshots' clones) live on the data pool
+        # the header names; everything else on the image's own pool
+        data_pool = self._header.get("data_pool")
+        self.data_io = self.io if data_pool is None \
+            else ioctx.client.open_ioctx(data_pool)
+        self._mu = threading.RLock()
         # snapshots taken under the pre-COW scheme (full-copy objects,
         # no rados snap id) remain usable through their own paths
         self._legacy_snaps = {s for s in self._header["snaps"]
@@ -183,7 +210,7 @@ class Image:
 
     def _probe_block(self, block: int) -> bool:
         try:
-            self.io.read(_data(self.name, block), 1, snap=0)
+            self.data_io.read(_data(self.name, block), 1, snap=0)
             return True
         except RadosError as e:
             if e.errno != errno.ENOENT:
@@ -215,21 +242,27 @@ class Image:
         if self._lock is not None:
             self._lock.check()
             if not self._lock.acquired:
-                self._lock.acquire()
+                with self._mu:
+                    self._lock.acquire()
             return
         if self._lockless_checked:
             return
         from .exclusive_lock import ExclusiveLock
         from .object_map import invalidate
-        aux = IoCtx(self.io.client, self.io.pool_id, self.io.pool_name)
-        probe = ExclusiveLock(aux, _header(self.name), self.name)
-        if probe.lockers() and aux.list_watchers(_header(self.name)):
-            raise RadosError(
-                errno.EBUSY,
-                f"image {self.name} is exclusively locked; open with "
-                f"exclusive=True")
-        invalidate(aux, self.name)
-        self._lockless_checked = True
+        with self._mu:
+            if self._lockless_checked:
+                return
+            aux = IoCtx(self.io.client, self.io.pool_id,
+                        self.io.pool_name)
+            probe = ExclusiveLock(aux, _header(self.name), self.name)
+            if probe.lockers() and \
+                    aux.list_watchers(_header(self.name)):
+                raise RadosError(
+                    errno.EBUSY,
+                    f"image {self.name} is exclusively locked; open "
+                    f"with exclusive=True")
+            invalidate(aux, self.name)
+            self._lockless_checked = True
 
     def close(self) -> None:
         self._closed = True
@@ -273,15 +306,18 @@ class Image:
 
     def _apply_snapc(self) -> None:
         ids = sorted(self._header["snap_ids"].values(), reverse=True)
-        self.io.snapc = [ids[0], ids] if ids else None
+        self.data_io.snapc = [ids[0], ids] if ids else None
 
     def _get_parent(self) -> "Image | None":
         if self._header["parent"] is None:
             return None
         if self._parent is None:
-            pname, psnap = self._header["parent"]
-            self._parent = Image(self.io, pname)
-            self._parent._read_snap_id = psnap
+            with self._mu:
+                if self._parent is None:
+                    pname, psnap = self._header["parent"]
+                    parent = Image(self.io, pname)
+                    parent._read_snap_id = psnap
+                    self._parent = parent
         return self._parent
 
     def _read_block(self, block: int, boff: int, run: int) -> bytes:
@@ -298,12 +334,13 @@ class Image:
             if skip_probe:
                 raise RadosError(errno.ENOENT, "object map: absent")
             if self._legacy_read is not None:
-                piece = self.io.read(
+                piece = self.data_io.read(
                     _legacy_snap_data(self.name, self._legacy_read,
                                       block), run, boff, snap=0)
             else:
-                piece = self.io.read(_data(self.name, block), run, boff,
-                                     snap=self._read_snap_id)
+                piece = self.data_io.read(
+                    _data(self.name, block), run, boff,
+                    snap=self._read_snap_id)
             return piece + b"\0" * (run - len(piece))
         except RadosError as e:
             if e.errno != errno.ENOENT:
@@ -317,12 +354,21 @@ class Image:
     # -- block I/O ----------------------------------------------------------
 
     def write(self, offset: int, data: bytes) -> int:
+        """Safe for concurrent callers on one handle (see the class
+        doc); two writers of the SAME bytes race like two writers of
+        one sector of a disk — the caller orders those."""
+        # rbd.* spans are on when the client's wire recorder is
+        with span("rbd.write", msgr_ledger().enabled):
+            return self._write(offset, data)
+
+    def _write(self, offset: int, data: bytes) -> int:
         if offset + len(data) > self.size():
             raise RadosError(errno.EINVAL, "write past end of image")
         self._writable()
         if self._journal is not None:
-            self._journal.append({"op": "write", "offset": offset},
-                                 bytes(data))
+            with self._mu:          # one event stream per image
+                self._journal.append({"op": "write", "offset": offset},
+                                     bytes(data))
         bs = self.block_size
         pos = 0
         while pos < len(data):
@@ -332,8 +378,8 @@ class Image:
                 self._copyup(block)
             if self._omap is not None:
                 self._omap.ensure_exists(block)   # write-ahead
-            self.io.write(_data(self.name, block),
-                          data[pos:pos + run], offset=boff)
+            self.data_io.write(_data(self.name, block),
+                               data[pos:pos + run], offset=boff)
             pos += run
         return len(data)
 
@@ -346,23 +392,29 @@ class Image:
             return
         if block in self._present_blocks:
             return
-        omap = self._live_omap()
-        if omap is not None and not omap.object_may_exist(block):
-            pass                        # map says absent: skip probe
-        else:
-            try:
-                self.io.read(_data(self.name, block), 1)
-                self._present_blocks.add(block)
-                return                  # child block already exists
-            except RadosError as e:
-                if e.errno != errno.ENOENT:
-                    raise
-        content = parent._read_block(block, 0, self.block_size)
-        if content.rstrip(b"\0"):
-            if self._omap is not None:
-                self._omap.ensure_exists(block)
-            self.io.write_full(_data(self.name, block), content)
-        self._present_blocks.add(block)
+        # one copy-up at a time per handle: two writers of one absent
+        # block must not both pull the parent over each other's data
+        with self._mu:
+            if block in self._present_blocks:
+                return
+            omap = self._live_omap()
+            if omap is not None and not omap.object_may_exist(block):
+                pass                    # map says absent: skip probe
+            else:
+                try:
+                    self.data_io.read(_data(self.name, block), 1)
+                    self._present_blocks.add(block)
+                    return              # child block already exists
+                except RadosError as e:
+                    if e.errno != errno.ENOENT:
+                        raise
+            content = parent._read_block(block, 0, self.block_size)
+            if content.rstrip(b"\0"):
+                if self._omap is not None:
+                    self._omap.ensure_exists(block)
+                self.data_io.write_full(_data(self.name, block),
+                                        content)
+            self._present_blocks.add(block)
 
     def _read_block_at(self, block: int, snapid: int) -> bytes:
         """One whole block read at an explicit snap context (the
@@ -400,13 +452,17 @@ class Image:
 
     def resize(self, new_size: int) -> None:
         self._writable()
+        with self._mu:
+            self._resize(new_size)
+
+    def _resize(self, new_size: int) -> None:
         if self._journal is not None:
             self._journal.append({"op": "resize", "size": new_size})
         old_blocks = self._nblocks()
         new_blocks = -(-new_size // self.block_size)
         for b in range(new_blocks, old_blocks):
             try:
-                self.io.remove(_data(self.name, b))
+                self.data_io.remove(_data(self.name, b))
             except RadosError:
                 pass
             self._present_blocks.discard(b)
@@ -421,16 +477,20 @@ class Image:
         if snap in self._header["snaps"]:
             raise RadosError(errno.EEXIST, f"snap {snap} exists")
         self._writable()
-        if self._journal is not None:
-            self._journal.append({"op": "snap_create", "snap": snap})
-        snapid = self.io.selfmanaged_snap_create()
-        self._header["snaps"].append(snap)
-        self._header["snap_ids"][snap] = snapid
-        # size at snap time: export-diff must bound its walk by the
-        # snapshot's extent, not the (possibly resized) head's
-        self._header.setdefault("snap_sizes", {})[snap] = self.size()
-        self._save_header()
-        self._apply_snapc()   # later writes COW against this snap
+        with self._mu:
+            if self._journal is not None:
+                self._journal.append({"op": "snap_create",
+                                      "snap": snap})
+            # the clones live with the data: so does the snap id
+            snapid = self.data_io.selfmanaged_snap_create()
+            self._header["snaps"].append(snap)
+            self._header["snap_ids"][snap] = snapid
+            # size at snap time: export-diff must bound its walk by
+            # the snapshot's extent, not the (possibly resized) head's
+            self._header.setdefault("snap_sizes", {})[snap] = \
+                self.size()
+            self._save_header()
+            self._apply_snapc()   # later writes COW against this snap
 
     def snap_list(self) -> list[str]:
         return list(self._header["snaps"])
@@ -463,11 +523,11 @@ class Image:
         for b in range(nblocks):
             try:
                 if snapid is None:
-                    data = self.io.read(
+                    data = self.data_io.read(
                         _legacy_snap_data(self.name, snap, b), 0)
                 else:
-                    data = self.io.read(_data(self.name, b), 0,
-                                        snap=snapid)
+                    data = self.data_io.read(_data(self.name, b), 0,
+                                             snap=snapid)
             except RadosError as e:
                 if e.errno != errno.ENOENT:
                     raise
@@ -475,11 +535,12 @@ class Image:
             if data.rstrip(b"\0"):
                 if self._omap is not None:
                     self._omap.ensure_exists(b)
-                self.io.write(_data(self.name, b),
-                              data.ljust(bs, b"\0")[:bs], offset=0)
+                self.data_io.write(_data(self.name, b),
+                                   data.ljust(bs, b"\0")[:bs],
+                                   offset=0)
             else:
                 try:
-                    self.io.remove(_data(self.name, b))
+                    self.data_io.remove(_data(self.name, b))
                 except RadosError:
                     pass
                 if self._omap is not None:
@@ -488,13 +549,18 @@ class Image:
 
     def snap_remove(self, snap: str) -> None:
         self._writable()
+        with self._mu:
+            self._snap_remove(snap)
+
+    def _snap_remove(self, snap: str) -> None:
         if self._journal is not None:
             self._journal.append({"op": "snap_remove", "snap": snap})
         if snap in self._legacy_snaps:
             nblocks = self._nblocks()
             for b in range(nblocks):
                 try:
-                    self.io.remove(_legacy_snap_data(self.name, snap, b))
+                    self.data_io.remove(
+                        _legacy_snap_data(self.name, snap, b))
                 except RadosError:
                     pass
             self._legacy_snaps.discard(snap)
@@ -510,7 +576,7 @@ class Image:
         self._apply_snapc()
         # report deletion so the OSD snap trimmer reclaims the clones
         try:
-            self.io.selfmanaged_snap_remove(snapid)
+            self.data_io.selfmanaged_snap_remove(snapid)
         except RadosError:
             pass   # advisory; trim just won't run for this id yet
 
@@ -523,6 +589,7 @@ class Image:
         self._writable()
         for b in range(self._nblocks()):
             self._copyup(b)
-        self._header["parent"] = None
-        self._parent = None
-        self._save_header()
+        with self._mu:
+            self._header["parent"] = None
+            self._parent = None
+            self._save_header()

@@ -18,6 +18,7 @@ probes.
 from __future__ import annotations
 
 import errno
+import threading
 
 from ..rados.client import RadosError
 
@@ -48,6 +49,9 @@ class ObjectMap:
         self.nblocks = nblocks
         self.state = bytearray(nblocks)
         self._loaded = False
+        # one handle, many writer threads: a state change and its
+        # write-ahead byte go together
+        self._mu = threading.Lock()
 
     # -- load / rebuild ------------------------------------------------------
 
@@ -101,29 +105,35 @@ class ObjectMap:
         """Mark EXISTS before the data write lands."""
         if not self._loaded or block >= self.nblocks:
             return
-        if self.state[block] != EXISTS:
-            self.io.write(_map_oid(self.name), bytes([EXISTS]),
-                          offset=block)
-            self.state[block] = EXISTS
+        if self.state[block] == EXISTS:
+            return          # the steady state: no lock, no round trip
+        with self._mu:
+            if self.state[block] != EXISTS:
+                self.io.write(_map_oid(self.name), bytes([EXISTS]),
+                              offset=block)
+                self.state[block] = EXISTS
 
     def mark_removed(self, block: int) -> None:
         """Mark NONEXISTENT after the data object is removed."""
         if not self._loaded or block >= self.nblocks:
             return
-        if self.state[block] != NONEXISTENT:
-            self.io.write(_map_oid(self.name), bytes([NONEXISTENT]),
-                          offset=block)
-            self.state[block] = NONEXISTENT
+        with self._mu:
+            if self.state[block] != NONEXISTENT:
+                self.io.write(_map_oid(self.name),
+                              bytes([NONEXISTENT]), offset=block)
+                self.state[block] = NONEXISTENT
 
     def resize(self, nblocks: int, exists_hint: int = NONEXISTENT) -> None:
-        if nblocks < len(self.state):
-            del self.state[nblocks:]
-        else:
-            self.state.extend(bytes([exists_hint]) *
-                              (nblocks - len(self.state)))
-        self.nblocks = nblocks
-        if self._loaded:
-            self.io.write_full(_map_oid(self.name), bytes(self.state))
+        with self._mu:
+            if nblocks < len(self.state):
+                del self.state[nblocks:]
+            else:
+                self.state.extend(bytes([exists_hint]) *
+                                  (nblocks - len(self.state)))
+            self.nblocks = nblocks
+            if self._loaded:
+                self.io.write_full(_map_oid(self.name),
+                                   bytes(self.state))
 
     def remove(self) -> None:
         try:

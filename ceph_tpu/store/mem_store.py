@@ -13,6 +13,7 @@ from typing import Iterable
 
 import numpy as np
 
+from ..common import spans
 from ..osd.types import ghobject_t, spg_t
 from . import object_store as os_
 from .object_store import ObjectStore, Transaction
@@ -114,7 +115,10 @@ class MemStore(ObjectStore):
         elif isinstance(op, os_.OpClone):
             src = coll.get(op.src)
             if src is not None:
-                coll[op.dst] = src.clone()
+                # a whole-object copy (an EC overwrite's kept
+                # generation): its own row inside `store.commit`
+                with spans.span("store.clone", spans.inside()):
+                    coll[op.dst] = src.clone()
         elif isinstance(op, os_.OpRename):
             src = coll.pop(op.src, None)
             if src is not None:
