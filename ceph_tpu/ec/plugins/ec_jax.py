@@ -181,16 +181,14 @@ class ErasureCodeJax(ErasureCode):
         the write path's checksum-and-parity-in-one-launch (reference
         analog: plugin encode + ECUtil.cc:172 HashInfo append, two
         separate passes there)."""
-        import jax.numpy as jnp
         bs = _ops()
-        from ...ops import crc32c_linear as cl
         if not self._use_w32:
             raise RuntimeError(
                 "encode_words_with_crc requires a TPU backend")
         point = self.fused_point()
         tile = tile or point["tile"]
         wb = wb or point["wb"]
-        cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
+        cmat_sub = bs._crc_tile_w32_const(wb)
         return bs.gf_encode_with_crc_w32_fold(
             self._enc_bitmat32, cmat_sub, words, self.m,
             tile=tile, wb=wb, combine=point["combine"])
@@ -405,15 +403,13 @@ class ErasureCodeJax(ErasureCode):
         the gf_encode_extents_with_crc_submit dispatch shapes (tile
         padding, pow2 tile-count bucketing, pow2 run-count bucketing
         all reproduced)."""
-        import jax.numpy as jnp
         bs = _ops()
         from ...common.util import next_pow2
-        from ...ops import crc32c_linear as cl
         k, m = self.k, self.m
         if not self._use_w32:                      # CPU: force_xla path
             tile = bs.FUSED_TILE
             nt = next_pow2(sum(-(-w // tile) for w in widths))
-            cmat = jnp.asarray(cl.crc_tile_matrix(tile))
+            cmat = bs._crc_tile_const(tile)
             return bs.aot_compile(
                 "fused_xla", bs.gf_encode_with_crc_xla,
                 (self._enc_bitmat, cmat,
@@ -430,7 +426,7 @@ class ErasureCodeJax(ErasureCode):
         nt2 = next_pow2(ntiles_total)
         pad_tiles = nt2 - ntiles_total
         words = self._aot_spec((k, nt2 * tile // 4), np.int32)
-        cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
+        cmat_sub = bs._crc_tile_w32_const(wb)
         if hier and point["combine"] == "kernel":
             if pad_tiles:
                 ntiles_run = ntiles_run + [pad_tiles]
@@ -449,7 +445,7 @@ class ErasureCodeJax(ErasureCode):
                 "hier_lsub_donate", bs._fused_hier_lsub_donate,
                 (self._enc_bitmat32, cmat_sub, words),
                 {"m": m, "tile": tile, "wb": wb, "interpret": False})
-        cmat32 = jnp.asarray(cl.crc_tile_matrix_w32(tile // 4))
+        cmat32 = bs._crc_tile_w32_const(tile // 4)
         return bs.aot_compile(
             "fused_w32", bs.gf_encode_with_crc_pallas_w32,
             (self._enc_bitmat32, cmat32, words),

@@ -40,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..common import spans
 from ..common.util import next_pow2
 from ..ec import gf
-from . import device
+from . import const_cache, device
 from .profiler import device_profiler
 
 LANE = 128           # TPU lane width: byte-axis tiles must be multiples
@@ -665,27 +665,59 @@ def _fused_hier_acc_call(bitmat32, cmat_sub, adv, run_map, first_map,
       words)
 
 
-def _acc_launch_args(ntiles_run, tile: int, wb: int):
+def _crc_tile_const(tile: int, tally=None):
+    """Device-resident crc_tile_matrix(tile) (byte-layout kernels)."""
+    from . import crc32c_linear as cl
+    return const_cache.get(("crc_tile", tile),
+                           lambda: cl.crc_tile_matrix(tile), tally)
+
+
+def _crc_tile_w32_const(wt: int, tally=None):
+    """Device-resident crc_tile_matrix_w32(wt) (word-packed kernels:
+    the flat kernel's tile matrix and the hier kernels' sub-block
+    matrix are the same constant at the same word count)."""
+    from . import crc32c_linear as cl
+    return const_cache.get(("crc_tile_w32", wt),
+                           lambda: cl.crc_tile_matrix_w32(wt), tally)
+
+
+def _acc_launch_args(ntiles_run, tile: int, wb: int, tally=None):
     """Scalar-prefetch maps + fold matrices for one accumulator
     launch: run_map (run index per grid step, monotonic), first_map
     (1 at each run's first step), the per-step tile advance matrix and
-    the per-run si-position combine matrix.  Single source of truth
-    for the single-extent fold entry and the extents path — the two
-    must never diverge on the accumulator contract."""
+    the per-run si-position combine matrix — device-resident, each
+    uploaded once (ops/const_cache.py) and keyed by what it depends
+    on: the maps by the run layout, the matrices by the operating
+    point.  Single source of truth for the single-extent fold entry
+    and the extents path — the two must never diverge on the
+    accumulator contract."""
     from . import crc32c_linear as cl
-    counts = np.asarray(list(ntiles_run), dtype=np.int64)
-    run_map = np.repeat(np.arange(len(counts), dtype=np.int32), counts)
-    first_map = np.zeros(len(run_map), dtype=np.int32)
-    starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
-    # zero-tile filler runs (launch-shape bucketing) have no first
-    # step; their start index aliases the next run's (or falls off the
-    # end) and must not set a flag
-    first_map[starts[counts > 0]] = 1
-    adv = jnp.asarray(cl.crc_advance_matrix(tile), dtype=jnp.int8)
-    comb = jnp.asarray(
-        cl.crc_combine_matrix((tile // 4) // wb, 4 * wb),
-        dtype=jnp.int8)
-    return jnp.asarray(run_map), jnp.asarray(first_map), adv, comb
+    layout = tuple(int(n) for n in ntiles_run)
+    counts = np.asarray(layout, dtype=np.int64)
+
+    def build_run_map():
+        return np.repeat(np.arange(len(counts), dtype=np.int32), counts)
+
+    def build_first_map():
+        first = np.zeros(int(counts.sum()), dtype=np.int32)
+        starts = np.concatenate(([0], np.cumsum(counts)[:-1]))
+        # zero-tile filler runs (launch-shape bucketing) have no first
+        # step; their start index aliases the next run's (or falls off
+        # the end) and must not set a flag
+        first[starts[counts > 0]] = 1
+        return first
+
+    run_map = const_cache.get(("acc_run_map", layout), build_run_map,
+                              tally)
+    first_map = const_cache.get(("acc_first_map", layout),
+                                build_first_map, tally)
+    adv = const_cache.get(("crc_advance", tile),
+                          lambda: cl.crc_advance_matrix(tile), tally)
+    s = (tile // 4) // wb
+    comb = const_cache.get(
+        ("crc_combine", s, 4 * wb),
+        lambda: cl.crc_combine_matrix(s, 4 * wb), tally)
+    return run_map, first_map, adv, comb
 
 
 def _hier_acc_core(bitmat32, cmat_sub, adv, combine, run_map, first_map,
@@ -792,13 +824,44 @@ def gf_encode_with_crc_w32_fold(bitmat32, cmat_sub, words, m: int,
     return parity, cl.combine_crcs_pow2(lb, 4 * wb)
 
 
-@functools.partial(jax.jit, static_argnames=("block_bytes",))
-def _combine_run(lbits, block_bytes: int):
-    """jit shell over combine_crcs_pow2 for the per-run folds of the
-    extents path (cached per (shape, block_bytes))."""
+@functools.partial(jax.jit, static_argnames=(
+    "nruns", "block_bytes", "r_tot", "rows"))
+def _combine_run(lbits, cuts, nruns: int, block_bytes: int,
+                 r_tot: int = 0, rows: int = 0):
+    """jit shell over combine_crcs_runs for the extents path: every
+    run's full blocks fold to one L per shard in ONE program per
+    launch, (nruns, k+m, 32).  `cuts` is the launch's run layout
+    (_run_cuts), data and not shape, so the jit key is the launch's
+    (blocks, runs) bucket.  With `rows` the L rows come as the per-tile
+    kernels emit them — (ntiles * rows, 32) or (ntiles, rows, 32), the
+    first `r_tot` rows of a tile real — and are re-laid to stream
+    order (r, ntiles, 32) in here, not by eager dispatches around the
+    call."""
     from . import crc32c_linear as cl
+    if rows:
+        with jax.named_scope("ec.crc_relayout"):
+            lbits = jnp.transpose(
+                lbits.reshape(-1, rows, 32)[:, :r_tot], (1, 0, 2))
     with jax.named_scope("ec.crc_combine"):
-        return cl.combine_crcs_pow2(lbits, block_bytes)
+        return cl.combine_crcs_runs(lbits, cuts, nruns, block_bytes)
+
+
+def _run_cuts(body_blocks, nblocks: int, tally=None):
+    """The run layout _combine_run reads, device-resident per layout:
+    (2, nblocks) i32 — per block its distance to its run's last body
+    block, and its run (-1: none).  `body_blocks` is (first block,
+    count) per run."""
+    layout = tuple((int(b), int(n)) for b, n in body_blocks)
+
+    def build():
+        cuts = np.zeros((2, nblocks), dtype=np.int32)
+        cuts[1] = -1
+        for i, (boff, nb) in enumerate(layout):
+            cuts[0, boff:boff + nb] = np.arange(nb - 1, -1, -1)
+            cuts[1, boff:boff + nb] = i
+        return cuts
+
+    return const_cache.get(("run_cuts", layout, nblocks), build, tally)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "tile"))
@@ -897,6 +960,12 @@ def gf_encode_extents_with_crc(bitmat, bitmat32, runs, m: int,
             wb=wb, combine=combine))
 
 
+# exact per-launch counts a submit handle carries for the launch
+# queue's counters (a split launch carries the sums of its two halves)
+_HANDLE_COUNTS = ("padded_bytes", "h2d_bytes", "h2d_const_bytes",
+                  "const_hits", "const_misses", "d2h_bytes")
+
+
 def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
                                       use_w32: bool | None = None,
                                       force_xla: bool | None = None,
@@ -956,12 +1025,8 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
                     combine=combine, donate=donate)))
             return {"split": parts, "n_runs": len(runs),
                     "path": "+".join(h["path"] for _, h in parts),
-                    "padded_bytes": sum(h["padded_bytes"]
-                                        for _, h in parts),
-                    "h2d_bytes": sum(h["h2d_bytes"]
-                                     for _, h in parts),
-                    "d2h_bytes": sum(h["d2h_bytes"]
-                                     for _, h in parts)}
+                    **{key: sum(h[key] for _, h in parts)
+                       for key in _HANDLE_COUNTS}}
     # operating point: big sequential drains ride the hier-crc kernel at
     # the autotuned tile; small/mixed drains keep the flat 2 KiB tile
     # where padding waste would dominate
@@ -1017,40 +1082,40 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         big.view("<u4").view(np.int32) if use_w32 and not force_xla
         else big)
     h2d.end()
-    rows = _crc_rows(r_tot)
     w32_out = False
-    lbits_devs = None
-    consts = []         # per-launch constant uploads (counted as H2D)
-    # ec.dispatch: constant matrices, the jitted call and the per-run
-    # combine dispatches — no host sync anywhere inside
+    # constants come from the device-resident cache (ops/const_cache):
+    # the tally is what THIS launch uploaded of them — nothing, once
+    # its operating point and run layout have been seen
+    tally = const_cache.Tally()
+    # ec.dispatch: the jitted call and the one run-combine program —
+    # no eager device operation and no host sync anywhere inside
     dispatch = spans.begin("ec.dispatch", spans_on,
                            padded_bytes=int(big.size))
+    lb_dev = None       # (pow2 runs, r, 32): ONE L array per launch
+    lb_src = None       # per-block L rows for _combine_run
+    rows = 0            # per-tile row count when lb_src is tile-major
     if force_xla:
-        cmat = jnp.asarray(cl.crc_tile_matrix(tile))
-        consts.append(cmat)
-        parity_dev, crc_bits = _aot_dispatch(
+        cmat = _crc_tile_const(tile, tally)
+        parity_dev, lb_src = _aot_dispatch(
             "fused_xla", gf_encode_with_crc_xla,
             (bitmat, cmat, staged), {"m": m, "tile": tile})
-        lb_all = jnp.transpose(crc_bits, (1, 0, 2))    # (r, ntiles, 32)
+        rows = r_tot                                   # (ntiles, r, 32)
         block_bytes = tile
         path = "xla"
     elif not use_w32:
         # byte-path Pallas kernel (TPU without the w32 layout): per-tile
         # L rows, device-combined per run below like the flat w32 path
-        cmat = jnp.asarray(cl.crc_tile_matrix(tile))
-        consts.append(cmat)
-        parity_dev, crc_flat = gf_encode_with_crc_pallas(
+        cmat = _crc_tile_const(tile, tally)
+        parity_dev, lb_src = gf_encode_with_crc_pallas(
             bitmat, cmat, staged, m)
-        lb_all = jnp.transpose(
-            crc_flat.reshape(ntiles_total, rows, 32)[:, :r_tot],
-            (1, 0, 2))                                 # (r, ntiles, 32)
+        rows = _crc_rows(r_tot)                     # (ntiles*rows, 32)
         block_bytes = tile
         path = "bytes"
     elif acc:
         # the overlapped accumulator kernel: one L block per RUN from
-        # the launch itself — no per-step lsub round-trip, no per-run
-        # combine dispatches, no sub-block host tail
-        cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
+        # the launch itself — no per-step lsub round-trip, no combine
+        # dispatch, no sub-block host tail
+        cmat_sub = _crc_tile_w32_const(wb, tally)
         # the L out-block is keyed by run count: bucket it to a power
         # of two as well (pad tiles ride a dummy trailing run, empty
         # filler runs contribute no grid steps), so (tiles, runs) jit
@@ -1061,24 +1126,21 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         nruns_acc = next_pow2(len(ntiles_run))
         ntiles_run += [0] * (nruns_acc - len(ntiles_run))
         run_map, first_map, adv, comb = _acc_launch_args(
-            ntiles_run, tile, wb)
-        consts += [cmat_sub, run_map, first_map, adv, comb]
+            ntiles_run, tile, wb, tally)
         acc_fn = _hier_acc_donate if donate else _hier_acc
-        parity_dev, lb = _aot_dispatch(
+        parity_dev, lb_dev = _aot_dispatch(
             "hier_acc_donate" if donate else "hier_acc", acc_fn,
             (bitmat32, cmat_sub, adv, comb, run_map, first_map,
              staged),
             {"m": m, "tile": tile, "wb": wb, "nruns": nruns_acc,
              "interpret": interpret})                  # (nruns, r, 32)
-        lbits_devs = [lb[i] for i in range(len(runs))]
         block_bytes = 4 * wb
         w32_out = True
         path = "hier_acc"
     elif hier:
-        cmat_sub = jnp.asarray(cl.crc_tile_matrix_w32(wb))
-        consts.append(cmat_sub)
+        cmat_sub = _crc_tile_w32_const(wb, tally)
         hier_fn = _fused_hier_lsub_donate if donate else _fused_hier_lsub
-        parity_dev, lb_all = _aot_dispatch(
+        parity_dev, lb_src = _aot_dispatch(
             "hier_lsub_donate" if donate else "hier_lsub", hier_fn,
             (bitmat32, cmat_sub, staged),
             {"m": m, "tile": tile, "wb": wb,
@@ -1087,55 +1149,45 @@ def gf_encode_extents_with_crc_submit(bitmat, bitmat32, runs, m: int,
         w32_out = True
         path = "hier_lsub"
     else:
-        wt = tile // 4
-        cmat32 = jnp.asarray(cl.crc_tile_matrix_w32(wt))
-        consts.append(cmat32)
-        parity_dev, crc_flat = _aot_dispatch(
+        cmat32 = _crc_tile_w32_const(tile // 4, tally)
+        parity_dev, lb_src = _aot_dispatch(
             "fused_w32", gf_encode_with_crc_pallas_w32,
             (bitmat32, cmat32, staged),
             {"m": m, "interpret": interpret})
-        lb_all = jnp.transpose(
-            crc_flat.reshape(ntiles_total, rows, 32)[:, :r_tot],
-            (1, 0, 2))                                 # (r, ntiles, 32)
+        rows = _crc_rows(r_tot)                     # (ntiles*rows, 32)
         block_bytes = tile
         w32_out = True
         path = "w32_flat"
-    if lbits_devs is None:
-        # per-run device combines dispatched NOW (still no host sync):
-        # each run's full blocks fold to one L per shard on device
-        lbits_devs = []
+    if lb_src is not None:
+        # every run's full blocks fold to one L per shard on device,
+        # all runs in ONE program dispatched NOW (still no host sync).
+        # Run count bucketed to a power of two like the accumulator's,
+        # so the jit key is the launch's (blocks, runs) bucket
+        body_blocks = []
         coff = 0
         for w, pr in zip(meta, padded):
-            nb = w // block_bytes
-            if nb:
-                boff = coff // block_bytes
-                lb_run = lb_all[:, boff:boff + nb]
-                # zero-PREFIX pad to the next power of two before the
-                # jitted combine: L(0^n || B) = L(B), so the pad is
-                # free, and it collapses the jit-cache key space from
-                # "every distinct extent length" to ~log2 shapes (a
-                # drain of varied object sizes must not recompile per
-                # length)
-                nb2 = next_pow2(nb)
-                if nb2 != nb:
-                    lb_run = jnp.pad(lb_run, ((0, 0), (nb2 - nb, 0),
-                                              (0, 0)))
-                lbits_devs.append(_combine_run(lb_run, block_bytes))
-            else:
-                lbits_devs.append(None)
+            body_blocks.append((coff // block_bytes, w // block_bytes))
             coff += pr.shape[1]
+        if any(nb for _, nb in body_blocks):
+            cuts = _run_cuts(body_blocks, big.shape[1] // block_bytes,
+                             tally)
+            lb_dev = _combine_run(
+                lb_src, cuts, nruns=next_pow2(len(runs)),
+                block_bytes=block_bytes, r_tot=r_tot, rows=rows)
     dispatch.end()
     return {"meta": meta, "padded": padded, "pads": pads,
-            "parity_dev": parity_dev, "lbits_devs": lbits_devs,
+            "parity_dev": parity_dev, "lb_dev": lb_dev,
             "block_bytes": block_bytes, "r_tot": r_tot, "m": m,
             "w32_out": w32_out, "big_width": big.shape[1],
             "path": path, "acc": acc,
-            # exact counts for the launch queue's transfer counters
+            # exact counts for the launch queue's transfer counters:
+            # a constant counts only when this launch uploaded it
             "padded_bytes": int(big.size),
-            "h2d_bytes": int(big.size)
-            + sum(int(c.nbytes) for c in consts),
-            "d2h_bytes": int(parity_dev.nbytes) + sum(
-                int(lb.nbytes) for lb in lbits_devs if lb is not None)}
+            "h2d_bytes": int(big.size) + tally.nbytes,
+            "h2d_const_bytes": tally.nbytes,
+            "const_hits": tally.hits, "const_misses": tally.misses,
+            "d2h_bytes": int(parity_dev.nbytes)
+            + (int(lb_dev.nbytes) if lb_dev is not None else 0)}
 
 
 def gf_encode_extents_with_crc_finalize(handle):
@@ -1162,32 +1214,35 @@ def gf_encode_extents_with_crc_finalize(handle):
     block_bytes = handle["block_bytes"]
     acc = handle.get("acc", False)
     # ec.d2h_wait: blocks until the device is done, then copies to the
-    # host — named for both, the host clock cannot part them
+    # host — named for both, the host clock cannot part them.  Two
+    # fetches a launch at most: the parity and the ONE L array of all
+    # its runs, cut apart below in numpy
     with spans.span("ec.d2h_wait", device_profiler().enabled):
         parity_big = np.asarray(handle["parity_dev"])
-        lbits_host = [None if lb is None else np.asarray(lb)
-                      for lb in handle["lbits_devs"]]
+        lb_dev = handle["lb_dev"]
+        lb_host = None if lb_dev is None else np.asarray(lb_dev)
     if handle["w32_out"]:
         parity_big = parity_big.view("<u4").view(np.uint8) \
             .reshape(handle["m"], handle["big_width"])
+    # (runs, k+m) u32; a launch none of whose runs holds a full block
+    # dispatched no combine and every body L is 0 (the rows past the
+    # real runs are the pow2 bucket's filler)
+    l_all = cl.bits_to_u32(lb_host) if lb_host is not None \
+        else np.zeros((len(meta), r_tot), dtype=np.uint32)
     out = []
     coff = 0
-    for w, pr, pad, lbits in zip(meta, padded, pads, lbits_host):
+    for i, (w, pr, pad) in enumerate(zip(meta, padded, pads)):
         par = parity_big[:, coff + pad:coff + pad + w]
         if acc:
             body = w                     # kernel L covers the full run
         else:
             nb = w // block_bytes        # full blocks = run body
             body = nb * block_bytes
-        if lbits is not None:
-            l = cl.bits_to_u32(lbits)                  # (k+m,) u32
-        else:
-            l = np.zeros(r_tot, dtype=np.uint32)
         tail_data = pr[:, pad + body:pad + w]
         tail_par = par[:, body:w]
         tail_bytes = np.concatenate([tail_data, tail_par], axis=0) \
             if w > body else np.zeros((r_tot, 0), dtype=np.uint8)
-        out.append((par, l, tail_bytes, body))
+        out.append((par, l_all[i], tail_bytes, body))
         coff += pr.shape[1]
     return out
 

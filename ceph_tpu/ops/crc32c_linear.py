@@ -89,7 +89,7 @@ def tile_crc_bits_w32(words, cmat32):
     return acc.astype(jnp.int32) & 1
 
 
-@functools.lru_cache(maxsize=16)
+@functools.lru_cache(maxsize=64)
 def crc_advance_matrix(nbytes: int) -> np.ndarray:
     """(32, 32) int8: row j = bits of A_{nbytes} e_j, so advancing an
     L-vector over `nbytes` zero bytes is `lbits @ this` (mod 2) — the
@@ -166,6 +166,47 @@ def combine_crcs_pow2(lbits, block_bytes: int):
         t //= 2
         bb *= 2
     return lbits[:, 0].astype(jnp.int32)
+
+
+def combine_crcs_runs(lbits, cuts, nruns: int, block_bytes: int):
+    """Combine the per-block L-vectors of a whole launch into one L
+    per shard per RUN, in one program whose shape does not depend on
+    where the runs lie.
+
+    lbits: (r, T, 32) int32 0/1, block t of shard r' in stream order;
+    cuts: (2, T) int32 — row 0 the number of blocks between block t
+    and the end of its run (0 for a run's last block), row 1 the run
+    block t belongs to (-1: no run — a run's pad blocks, the launch's
+    bucket filler).  Returns (nruns, r, 32) int32 0/1.
+
+    L(B_0||...||B_{n-1}) = XOR_t A^{(n-1-t) * block_bytes} L(B_t), so
+    every block is advanced over the bytes that follow it in its run —
+    level j advances by 2^j blocks where bit j of the distance is set,
+    ceil(log2 T) masked (r*T, 32) x (32, 32) int8 matmuls — and the
+    runs' blocks are then summed mod 2 by one matmul against the
+    one-hot (nruns, T) run matrix.  The run layout is DATA here (a
+    device-resident constant per layout, ops/const_cache.py), so the
+    jit key is (T, nruns) — the launch's own pow2 bucket — and no
+    slice, pad or transpose is dispatched per run around it."""
+    import jax
+    import jax.numpy as jnp
+    r, t, _ = lbits.shape
+    dist, run_id = cuts[0], cuts[1]
+    x = lbits.astype(jnp.int8)
+    for j in range((t - 1).bit_length()):
+        mat = jnp.asarray(crc_advance_matrix(block_bytes << j))
+        adv = jax.lax.dot_general(
+            x, mat, dimension_numbers=(((2,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32) & 1
+        x = jnp.where((((dist >> j) & 1) == 1)[None, :, None],
+                      adv.astype(jnp.int8), x)
+    onehot = (run_id[None, :] ==
+              jnp.arange(nruns, dtype=jnp.int32)[:, None])
+    out = jax.lax.dot_general(
+        onehot.astype(jnp.int8), x,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.int32)              # (nruns, r, 32)
+    return out & 1
 
 
 def fold_run_crc(lbody: int, body_bytes: int, seed: int,

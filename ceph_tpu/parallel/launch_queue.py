@@ -86,6 +86,14 @@ def _codec_label(plugin) -> str:
         return type(plugin).__name__
 
 
+# launch-queue counter <- the exact count a fused submit handle carries
+_HANDLE_COUNTERS = (("ec_h2d_bytes", "h2d_bytes"),
+                    ("ec_h2d_const_bytes", "h2d_const_bytes"),
+                    ("ec_const_cache_hits", "const_hits"),
+                    ("ec_const_cache_misses", "const_misses"),
+                    ("ec_d2h_bytes", "d2h_bytes"))
+
+
 def _extents_bucket(handle) -> str:
     """Jit-bucket key of a fused-extents submit handle: the (path,
     padded width, bucketed run count) triple the pow2 launch-shape
@@ -271,9 +279,19 @@ def _build_queue_perf(name: str):
                              "the fused jit: after per-run tile padding "
                              "and the pow2 tile-count bucket")
             .add_u64_counter("ec_h2d_bytes",
-                             "host bytes handed to the device by fused "
-                             "launches (staged data + per-launch "
-                             "constant matrices)")
+                             "host bytes launches uploaded to the "
+                             "device: the staged data, and a constant "
+                             "only when that launch uploaded it")
+            .add_u64_counter("ec_h2d_const_bytes",
+                             "the part of ec_h2d_bytes that was "
+                             "constants (crc matrices, run-layout "
+                             "maps): uploaded on a cache miss only "
+                             "(ops/const_cache.py)")
+            .add_u64_counter("ec_const_cache_hits",
+                             "constants a fused launch took "
+                             "device-resident from the cache")
+            .add_u64_counter("ec_const_cache_misses",
+                             "constants a fused launch had to upload")
             .add_u64_counter("ec_d2h_bytes",
                              "bytes fused launches read back to the "
                              "host (parity + crc L-bits), counted at "
@@ -644,10 +662,8 @@ class ECLaunchQueue:
                     if self.perf:
                         # what the launch hands over and will read
                         # back, known from the shapes at submit
-                        self.perf.inc("ec_h2d_bytes",
-                                      handle["h2d_bytes"])
-                        self.perf.inc("ec_d2h_bytes",
-                                      handle["d2h_bytes"])
+                        for counter, key in _HANDLE_COUNTERS:
+                            self.perf.inc(counter, handle[key])
                 else:
                     padded = sum(s.nbytes for s in subs)
                 # plugins that know their real jit-key axes (the jax

@@ -438,8 +438,11 @@ def test_padded_bytes_from_the_shapes(written_cluster):
         == 2 * 6 * 2048
     assert q["ec_host_launch_padded_bytes"] \
         - two["ec_host_launch_padded_bytes"] == 2 * 8 * 2048
-    # staged words + per-launch constants up; parity + crc bits down
-    assert q["ec_h2d_bytes"] > q["ec_host_launch_padded_bytes"]
+    # staged words up, and a constant only in the launch that uploaded
+    # it (another test of this process may have); parity + crc bits down
+    assert q["ec_h2d_bytes"] == \
+        q["ec_host_launch_padded_bytes"] + q["ec_h2d_const_bytes"]
+    assert q["ec_const_cache_hits"] + q["ec_const_cache_misses"] > 0
     assert q["ec_d2h_bytes"] >= q["ec_host_launch_padded_bytes"] // 2
 
 
