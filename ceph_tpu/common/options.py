@@ -217,9 +217,6 @@ register_options([
            "byte-axis tile of the GF matmul kernel", Level.DEV, min=128),
     Option("tpu_fused_crc", bool, True,
            "emit shard crc32c from the encode launch", Level.DEV),
-    Option("tpu_batch_window_ms", float, 0.0,
-           "max time to hold EC ops for cross-transaction batching",
-           Level.DEV, min=0.0),
     Option("ec_dispatch_ahead_depth", int, 2,
            "max encode drains kept in flight on the device before the "
            "completion stage materializes the oldest (dispatch-ahead "
@@ -237,12 +234,6 @@ register_options([
            Level.DEV),
     # per-host EC launch queue (cross-PG continuous batching on the
     # MeshService seam; docs/PIPELINE.md "Host launch queue")
-    Option("osd_ec_host_batch", bool, True,
-           "route EC encode launches of every PG on the host through "
-           "one per-device launch queue that coalesces runs from "
-           "different PGs into super-batch launches (per-PG in-order "
-           "completion and failure containment preserved); off = each "
-           "PG launches its own drains"),
     Option("osd_ec_host_batch_window_us", float, 250.0,
            "max microseconds a submitted run waits in the host launch "
            "queue for co-batching before the window fires; 0 launches "

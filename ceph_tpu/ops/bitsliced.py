@@ -230,71 +230,10 @@ def _make_gf_kernel_w32(interpret: bool):
     return _gf_kernel_w32
 
 
-def _stream_group(k: int) -> int:
-    """Bit-planes per streaming grid step: as many as fit a 128-lane
-    matrix block (the Pallas TPU block divisibility rule AND the MXU's
-    native contraction depth).  0 = streaming unsupported for this k
-    (non-power-of-two chunk rows; use the all-planes kernel)."""
-    if 128 % (4 * k) == 0:
-        g = min(8, 128 // (4 * k))
-        if 8 % g == 0:
-            return g
-    return 0
-
-
-def _make_gf_kernel_w32_stream(interpret: bool, k: int, g: int):
-    """Streaming kernel: the bit-plane GROUP index is the INNERMOST
-    grid axis, so each grid step extracts g planes (g*4k = 128 rows —
-    one MXU-native block), runs one matmul, and XOR-folds the mod-2
-    partial into a persistent VMEM scratch accumulator ((a+b)&1 ==
-    (a&1)^(b&1) over GF(2), so the accumulator is i8).  Neither the
-    full concatenated (32k, W) plane buffer (8x the input tile) nor
-    more than one group's matmul product is ever live — the VMEM cut
-    larger tiles need.  (An unrolled
-    in-kernel sum chain OOMs VMEM — every partial stays allocated on
-    the kernel stack — and lax.dynamic_slice on the matrix doesn't
-    lower in Pallas TPU, so the grid axis IS the plane loop.)"""
-    ngroups = 8 // g
-
-    def _kern(bitmat_ref, in_ref, out_ref, acc_ref):
-        gi = pl.program_id(1)
-        m = out_ref.shape[0]
-        mask = jnp.int32(0x01010101)
-
-        @pl.when(gi == 0)
-        def _init():
-            acc_ref[:] = jnp.zeros_like(acc_ref)
-
-        j0 = gi * g
-        w = in_ref[:]
-        planes = jnp.concatenate(
-            [_words_to_bytes((w >> (j0 + jj)) & mask, interpret)
-             for jj in range(g)], axis=0)               # (g*4k, W) i8
-        part = jax.lax.dot_general(
-            bitmat_ref[:], planes,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32,
-        )                                               # (32m, W)
-        acc_ref[:] = acc_ref[:] ^ (part & 1).astype(jnp.int8)
-
-        @pl.when(gi == ngroups - 1)
-        def _emit():
-            prod = acc_ref[:]
-            out = prod[0:4 * m].astype(jnp.int32)
-            for i in range(1, 8):
-                out = out + (prod[i * 4 * m:(i + 1) * 4 * m]
-                             .astype(jnp.int32) << i)
-            out_ref[:] = _bytes_to_words(out.astype(jnp.uint8),
-                                         interpret)
-    return _kern
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("r", "tile", "interpret", "stream"))
+@functools.partial(jax.jit, static_argnames=("r", "tile", "interpret"))
 def gf_bitmatmul_pallas_w32(bitmat32: jnp.ndarray, words: jnp.ndarray,
                             r: int, tile: int = DEFAULT_TILE,
-                            interpret: bool = False,
-                            stream: bool = False) -> jnp.ndarray:
+                            interpret: bool = False) -> jnp.ndarray:
     """Word-packed path: operates on i32 words end to end so no device
     relayout is ever paid (a host numpy `.view('<u4')` is free; an XLA
     u8<->i32 bitcast on TPU is a physical retiling copy that costs more
@@ -304,40 +243,17 @@ def gf_bitmatmul_pallas_w32(bitmat32: jnp.ndarray, words: jnp.ndarray,
     k, w = words.shape
     wt = tile // 4                                     # lane words per step
     assert w % wt == 0, (w, wt)
-    if not stream:
-        return pl.pallas_call(
-            _make_gf_kernel_w32(interpret),
-            grid=(w // wt,),
-            in_specs=[
-                pl.BlockSpec((32 * r, 32 * k), lambda t: (0, 0)),
-                pl.BlockSpec((k, wt), lambda t: (0, t)),
-            ],
-            out_specs=pl.BlockSpec((r, wt), lambda t: (0, t)),
-            out_shape=jax.ShapeDtypeStruct((r, w), jnp.int32),
-            interpret=interpret,
-            **_parallel_grid(1, interpret),
-        )(bitmat32.astype(jnp.int8), words)
-    # streaming: plane-group index is the innermost grid axis; group
-    # gi's matrix block is bitmat32's contiguous column range for
-    # planes [gi*g, (gi+1)*g) — the w32 layout is plane-major, so the
-    # BlockSpec index is just (0, gi)
-    g = _stream_group(k)
-    if g == 0:
-        raise ValueError(
-            f"streaming w32 kernel needs 128 %% (4k) == 0 (k={k}); "
-            "use stream=False")
-    scratch = pltpu.VMEM((32 * r, wt), jnp.int8)
     return pl.pallas_call(
-        _make_gf_kernel_w32_stream(interpret, k, g),
-        grid=(w // wt, 8 // g),
+        _make_gf_kernel_w32(interpret),
+        grid=(w // wt,),
         in_specs=[
-            pl.BlockSpec((32 * r, g * 4 * k), lambda t, gi: (0, gi)),
-            pl.BlockSpec((k, wt), lambda t, gi: (0, t)),
+            pl.BlockSpec((32 * r, 32 * k), lambda t: (0, 0)),
+            pl.BlockSpec((k, wt), lambda t: (0, t)),
         ],
-        out_specs=pl.BlockSpec((r, wt), lambda t, gi: (0, t)),
+        out_specs=pl.BlockSpec((r, wt), lambda t: (0, t)),
         out_shape=jax.ShapeDtypeStruct((r, w), jnp.int32),
-        scratch_shapes=[scratch],
         interpret=interpret,
+        **_parallel_grid(1, interpret),
     )(bitmat32.astype(jnp.int8), words)
 
 

@@ -86,7 +86,7 @@ class PrewarmPlan:
         if plain_widths is None:
             plain_widths = [2048 << j for j in range(4)]
         if decode_widths is None:
-            # up to osd/ec_backend.ECBackend.DECODE_MAX_LAUNCH_W: the
+            # up to parallel/launch_queue.DECODE_MAX_LAUNCH_W: the
             # grouped recovery decode caps its concatenated launch
             # width there and the launch queue pow2-pads every decode,
             # so {pow2 <= cap} IS the full runtime decode width set

@@ -665,7 +665,6 @@ class OSDDaemon:
         self.map_event = threading.Event()
         self.pgs: dict[pg_t, PGState] = {}
         self.pg_lock = threading.RLock()
-        self._batch_armed: dict[int, bool] = {}   # backend -> window armed
         from concurrent.futures import ThreadPoolExecutor
         self._op_pool = ThreadPoolExecutor(
             max_workers=16, thread_name_prefix=f"osd.{osd_id}.op")
@@ -3660,8 +3659,6 @@ class OSDDaemon:
                                     int(msg.snapc[0]),
                                     is_delete=objop.delete)
             done = threading.Event()
-            window = float(self.cct.conf.get("tpu_batch_window_ms")
-                           or 0)
             # version allocation and pipeline entry must be ATOMIC:
             # with ops running concurrently (sharded op pool), a later
             # version entering the FIFO pipeline first would commit out
@@ -3669,14 +3666,6 @@ class OSDDaemon:
             # blocking metadata prefetch runs BEFORE the lock.
             staged = be.make_op(txn, done.set, top=top) \
                 if state.kind == "ec" else None
-            if window > 0 and state.kind == "ec":
-                # dynamic batch window (SURVEY section 7 "hard parts",
-                # BlueStore-deferred style): hold the pipeline drain
-                # briefly so concurrent client ops encode in ONE codec
-                # launch instead of one launch each.  Armed AFTER the
-                # prefetch: the window must cover enqueue, not the
-                # metadata RPCs.
-                self._arm_batch_drain(be, window)
             with state.lock:
                 version = state.next_version(self.osdmap.epoch)
                 top.set_info("version", str(version))
@@ -3710,33 +3699,6 @@ class OSDDaemon:
                                         self.osdmap.epoch,
                                         sent_ts=sent_ts))
         self.op_tracker.unregister(top, result)
-
-    def _arm_batch_drain(self, be, window_ms: float) -> None:
-        """One timer per backend per window: the first op entering an
-        idle window holds the drain and schedules the release; ops
-        arriving meanwhile pile into waiting_reads and flush together."""
-        with self.pg_lock:
-            armed = self._batch_armed.get(id(be))
-            if armed:
-                return
-            self._batch_armed[id(be)] = True
-        with be.lock:
-            be._hold += 1
-
-        def _release():
-            with self.pg_lock:
-                self._batch_armed[id(be)] = False
-            # check_ops must run UNDER be.lock (the batch() context
-            # manager's form): an unlocked drain races a concurrent
-            # locked check_ops and double-plans the head op
-            with be.lock:
-                be._hold -= 1
-                if be._hold == 0:
-                    be.check_ops()
-
-        t = threading.Timer(window_ms / 1000.0, _release)
-        t.daemon = True
-        t.start()
 
     # -- self-managed snapshots (reference SnapSet + make_writeable) --------
 
@@ -4037,8 +3999,7 @@ class OSDDaemon:
 
     def _host_launch_queue(self):
         """The per-host EC launch queue (cross-PG continuous batching,
-        parallel/launch_queue.py) when osd_ec_host_batch is on; None
-        otherwise (each PG then launches its own drains).  Handed out
+        parallel/launch_queue.py), with this daemon's knobs.  Handed out
         through the MeshService seam — it brokers the device plane, so
         it brokers the launch queue — and works with or without a
         configured mesh.  The queue's perf counters (launches,
@@ -4050,8 +4011,6 @@ class OSDDaemon:
         normal sum-across-daemons aggregation read n_daemons times
         the real launch/byte counts.  Every daemon still serves the
         host truth via the `launch queue status` asok."""
-        if not bool(self.cct.conf.get("osd_ec_host_batch")):
-            return None
         from ..parallel.service import MeshService
         queue = MeshService.host_launch_queue(
             window_us=float(self.cct.conf.get(
@@ -4078,7 +4037,6 @@ class OSDDaemon:
                 for pgid, st in self.pgs.items() if st.kind == "ec"}
         return {
             "osd": self.osd_id,
-            "enabled": bool(self.cct.conf.get("osd_ec_host_batch")),
             "queue": queue.status() if queue is not None else None,
             "pg_queue_drains": pgs,
         }

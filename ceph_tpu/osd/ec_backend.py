@@ -50,7 +50,8 @@ from ..common.spans import span
 from ..common.tracked_op import NULL_TRACKED
 from ..ec.interface import ErasureCodeError, ErasureCodeInterface
 from ..ops.profiler import device_profiler
-from ..parallel.launch_queue import DECODE_MAX_LAUNCH_W
+from ..parallel.launch_queue import (DECODE_MAX_LAUNCH_W, ECLaunchQueue,
+                                     _codec_label)
 from ..store.object_store import ObjectStore, Transaction
 from . import ec_transaction as ect
 from . import ec_util
@@ -235,16 +236,17 @@ class _Drain:
     # (op, oid, extent, run (k, W)) per stripe-aligned extent, op order
     work: list[tuple]
     kinds: list[str]                  # per work item: "fused" | "plain"
-    fused_handle: object | None       # plugin submit handle
+    fused_handle: object | None       # the queue's LaunchTicket
     fused_pos: dict[int, int]         # work index -> position in handle
-    plain_handle: tuple | None        # ("mesh"|"plugin"|"np", handle)
+    plain_handle: tuple | None        # ("queue", ticket) | ("mesh", h)
     plain_cols: dict[int, int]        # work index -> column offset
     t_assemble: float = 0.0
-    # flight-recorder records of DIRECT (non-queue) launches; queue
-    # launches are recorded by the queue itself and stitched back
-    # through the ticket's launch_id (ops/profiler.py)
-    prof_fused: object | None = None
+    # flight-recorder record of a MESH launch; queue launches are
+    # recorded by the queue itself and stitched back through the
+    # ticket's launch_id (ops/profiler.py)
     prof_plain: object | None = None
+    # the launch-queue tickets this drain holds (fused, plain)
+    tickets: list = field(default_factory=list)
 
 
 def _build_ec_perf(name: str):
@@ -329,7 +331,8 @@ def _build_ec_perf(name: str):
                              "CLAY plane-read repairs that fell back "
                              "to the full-read decode path")
             .add_u64_counter("ec_reconstruct_reads",
-                             "degraded client reads served by "
+                             "degraded reads (a client's, or an "
+                             "overwrite's pre-read) served by "
                              "reconstruct-on-read")
             .add_u64_counter("ec_reconstruct_read_bytes",
                              "logical bytes served by "
@@ -392,14 +395,15 @@ class ECBackend:
                 self._mesh_config_error(why)
                 mesh_codec = None
         self.mesh_codec = mesh_codec
-        # Per-host EC launch queue (parallel/launch_queue.py): when
-        # set, this backend's drains submit their encode runs to the
-        # shared queue — which coalesces them with OTHER PGs' runs
-        # into one super-batch launch per window — instead of issuing
-        # a private partial-occupancy launch.  Completion, in-order
+        # Per-host EC launch queue (parallel/launch_queue.py): every
+        # encode, decode and repair launch of this backend is a
+        # submission there (None = the host's own queue) — it coalesces
+        # them with OTHER PGs' runs into one super-batch launch per
+        # window, pads, counts and records them.  Completion, in-order
         # acks, and failure containment stay per-PG; the queue only
         # owns the launch.
-        self._launch_queue = launch_queue
+        self._launch_queue = launch_queue if launch_queue is not None \
+            else ECLaunchQueue.host_instance()
         # degraded-read fan-out wait (conf osd_ec_read_timeout): was a
         # hardcoded 30 s; timeouts now count (ec_read_timeouts) instead
         # of silently shaping latency
@@ -501,10 +505,9 @@ class ECBackend:
 
     def _note_fused_path(self, path: str | None) -> None:
         """Record which fused kernel family served a drain (hier_* =
-        the overlapped Pallas kernels, anything else a fallback).
-        Direct submits attribute at launch; launch-queue drains at
-        completion (the super-batch's path is unknown until the
-        shared launch fires)."""
+        the overlapped Pallas kernels, anything else a fallback), at
+        completion: the super-batch's path is unknown until the shared
+        launch fires."""
         self.fused_path = path
         if self.perf:
             self.perf.inc(
@@ -840,10 +843,9 @@ class ECBackend:
                 if done[0] or len(got) < self.k:
                     return
                 done[0] = True
-                use = dict(list(got.items())[: self.k])
-            logical = ec_util.decode(self.sinfo, self.ec_impl, use,
-                                     e.length)
-            self._rmw_read_complete(op, oid, e, logical)
+                have = dict(got)
+            self._rmw_read_complete(op, oid, e, self._reconstruct_read(
+                oid, have, chunk_len, e.length))
 
         if len(candidates) + len(got) < self.k:
             raise ErasureCodeError(5, f"unrecoverable: {oid} extent {e}")
@@ -1006,11 +1008,10 @@ class ECBackend:
         fused_set = set(fused_idx)
         drain.kinds = ["fused" if i in fused_set else "plain"
                        for i in range(len(work))]
-        # flight recorder (ops/profiler.py): direct launches record
-        # here; queue submissions carry the ops' trace ids so the
-        # queue's super-batch record can name its contributors
-        from ..parallel.launch_queue import (_codec_label,
-                                             _extents_bucket)
+        # flight recorder (ops/profiler.py): the queue records its own
+        # launches — submissions carry the ops' trace ids so its
+        # super-batch record can name its contributors; only a mesh
+        # launch is recorded here
         prof = device_profiler()
         traces = tuple(op.top.trace.trace_id for op in ready
                        if op.top.is_tracked) if prof.enabled else ()
@@ -1018,47 +1019,14 @@ class ECBackend:
             if fused_idx:
                 drain.fused_pos = {wi: p
                                    for p, wi in enumerate(fused_idx)}
-                fused_runs = [runs[i] for i in fused_idx]
-                if self._launch_queue is not None:
-                    # per-host continuous batching: the queue
-                    # coalesces these runs with other PGs' into one
-                    # super-batch launch; kernel-path attribution
-                    # waits for the launch (completion half)
-                    drain.fused_handle = \
-                        self._launch_queue.submit_extents(
-                            self.ec_impl, fused_runs, owner=id(self),
-                            traces=traces)
-                    if self.perf:
-                        self.perf.inc("ec_host_queue_drains")
-                else:
-                    rec = prof.begin(
-                        "fused_encode", codec=_codec_label(self.ec_impl),
-                        runs=len(fused_runs),
-                        nbytes=sum(r.size for r in fused_runs),
-                        traces=traces)
-                    drain.fused_handle = \
-                        self.ec_impl.encode_extents_with_crc_submit(
-                            fused_runs)
-                    prof.submitted(
-                        rec,
-                        self.ec_impl.launch_bucket(drain.fused_handle)
-                        if hasattr(self.ec_impl, "launch_bucket")
-                        else _extents_bucket(drain.fused_handle),
-                        path=drain.fused_handle.get("path")
-                        if isinstance(drain.fused_handle, dict)
-                        else None)
-                    drain.prof_fused = rec
-                    # kernel-path provenance (ISSUE 11): which fused
-                    # kernel served this drain — hier_acc/hier_lsub
-                    # are the overlapped Pallas family, anything else
-                    # is a fallback; surfaced as perf counters +
-                    # fused_path so a silent fallback at plugin init
-                    # is attributable from `perf dump`, not just a
-                    # slower bench row
-                    self._note_fused_path(
-                        drain.fused_handle.get("path")
-                        if isinstance(drain.fused_handle, dict)
-                        else None)
+                # per-host continuous batching: the queue coalesces
+                # these runs with other PGs' into one super-batch
+                # launch; kernel-path attribution waits for the launch
+                # (completion half)
+                drain.fused_handle = self._launch_queue.submit_extents(
+                    self.ec_impl, [runs[i] for i in fused_idx],
+                    owner=id(self), traces=traces)
+                drain.tickets.append(drain.fused_handle)
             if plain_idx:
                 col = 0
                 for i in plain_idx:
@@ -1086,52 +1054,23 @@ class ECBackend:
                     drain.prof_plain = rec
                     if self.perf:
                         self.perf.inc("ec_mesh_drains")
-                elif self._launch_queue is not None:
+                else:
                     drain.plain_handle = (
                         "queue", self._launch_queue.submit_chunks(
                             self.ec_impl, big, owner=id(self),
                             traces=traces))
-                    if self.perf and not fused_idx:
-                        self.perf.inc("ec_host_queue_drains")
-                elif hasattr(self.ec_impl, "encode_chunks_submit"):
-                    rec = prof.begin(
-                        "plain_encode", codec=_codec_label(self.ec_impl),
-                        nbytes=int(big.size), traces=traces)
-                    h = self.ec_impl.encode_chunks_submit(big)
-                    drain.plain_handle = ("plugin", h)
-                    prof.submitted(rec, f"c:{h[0]}:w{big.shape[1]}",
-                                   path=str(h[0]))
-                    drain.prof_plain = rec
-                else:
-                    # host-synchronous CPU plugins: nothing to defer —
-                    # the whole launch is the submit; device time 0
-                    rec = prof.begin(
-                        "plain_encode", codec=_codec_label(self.ec_impl),
-                        nbytes=int(big.size), traces=traces)
-                    drain.plain_handle = (
-                        "np", np.asarray(self.ec_impl.encode_chunks(big)))
-                    # jit=False: a pure-CPU encode has no compiled
-                    # program — its wall must not read as a "compile"
-                    prof.submitted(rec, f"c:np:w{big.shape[1]}",
-                                   path="np",
-                                   jit=getattr(self.ec_impl,
-                                               "jit_backed", False))
-                    prof.materialized(rec, 0.0)
+                    drain.tickets.append(drain.plain_handle[1])
+            if drain.tickets:
+                self.perf.inc("ec_host_queue_drains")
         except Exception:
             # withdraw any queue submissions this drain already made:
             # the owning ops are about to abort, and an orphaned
             # pending submission would launch (and hold) work nobody
             # will ever finalize
-            if getattr(drain.fused_handle, "is_launch_ticket", False):
-                drain.fused_handle.cancel()
-            # undo this drain's projection refs before the caller
-            # aborts the ops (a stale projection would quietly push
-            # every later append of these objects off the fused path)
-            for _, oid, _, _ in work:
-                self._sim_refs[oid] -= 1
-                if self._sim_refs[oid] <= 0:
-                    del self._sim_refs[oid]
-                    self._sim_chunk.pop(oid, None)
+            for t in drain.tickets:
+                t.cancel()
+            # and its projection refs, before the caller aborts the ops
+            self._drop_sim_refs(drain)
             raise
         # submit half done: the device work is in flight, no host sync
         # has happened (the launch/materialize split makes host-vs-
@@ -1194,31 +1133,23 @@ class ECBackend:
             self._materialize_and_commit(drain)
 
     def _materialize_and_commit(self, drain: _Drain) -> None:
-        import time as _time
-        t0 = _time.perf_counter()
+        t0 = time.perf_counter()
         prof = device_profiler()
         try:
             try:
                 fh = drain.fused_handle
-                if fh is None:
-                    fused_res = []
-                elif getattr(fh, "is_launch_ticket", False):
-                    # launch-queue drain: result() forces the shared
-                    # super-batch to launch if the window hasn't fired
-                    # (flush-on-demand keeps lone-PG sync semantics)
-                    # and demuxes THIS submission's per-run results
+                fused_res = []
+                if fh is not None:
+                    # result() forces the shared super-batch to launch
+                    # if the window hasn't fired (flush-on-demand keeps
+                    # lone-PG sync semantics) and demuxes THIS
+                    # submission's per-run results
                     fused_res = fh.result()
                     self._note_fused_path(fh.path)
-                else:
-                    t_f = _time.perf_counter()
-                    fused_res = \
-                        self.ec_impl.encode_extents_with_crc_finalize(fh)
-                    prof.materialized(drain.prof_fused,
-                                      _time.perf_counter() - t_f)
                 plain_par = None
                 if drain.plain_handle is not None:
                     kind, h = drain.plain_handle
-                    t_p = _time.perf_counter()
+                    t_p = time.perf_counter()
                     if kind == "queue":
                         plain_par = np.asarray(h.result())
                     elif kind == "mesh":
@@ -1232,13 +1163,7 @@ class ECBackend:
                                                "mesh plane disabled")
                         plain_par = mc.encode_flat_finalize(h)
                         prof.materialized(drain.prof_plain,
-                                          _time.perf_counter() - t_p)
-                    elif kind == "plugin":
-                        plain_par = self.ec_impl.encode_chunks_finalize(h)
-                        prof.materialized(drain.prof_plain,
-                                          _time.perf_counter() - t_p)
-                    else:
-                        plain_par = h
+                                          time.perf_counter() - t_p)
             except Exception as e:  # noqa: BLE001 — device/encode failure
                 if self.perf:
                     self.perf.inc("ec_drain_errors")
@@ -1247,11 +1172,8 @@ class ECBackend:
                 # is still pending — otherwise the window worker
                 # launches it for nobody (post-launch cancel is a
                 # no-op and the unread results are simply dropped)
-                for h in (drain.fused_handle,
-                          drain.plain_handle[1]
-                          if drain.plain_handle is not None else None):
-                    if getattr(h, "is_launch_ticket", False):
-                        h.cancel()
+                for t in drain.tickets:
+                    t.cancel()
                 if drain.plain_handle is not None and \
                         drain.plain_handle[0] == "mesh":
                     # mesh finalize failure: abort THIS drain's ops,
@@ -1262,7 +1184,7 @@ class ECBackend:
                 for op in drain.ops:
                     self._abort_op(op, e)
                 return
-            device_dt = _time.perf_counter() - t0
+            device_dt = time.perf_counter() - t0
             worked = {id(op) for op, _, _, _ in drain.work}
             # trace stitching (ops/profiler.py): the launch ids that
             # served this drain land as events on every contributing
@@ -1270,19 +1192,13 @@ class ECBackend:
             # the threshold lands FIRST, so slow-op blame (largest
             # gap ends at the event) names the bucket that compiled
             # instead of a bare "ec_encode_materialize"
-            stitches = []
-            for src in (fh, drain.plain_handle[1]
-                        if drain.plain_handle is not None else None):
-                if getattr(src, "is_launch_ticket", False) and \
-                        src.launch_id is not None:
-                    stitches.append((src.launch_id, src.bucket,
-                                     src.compiled, src.compile_s,
-                                     src.cache_hit))
-            for rec in (drain.prof_fused, drain.prof_plain):
-                if rec is not None:
-                    stitches.append((rec.launch_id, rec.bucket,
-                                     rec.compiled, rec.compile_s,
-                                     rec.cache_hit))
+            # (a ticket carries its launch record's fields; of a mesh
+            # launch the record itself is at hand)
+            stitches = [
+                (src.launch_id, src.bucket, src.compiled, src.compile_s,
+                 src.cache_hit)
+                for src in drain.tickets + [drain.prof_plain]
+                if src is not None and src.launch_id is not None]
             stall_s = prof.stall_s
             for op in drain.ops:
                 if id(op) in worked:
@@ -1310,7 +1226,7 @@ class ECBackend:
                     np.concatenate([run, par], axis=0)
             self._fold_drain_crcs(drain, encoded_by_op, fused_ls,
                                   crcs_by_op)
-            t1 = _time.perf_counter()
+            t1 = time.perf_counter()
             for op in drain.ops:
                 try:
                     self._commit_op(op, encoded_by_op[id(op)],
@@ -1322,7 +1238,7 @@ class ECBackend:
             if self.perf:
                 self.perf.tinc("ec_drain_device", device_dt)
                 self.perf.tinc("ec_drain_commit",
-                               _time.perf_counter() - t1)
+                               time.perf_counter() - t1)
         finally:
             self._drop_sim_refs(drain)
 
@@ -1527,7 +1443,7 @@ class ECBackend:
         fans out to the parity shards IMMEDIATELY — known-down holders
         fail synchronously, so a degraded object pays one extra fan-out,
         not a timeout — and the missing rows rebuild through the
-        batched decode path (launch queue / mesh / plugin decode), the
+        batched decode path (mesh / launch queue), the
         same machinery background repair uses.  The fan-out wait is
         `osd_ec_read_timeout` (was a hardcoded 30 s) and every expiry
         counts in ec_read_timeouts instead of silently returning
@@ -1604,12 +1520,12 @@ class ECBackend:
                           have: dict[int, np.ndarray],
                           chunk_len: int, span: int) -> np.ndarray:
         """Reconstruct-on-read: rebuild the missing data shards of a
-        degraded read through the batched decode path — the per-host
-        launch queue (co-batched with other PGs' repair decodes) when
-        one is wired, the mesh collective when that plane is up, the
-        plugin decode otherwise.  Sub-chunked codes (CLAY) keep the
-        dict-decode path: a partial chunk run does not respect their
-        plane layout."""
+        degraded read (a client's, or an overwrite's pre-read) through
+        the batched decode path — the mesh
+        collective when that plane is up, else the per-host launch
+        queue (co-batched with other PGs' repair decodes).  Sub-chunked
+        codes (CLAY) keep the dict-decode path: a partial chunk run
+        does not respect their plane layout."""
         if self.perf:
             self.perf.inc("ec_reconstruct_reads")
             self.perf.inc("ec_reconstruct_read_bytes", span)
@@ -1637,13 +1553,8 @@ class ECBackend:
             dense = np.zeros((self.n, chunk_len), dtype=np.uint8)
             for s, d in use.items():
                 dense[s] = d
-            if self._launch_queue is not None:
-                ticket = self._launch_queue.submit_decode(
-                    self.ec_impl, dense, erasures, owner=id(self))
-                dec = np.asarray(ticket.result())
-            else:
-                dec = np.asarray(
-                    self.ec_impl.decode_chunks(dense, erasures))
+            dec = np.asarray(self._launch_queue.submit_decode(
+                self.ec_impl, dense, erasures, owner=id(self)).result())
         nstripes = chunk_len // self.sinfo.chunk_size
         logical = dec[: self.k] \
             .reshape(self.k, nstripes, self.sinfo.chunk_size) \
@@ -1736,15 +1647,6 @@ class ECBackend:
     # once fan-out would, while still collapsing to one launch per
     # geometry group within each slice
     RECOVER_BATCH_MAX = 64
-    # max concatenated byte width of one grouped recovery decode
-    # launch (single source: parallel/launch_queue, which enforces the
-    # same cap on cross-PG coalescing): with the queue's pow2 padding
-    # this bounds the decode jit-bucket universe to {pow2 <= cap} x
-    # {cardinality <= m} — small enough for the boot prewarm
-    # (ops/prewarm.py) to cover exactly, so a recovery storm never
-    # mints a first-seen bucket.  A single object's chunk wider than
-    # the cap still launches alone (an object's chunk is atomic).
-    DECODE_MAX_LAUNCH_W = DECODE_MAX_LAUNCH_W
 
     def recover_shards_batch(
             self, items: list[tuple[hobject_t, list[int]]],
@@ -1955,7 +1857,7 @@ class ECBackend:
         stacked helper plane rows ride ONE batched GF matmul — the
         mesh collective when that plane is up, the per-host launch
         queue (co-batched with writes and other PGs' repairs)
-        otherwise, the plan's own device/host apply as the floor."""
+        otherwise."""
         plan = self._clay_plan(lost, helpers)
         rows_list = [
             self.ec_impl.repair_rows(
@@ -1972,14 +1874,11 @@ class ECBackend:
                 self._disable_mesh(e)
                 rebuilt_list = None
         if rebuilt_list is None:
-            if self._launch_queue is not None:
-                from ..common.util import concat_columns, split_columns
-                big, widths = concat_columns(rows_list)
-                out = np.asarray(self._launch_queue.submit_clay_repair(
-                    plan, big, owner=id(self)).result())
-                rebuilt_list = split_columns(out, widths)
-            else:
-                rebuilt_list = plan.apply_batch(rows_list)
+            from ..common.util import concat_columns, split_columns
+            big, widths = concat_columns(rows_list)
+            out = np.asarray(self._launch_queue.submit_clay_repair(
+                plan, big, owner=id(self)).result())
+            rebuilt_list = split_columns(out, widths)
         if self.perf:
             self.perf.inc("ec_clay_repair_launches")
             self.perf.inc("ec_clay_repairs", len(sts))
@@ -2030,11 +1929,9 @@ class ECBackend:
                 meshed = False
         if not meshed:
             if self.ec_impl.get_sub_chunk_count() == 1:
-                # one concatenated decode for the whole group — through
-                # the per-host launch queue when one is wired, so
-                # recovery decodes coalesce with OTHER PGs' repairs
-                # (and share occupancy accounting with writes) instead
-                # of issuing a private launch
+                # one concatenated decode for the whole group, which
+                # the launch queue coalesces with OTHER PGs' repairs
+                # (and counts with the writes' occupancy)
                 # width-capped slices (DECODE_MAX_LAUNCH_W): the
                 # concatenated width, pow2-padded by the queue, stays
                 # inside the prewarm-enumerable bucket set instead of
@@ -2044,7 +1941,7 @@ class ECBackend:
                 cur_w = 0
                 for st in sts:
                     w = st["chunk_len"]
-                    if cur and cur_w + w > self.DECODE_MAX_LAUNCH_W:
+                    if cur and cur_w + w > DECODE_MAX_LAUNCH_W:
                         slices.append(cur)
                         cur, cur_w = [], 0
                     cur.append(st)
@@ -2060,14 +1957,9 @@ class ECBackend:
                         for s, d in st["have"].items():
                             big[s, col:col + w] = d
                         col += w
-                    if self._launch_queue is not None:
-                        dec = np.asarray(
-                            self._launch_queue.submit_decode(
-                                self.ec_impl, big, list(erasures),
-                                owner=id(self)).result())
-                    else:
-                        dec = self.ec_impl.decode_chunks(
-                            big, list(erasures))
+                    dec = np.asarray(self._launch_queue.submit_decode(
+                        self.ec_impl, big, list(erasures),
+                        owner=id(self)).result())
                     col = 0
                     for st, w in zip(chunk_sts, widths):
                         rebuilt_per_st.append(

@@ -218,8 +218,6 @@ class LaunchTicket:
     fired — flush-on-demand) and finalized, then returns this
     submission's demultiplexed share of the results."""
 
-    is_launch_ticket = True
-
     def __init__(self, queue: "ECLaunchQueue", kind: str, key: tuple):
         self._queue = queue
         self.kind = kind

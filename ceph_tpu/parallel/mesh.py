@@ -442,11 +442,3 @@ class ClayRepairPlan:
         return np.asarray(bitsliced.gf_bitmatmul_xla(
             self._bitmat, jnp.asarray(rows), self.out_rows))
 
-    def apply_batch(self, rows_list) -> list[np.ndarray]:
-        """Batched single-device apply: objects' byte axes concatenate
-        into one launch, results demux per object (the non-mesh analog
-        of clay_repair_batch)."""
-        if not rows_list:
-            return []
-        big, widths = concat_columns(rows_list)
-        return split_columns(self.apply_device(big), widths)

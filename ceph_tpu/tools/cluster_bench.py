@@ -10,9 +10,7 @@ cluster.  Rows (one JSON line each):
   python -m ceph_tpu.tools.cluster_bench --seconds 5 --threads 8
 
 Matrix: replicated x3, EC k=2 m=1, EC k=8 m=3 (the reference's
-canonical profile) — each on MemStore; EC additionally with the
-dynamic batch window on vs off (tpu_batch_window_ms) to quantify the
-cross-transaction batching the TPU pipeline exists for.
+canonical profile) — each on MemStore.
 
 `--scale [N]` (default 64) is the CONTROL-PLANE row instead: stand up
 the largest thread-topology cluster the box allows, churn map epochs
@@ -138,17 +136,16 @@ def _make_pool(client, name: str, profile: str | None) -> str:
     return pool
 
 
-def _matrix(args) -> list[tuple[str, str | None, float]]:
+def _matrix(args) -> list[tuple[str, str | None]]:
     """ONE matrix for both topologies (the A/B claim depends on it)."""
-    rows = [("replicated", None, 0.0)]
+    rows = [("replicated", None)]
     if not args.quick:
-        rows.append(("ec_k2m1", "cb21", 0.0))
-    rows += [("ec_k8m3", "cb83", 0.0),
-             ("ec_k8m3_batched", "cb83", args.window_ms)]
+        rows.append(("ec_k2m1", "cb21"))
+    rows.append(("ec_k8m3", "cb83"))
     if args.mesh is not None:
         # mesh-plane A/B row: jax-plugin profile so the EC backends
         # actually acquire the MeshService codec (docs/MULTICHIP.md)
-        rows.append(("ec_k8m3_mesh", "cb83x", 0.0))
+        rows.append(("ec_k8m3_mesh", "cb83x"))
     return rows
 
 
@@ -180,8 +177,7 @@ def _row_mesh(c, args, profile) -> str | None:
         return None
 
 
-def _bench_row(c, client, args, name, profile, window,
-               extra: dict) -> dict:
+def _bench_row(c, client, args, name, profile, extra: dict) -> dict:
     pool = _make_pool(client, name, profile)
     res = bench_pool(c, client, pool, args.seconds, args.threads,
                      args.size)
@@ -190,7 +186,6 @@ def _bench_row(c, client, args, name, profile, window,
     # cluster AFTER the row ran, not from the CLI flag
     row = {"config": name, "objectstore": args.objectstore,
            "threads": args.threads, "obj_size": args.size,
-           "batch_window_ms": window,
            "mesh": _row_mesh(c, args, profile), **res, **extra}
     # device-plane provenance (ISSUE 15): EC rows embed the host
     # flight recorder's summary so a rate move is attributable to
@@ -208,8 +203,6 @@ def main(argv=None) -> int:
     ap.add_argument("--size", type=int, default=1 << 20)
     ap.add_argument("--osds", type=int, default=12)
     ap.add_argument("--objectstore", default="memstore")
-    ap.add_argument("--window-ms", type=float, default=4.0,
-                    help="batch window for the windowed EC rows")
     ap.add_argument("--quick", action="store_true",
                     help="small matrix (replicated + one EC profile)")
     ap.add_argument("--mesh", nargs="?", const="", default=None,
@@ -281,9 +274,7 @@ def main(argv=None) -> int:
                  data_dir=data_dir, mesh_devices=args.mesh) as c:
         client = c.client()
         _setup_profiles(client, mesh=args.mesh is not None)
-        for name, profile, window in _matrix(args):
-            for osd in c.osds:
-                osd.cct.conf.set("tpu_batch_window_ms", window)
+        for name, profile in _matrix(args):
             counters = {
                 "codec_launches": -sum(
                     getattr(st.backend, "batched_launches", 0)
@@ -293,7 +284,7 @@ def main(argv=None) -> int:
                     getattr(st.backend, "batched_extents", 0)
                     for osd in c.osds
                     for st in getattr(osd, "pgs", {}).values())}
-            _bench_row(c, client, args, name, profile, window, {})
+            _bench_row(c, client, args, name, profile, {})
             # report per-row deltas of the cumulative in-process
             # counters (unavailable cross-process)
             counters["codec_launches"] += sum(
@@ -804,25 +795,17 @@ def _main_scale(args) -> int:
 
 
 def _main_processes(args) -> int:
-    """Process-topology twin of the SAME matrix.  Per-OSD conf must
-    ride the spawn command line, so rows whose batch window differs
-    get their own cluster; codec launch counters live in other
-    processes and are not reported."""
+    """Process-topology twin of the SAME matrix; codec launch counters
+    live in other processes and are not reported."""
     from ..tools.proc_cluster import ProcCluster
 
-    by_window: dict[float, list] = {}
-    for name, profile, window in _matrix(args):
-        by_window.setdefault(window, []).append((name, profile, window))
-    for window, rows in by_window.items():
-        conf = {"tpu_batch_window_ms": window} if window else {}
-        with ProcCluster(n_osds=args.osds,
-                         objectstore=args.objectstore,
-                         conf=conf, mesh_devices=args.mesh) as c:
-            client = c.client()
-            _setup_profiles(client, mesh=args.mesh is not None)
-            for name, profile, w in rows:
-                _bench_row(c, client, args, name, profile, w,
-                           {"topology": "processes"})
+    with ProcCluster(n_osds=args.osds, objectstore=args.objectstore,
+                     mesh_devices=args.mesh) as c:
+        client = c.client()
+        _setup_profiles(client, mesh=args.mesh is not None)
+        for name, profile in _matrix(args):
+            _bench_row(c, client, args, name, profile,
+                       {"topology": "processes"})
     return 0
 
 

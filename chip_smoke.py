@@ -8,8 +8,7 @@ what `vstart` and `rados_cli` wrap — at a size a Ceph operator would
 call real (`rados bench` defaults on BASELINE.json configs[1]):
 
   12 OSDs + 1 mon, EC pool plugin=jax technique=cauchy k=8 m=3
-  stripe_unit=4096, pg_num 128, launch queue on (osd_ec_host_batch
-  default), MemStore.
+  stripe_unit=4096, pg_num 128, MemStore.
 
   codec cross-check   jax vs the isa CPU plugin on 1 MiB stripes for
                       k8m3 / k4m2 / k2m1 (parity, fused crcs vs host

@@ -20,13 +20,13 @@ from ceph_tpu.store import MemStore
 REG = ErasureCodePluginRegistry.instance()
 
 
-def make_backend(k=4, m=2, chunk=64, plugin="jerasure"):
+def make_backend(k=4, m=2, chunk=64, plugin="jerasure", queue=None):
     codec = REG.factory(plugin, {"k": str(k), "m": str(m)})
     sinfo = StripeInfo(stripe_width=k * chunk, chunk_size=chunk)
     store = MemStore()
     store.mount()
     shards = LocalShardBackend(store, pg_t(1, 0), k + m)
-    return ECBackend(codec, sinfo, shards), store
+    return ECBackend(codec, sinfo, shards, launch_queue=queue), store
 
 
 def oid(name):
@@ -533,11 +533,16 @@ def test_pipeline_subwrite_failure_drains_cleanly():
 
 
 def test_pipeline_encode_failure_aborts_cleanly():
-    """A device finalize failure aborts the drain's ops through the
-    in-order finish queue: error attached, pins and projections (incl.
-    the cross-drain _sim_chunk refs) fully released, later drains
-    unaffected."""
-    backend, _ = make_backend(plugin="jax")
+    """A device finalize failure aborts exactly the ops that rode the
+    failing launch, through the in-order finish queue: error attached,
+    pins and projections (incl. the cross-drain _sim_chunk refs) fully
+    released, acks in order — and the next launch of the same backend
+    succeeds and reads back.  Both ops of the pipeline window ride ONE
+    launch (the queue's window never fires on its own here: the first
+    finalize flushes both submissions), so both fail."""
+    from ceph_tpu.parallel.launch_queue import ECLaunchQueue
+    q = ECLaunchQueue(window_us=60_000_000.0)
+    backend, _ = make_backend(plugin="jax", queue=q)
     orig = backend.ec_impl.encode_extents_with_crc_finalize
     boom = {"armed": True}
 
@@ -550,23 +555,41 @@ def test_pipeline_encode_failure_aborts_cleanly():
     backend.ec_impl.encode_extents_with_crc_finalize = failing
     rng = np.random.default_rng(35)
     payloads = [rng.integers(0, 256, 512, dtype=np.uint8)
-                for _ in range(2)]
+                for _ in range(3)]
     acks = []
     ops = []
-    with backend.pipeline():
-        for i, p in enumerate(payloads):
-            txn = PGTransaction()
-            txn.write(oid(f"ef{i}"), 0, p)
-            ops.append(backend.submit_transaction(
-                txn, eversion_t(1, i + 1), lambda i=i: acks.append(i)))
-    assert acks == [0, 1]
-    assert ops[0].state == "failed" and ops[0].error is not None
-    assert ops[1].state == "done" and ops[1].error is None
-    np.testing.assert_array_equal(backend.read(oid("ef1"), 0, 512),
-                                  payloads[1])
-    assert len(backend.extent_cache) == 0
-    assert not backend._projected
-    assert not backend._sim_chunk and not backend._sim_refs
+
+    def submit(i):
+        txn = PGTransaction()
+        txn.write(oid(f"ef{i}"), 0, payloads[i])
+        ops.append(backend.submit_transaction(
+            txn, eversion_t(1, i + 1), lambda: acks.append(i)))
+
+    try:
+        with backend.pipeline():
+            submit(0)
+            submit(1)
+        assert q.status()["launches"] == 1
+        for op in ops:
+            assert op.state == "failed" and \
+                "injected finalize failure" in str(op.error)
+        assert backend.perf.dump()["ec_drain_errors"] == 2
+        assert len(backend.extent_cache) == 0
+        assert not backend._projected
+        assert not backend._sim_chunk and not backend._sim_refs
+        submit(2)
+        assert acks == [0, 1, 2]
+        assert q.status()["launches"] == 2
+        assert ops[2].state == "done" and ops[2].error is None
+        np.testing.assert_array_equal(
+            backend.read(oid("ef2"), 0, 512), payloads[2])
+        for i in (0, 1):
+            assert not backend.exists(oid(f"ef{i}"))
+        assert len(backend.extent_cache) == 0
+        assert not backend._projected
+        assert not backend._sim_chunk and not backend._sim_refs
+    finally:
+        q.close()
 
 
 def _mesh_pipeline_backend(k=4, m=2, chunk=64):

@@ -93,7 +93,9 @@ def test_cross_pg_fused_results_match_unbatched():
     across the shared launch."""
     q = ECLaunchQueue(window_us=WIN_NEVER)
     batched = [make_backend(i, q, "jax") for i in range(2)]
-    solo = [make_backend(10 + i, None, "jax") for i in range(2)]
+    # window 0: every submission is a launch of its own
+    alone = ECLaunchQueue(window_us=0)
+    solo = [make_backend(10 + i, alone, "jax") for i in range(2)]
     rng = np.random.default_rng(3)
     chunks = [rng.integers(0, 256, 512, dtype=np.uint8)
               for _ in range(4)]
@@ -109,6 +111,7 @@ def test_cross_pg_fused_results_match_unbatched():
             group[1].submit_transaction(txn, eversion_t(1, 1),
                                         lambda: None)
     assert q.status()["launches"] >= 1
+    assert alone.status()["launches"] == alone.status()["submissions"] == 3
     for bq, bs, name, ln in ((batched[0], solo[0], "x", 1024),
                              (batched[1], solo[1], "y", 512)):
         np.testing.assert_array_equal(bq.read(oid(name), 0, ln),
@@ -444,11 +447,108 @@ def test_mixed_width_batch_keeps_hier_kernel_interpret():
                 f"shard {s}"
 
 
+# -- the one route -----------------------------------------------------------
+
+class _DownShards(LocalShardBackend):
+    """Shards in `down` fail their reads at once (a known-down holder)."""
+    down: frozenset = frozenset()
+
+    def sub_read(self, shard, oid, off, length, on_done):
+        if shard in self.down:
+            on_done(shard, None)
+            return
+        super().sub_read(shard, oid, off, length, on_done)
+
+
+@pytest.mark.parametrize("plugin,k,m,append,degraded,recover", [
+    ("jax", 2, 1, "x", "d", "d"),          # jitted, fused appends
+    ("jerasure", 2, 1, "c", "d", "d"),     # host-synchronous
+    # sub-chunked: a degraded read is the codec's own per-object
+    # decode (no launch to route); one lost shard is a repair plan
+    ("clay", 4, 2, "c", None, "r"),
+])
+def test_backend_without_queue_argument_launches_through_host_queue(
+        plugin, k, m, append, degraded, recover):
+    """The ONE route from an EC op to the device: a backend built with
+    no `launch_queue` argument holds the host's queue, and its append,
+    overwrite, degraded read, degraded overwrite and recovery each show
+    up there as a launch of the expected kind — a direct plugin call creeping back
+    into ECBackend leaves a count where it was and fails here."""
+    from ceph_tpu.osd.ec_transaction import shard_oid
+    from ceph_tpu.store.object_store import Transaction
+    chunk = 1024
+    be = make_backend(0, None, plugin, k=k, m=m, chunk=chunk,
+                      shards_cls=_DownShards)
+    host = ECLaunchQueue.host_instance()
+    assert be._launch_queue is host
+
+    def counts():
+        st = host.status()
+        enc = st["launches"] - st["decode_launches"] \
+            - st["repair_launches"]
+        drains = be.perf.dump()
+        return {"x": drains["ec_fused_kernel_drains"]
+                + drains["ec_fused_fallback_drains"],
+                "c": drains["ec_plain_drains"], "enc": enc,
+                "d": st["decode_launches"], "r": st["repair_launches"]}
+
+    def rose(before, kind):
+        after = counts()
+        assert after[kind] > before[kind], (kind, before, after)
+        if kind in "xc":
+            assert after["enc"] > before["enc"], (before, after)
+
+    rng = np.random.default_rng(30)
+    payload = rng.integers(0, 256, k * chunk, dtype=np.uint8)
+    o = oid("route")
+    c0 = counts()
+    assert write_one(be, "route", payload) == [1]          # append
+    rose(c0, append)
+    c0 = counts()
+    patch = rng.integers(0, 256, 30, dtype=np.uint8)
+    txn = PGTransaction()
+    txn.write(o, 10, patch)                                # overwrite
+    done = []
+    be.submit_transaction(txn, eversion_t(1, 2), lambda: done.append(1))
+    assert done == [1]
+    rose(c0, "c")
+    payload[10:40] = patch
+    c0 = counts()
+    be.shards.down = frozenset({0})                        # degraded read
+    np.testing.assert_array_equal(be.read(o, 0, payload.size), payload)
+    if degraded is not None:
+        rose(c0, degraded)
+    c0 = counts()
+    patch = rng.integers(0, 256, 30, dtype=np.uint8)
+    txn = PGTransaction()
+    txn.write(o, 100, patch)           # degraded overwrite: its pre-read
+    be.submit_transaction(txn, eversion_t(1, 3), lambda: done.append(2))
+    assert done == [1, 2]
+    if degraded is not None:
+        rose(c0, degraded)
+    rose(c0, "c")
+    payload[100:130] = patch
+    be.shards.down = frozenset()
+    np.testing.assert_array_equal(be.read(o, 0, payload.size), payload)
+    goid = shard_oid(o, 1)                                 # recovery
+    cid = be.shards.cids[1]
+    lost = be.shards.store.read(cid, goid).copy()
+    t = Transaction()
+    t.remove(goid)
+    be.shards.store.queue_transactions(cid, [t])
+    c0 = counts()
+    pushed = {}
+    be.recover_shard(o, [1], lambda s, data, h: pushed.__setitem__(
+        s, np.asarray(data).copy()))
+    rose(c0, recover)
+    np.testing.assert_array_equal(pushed[1], lost)
+
+
 # -- deployment wiring -------------------------------------------------------
 
 def test_cluster_default_wiring_and_asok(tmp_path):
-    """osd_ec_host_batch defaults on: every EC PG of every OSD in the
-    host process routes drains through ONE queue, `launch queue
+    """Every EC PG of every OSD in the host process routes drains
+    through ONE queue, `launch queue
     status` (asok, incl. the ceph_cli three-word fold) surfaces the
     occupancy counters, and lat_ec_batch_wait reaches
     dump_latencies."""
@@ -471,7 +571,7 @@ def test_cluster_default_wiring_and_asok(tmp_path):
         assert queue is not None
         assert queue.status()["launches"] >= 1
         sts = [osd._asok_launch_queue_status({}) for osd in c.osds]
-        assert all(st["enabled"] for st in sts)
+        assert all(st["queue"]["launches"] >= 1 for st in sts)
         assert any(sum(st["pg_queue_drains"].values()) > 0
                    for st in sts)
         # the queue's perf set (incl. the wait histogram) registers
