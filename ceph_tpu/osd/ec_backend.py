@@ -1341,6 +1341,12 @@ class ECBackend:
                     and (objop.truncate_to is None or not existed)
                     and not objop.attrs)
                 rb.hinfo_old = hinfo.encode() if existed else None
+                # what every shard is about to write, in chunk space:
+                # the shard patches its chunk_crc from these bytes
+                rb.extents = [
+                    (self.sinfo.aligned_logical_offset_to_chunk_offset(
+                        e.off), e.length // self.sinfo.k)
+                    for e in op.plan.will_write.get(oid, [])]
             # anything not a pure append keeps the old object under a
             # generation so the shard can roll it back locally
             # (reference ecbackend.rst local-rollbackability contract)

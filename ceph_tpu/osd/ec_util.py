@@ -19,6 +19,7 @@ Re-expresses reference src/osd/ECUtil.{h,cc}:
 
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -26,6 +27,7 @@ import numpy as np
 from ..common import crc32c as _crc
 from ..common.spans import span
 from ..ec.interface import ErasureCodeInterface
+from .types import ghobject_t
 
 
 @dataclass(frozen=True)
@@ -74,18 +76,21 @@ class StripeInfo:
 
 
 HINFO_KEY = "hinfo_key"  # shard xattr name (reference ECUtil.cc get_hinfo_key)
-# Per-shard full-chunk crc32c, maintained BY THE SHARD on every write
-# once the object's cumulative hinfo is invalidated by an overwrite
-# (the integrity story for overwritten objects; the reference's
-# allow_ec_overwrites pools lean on deep-scrub reads the same way).
+# Per-shard full-chunk crc32c — ONE crc over ALL the shard object's
+# bytes (seed 0xffffffff, no final inversion: the convention of the
+# hinfo's cumulative hashes, so one function gives both) — maintained
+# BY THE SHARD on every write once the object's cumulative hinfo is
+# invalidated by an overwrite (the integrity story for overwritten
+# objects; the reference's allow_ec_overwrites pools lean on deep-scrub
+# reads the same way).  `refresh_chunk_crcs` keeps it in O(write): crc
+# is linear over GF(2), so an in-place overwrite patches the four bytes
+# from the bytes it changed.
 CHUNK_CRC_KEY = "chunk_crc"
 
 
 def chunk_crc_of(data) -> bytes:
-    from ..common import crc32c as _crc32c
-    import numpy as _np
-    return _crc32c.crc32c(_np.asarray(data).tobytes(),
-                          0xFFFFFFFF).to_bytes(4, "little")
+    return _crc.crc32c(np.asarray(data).tobytes(),
+                       0xFFFFFFFF).to_bytes(4, "little")
 
 
 def recovery_attrs(hinfo: "HashInfo", data) -> dict[str, bytes]:
@@ -97,19 +102,114 @@ def recovery_attrs(hinfo: "HashInfo", data) -> dict[str, bytes]:
     return attrs
 
 
+# How often the patch engaged, per calling thread: `refresh_chunk_crcs`
+# returns one int (its bytes), so the objects it patched and the ones
+# it re-hashed whole are tallied here and the caller that keeps
+# counters (OSDDaemon._apply_sub_write: `ec_shard_chunk_crc_patches` /
+# `_rehashes`) takes them right after the call, on the same thread.
+class _Tally(threading.local):
+    patches = 0
+    rehashes = 0
+
+
+_tally = _Tally()
+
+
+def take_chunk_crc_tally() -> tuple[int, int]:
+    """(objects patched, objects re-hashed whole) by the calling
+    thread's `refresh_chunk_crcs` calls since it last asked."""
+    out = (_tally.patches, _tally.rehashes)
+    _tally.patches = _tally.rehashes = 0
+    return out
+
+
+def _old_chunk_crc(store, cid, gen_oid, shard: int, rollback,
+                   size: int) -> int | None:
+    """The crc of the shard object's bytes BEFORE the entry, without
+    reading them: the chunk_crc attr the generation was cloned with,
+    or — on the first overwrite, no attr yet — the prior hinfo's
+    cumulative hash of this shard, which is the same crc while the
+    object was append-only."""
+    try:
+        return int.from_bytes(
+            store.getattr(cid, gen_oid, CHUNK_CRC_KEY), "little")
+    except KeyError:
+        pass
+    if rollback.hinfo_old is None:
+        return None
+    hinfo = HashInfo.decode(rollback.hinfo_old)
+    if not hinfo.crc_valid or hinfo.total_chunk_size != size \
+            or shard >= len(hinfo.cumulative_shard_hashes):
+        return None
+    return hinfo.get_chunk_hash(shard)
+
+
+def _patched_chunk_crc(store, cid, goid, shard: int, rollback
+                       ) -> tuple[int, int] | None:
+    """(new chunk_crc, bytes hashed) of an object the entry overwrote
+    in place, from the changed extents alone; None where only a whole
+    re-hash is right (see refresh_chunk_crcs).  For bytes [off, off+n)
+    of an object of size S changed in place,
+
+        crc(new) = crc(old) ^ zeros(crc32c(old ^ new, seed 0), S-off-n)
+
+    Old bytes come from the generation the entry's own transaction
+    cloned, so an entry applied twice patches with a zero delta."""
+    if rollback.kept_generation is None or rollback.extents is None:
+        return None
+    gen_oid = ghobject_t(goid.hobj, rollback.kept_generation, shard)
+    try:
+        size = store.stat(cid, gen_oid)
+        if store.stat(cid, goid) != size:
+            return None     # grew or shrank: offsets from the end moved
+    except KeyError:
+        return None
+    extents = sorted(rollback.extents)
+    end = 0
+    for off, n in extents:
+        if off < end or off + n > size:
+            return None     # overlapping, or past the old end
+        end = off + n
+    crc = _old_chunk_crc(store, cid, gen_oid, shard, rollback, size)
+    if crc is None:
+        return None
+    for off, n in extents:
+        delta = np.bitwise_xor(store.read(cid, gen_oid, off, n),
+                               store.read(cid, goid, off, n))
+        crc ^= _crc.crc32c_zeros(_crc.crc32c(delta.tobytes(), 0),
+                                 size - off - n)
+    return crc, sum(n for _, n in extents)
+
+
 def refresh_chunk_crcs(store, cid, shard: int, entries,
                        spans_on: bool = False) -> int:
     """Shard-side integrity upkeep after applying a sub-write: an
     object that has entered overwrite mode (a generation was kept, or
     a chunk_crc attr already exists from an earlier overwrite) gets
-    its full-chunk crc recomputed from local bytes.  Pure appends on
-    never-overwritten objects skip this — their cumulative hinfo is
-    still authoritative.  Returns the bytes it re-read and re-hashed
-    (the WHOLE shard object per overwritten object, whatever the
-    write's size: `ec_shard_chunk_crc_bytes`); each re-hash is an
+    its full-chunk crc brought up to date from local bytes.  Pure
+    appends on never-overwritten objects skip this — their cumulative
+    hinfo is still authoritative.
+
+    An in-place overwrite PATCHES the attr from the bytes it changed
+    (`_patched_chunk_crc`): the entry kept a generation, names its
+    chunk extents, all of them inside the old size, the size did not
+    change, and the old crc is known (the attr, or on the first
+    overwrite the prior hinfo's hash of this shard).  Everything else
+    — no generation (an append onto an object already in overwrite
+    mode), a size change, unknown extents (an entry from an older peer
+    or log), no usable old crc — re-reads and re-hashes the WHOLE
+    shard object.  The decision is taken from the entry and the store
+    alone.  A patch never reads the bytes it did not change, so bitrot
+    outside the extent keeps its mismatch for deep scrub; a whole
+    re-hash launders it.
+
+    Returns the bytes it passed through crc32c (the changed extents on
+    a patch, the object's size on a whole re-hash:
+    `ec_shard_chunk_crc_bytes`); `take_chunk_crc_tally` says how many
+    objects went which way.  Each object's upkeep is an
     `ec.chunk_crc_refresh` span when `spans_on`."""
+    from ..store.object_store import Transaction
     from .pg_log import LogOp
-    from .types import ghobject_t
     seen = set()
     hashed = 0
     for e in entries:
@@ -123,15 +223,23 @@ def refresh_chunk_crcs(store, cid, shard: int, entries,
             except KeyError:
                 continue   # append-only object: hinfo covers it
         with span("ec.chunk_crc_refresh", spans_on):
-            try:
-                data = store.read(cid, goid)
-            except KeyError:
-                continue
-            from ..store.object_store import Transaction
+            patched = _patched_chunk_crc(store, cid, goid, shard,
+                                         e.rollback)
+            if patched is not None:
+                crc, n = patched
+                attr = crc.to_bytes(4, "little")
+                _tally.patches += 1
+            else:
+                try:
+                    data = store.read(cid, goid)
+                except KeyError:
+                    continue
+                attr, n = chunk_crc_of(data), int(data.size)
+                _tally.rehashes += 1
             txn = Transaction()
-            txn.setattr(goid, CHUNK_CRC_KEY, chunk_crc_of(data))
+            txn.setattr(goid, CHUNK_CRC_KEY, attr)
             store.queue_transactions(cid, [txn])
-            hashed += int(data.size)
+            hashed += n
     return hashed
 
 

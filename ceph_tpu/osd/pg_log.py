@@ -51,6 +51,11 @@ class RollbackInfo:
     hinfo_old: bytes | None = None              # prior hinfo xattr
     old_chunk_size: int | None = None           # per-shard size before
     pure_append: bool = False                   # undo == truncate
+    # per-shard chunk extents [(chunk_off, len), ...] the entry wrote,
+    # the same on every shard (reference ObjectModDesc::
+    # rollback_extents); None = unknown (an older peer, an older
+    # persisted log) — ec_util.refresh_chunk_crcs patches from them
+    extents: list[tuple[int, int]] | None = None
 
 
 @dataclass
@@ -85,7 +90,7 @@ def entry_to_wire(e: LogEntry) -> list:
             e.op.value, rb.append_old_size, rb.old_chunk_size,
             rb.pure_append,
             rb.hinfo_old.hex() if rb.hinfo_old is not None else None,
-            rb.kept_generation]
+            rb.kept_generation, rb.extents]
 
 
 def entry_from_wire(w: list) -> LogEntry:
@@ -94,7 +99,11 @@ def entry_from_wire(w: list) -> LogEntry:
         RollbackInfo(append_old_size=w[4], old_chunk_size=w[5],
                      pure_append=w[6],
                      hinfo_old=bytes.fromhex(w[7]) if w[7] else None,
-                     kept_generation=w[8] if len(w) > 8 else None))
+                     kept_generation=w[8] if len(w) > 8 else None,
+                     # nine elements: written before extents rode the
+                     # entry
+                     extents=[(off, n) for off, n in w[9]]
+                     if len(w) > 9 and w[9] is not None else None))
 
 
 def _omap_key(e: LogEntry) -> bytes:
@@ -341,7 +350,7 @@ class ShardPGLog:
         entries with neither are removed and reported, so the primary's
         recovery rebuilds them from the authoritative shards.
         Returns the oids needing such recovery."""
-        from .ec_util import HINFO_KEY
+        from .ec_util import CHUNK_CRC_KEY, HINFO_KEY, chunk_crc_of
 
         undone = [e for e in self.log.entries if e.version > v]
         undone.sort(key=lambda e: e.version, reverse=True)
@@ -369,6 +378,17 @@ class ShardPGLog:
                         txn.setattr(goid, HINFO_KEY, rb.hinfo_old)
                     else:
                         txn.rmattr(goid, HINFO_KEY)
+                    # an append onto an object in overwrite mode also
+                    # re-hashed its chunk_crc: bring it back to the
+                    # bytes that stay (later overwrites PATCH the attr,
+                    # so a stale one would never heal)
+                    try:
+                        self.store.getattr(self.cid, goid, CHUNK_CRC_KEY)
+                        txn.setattr(goid, CHUNK_CRC_KEY, chunk_crc_of(
+                            self.store.read(self.cid, goid, 0,
+                                            rb.old_chunk_size)))
+                    except KeyError:
+                        pass
             else:
                 txn.remove(goid)
                 if e.oid not in removed:

@@ -108,52 +108,54 @@ uint32_t ceph_tpu_crc32c(uint32_t crc, const uint8_t *buf, size_t len) {
 
 /* ---------------- combine: crc(A||B) from crc(A), crc(B), len(B) -----
  *
- * GF(2) matrix method (zlib-style): advancing a CRC over n zero bytes is
- * multiplication of the crc (as a GF(2) 32-vector) by M_zero^n; combine =
- * shift crc(A) over len(B) zeros then xor crc(B).  This is also exactly
- * what the reference's ceph_crc32c_zeros enables (extending a crc across
+ * Advancing a CRC over n zero bytes multiplies it, as a polynomial over
+ * GF(2), by x^(8n) mod P (zlib's crc32_combine since 1.2.12; the matrix
+ * squaring this replaces cost ~70 us a call, and a shard pays one call
+ * per overwritten extent: ec_util.refresh_chunk_crcs); combine = shift
+ * crc(A) over len(B) zeros then xor crc(B).  This is also exactly what
+ * the reference's ceph_crc32c_zeros enables (extending a crc across
  * zero padding without touching memory).
  */
 
-static uint32_t gf2_times(const uint32_t *mat, uint32_t vec) {
-    uint32_t sum = 0;
-    int i = 0;
-    while (vec) {
-        if (vec & 1) sum ^= mat[i];
-        vec >>= 1;
-        i++;
+/* a(x) * b(x) mod P in the reflected representation (bit 31 is x^0):
+ * 32 shift-and-xor steps at most (zlib's multmodp).  a must not be 0;
+ * the callers pass powers of x, which are units mod P. */
+static uint32_t gf2_mulmod(uint32_t a, uint32_t b) {
+    uint32_t m = 1u << 31, p = 0;
+    for (;;) {
+        if (a & m) {
+            p ^= b;
+            if ((a & (m - 1)) == 0) break;
+        }
+        m >>= 1;
+        b = (b & 1) ? (b >> 1) ^ POLY_REFLECTED : b >> 1;
     }
-    return sum;
+    return p;
 }
 
-static void gf2_square(uint32_t *sq, const uint32_t *mat) {
-    for (int i = 0; i < 32; i++)
-        sq[i] = gf2_times(mat, mat[i]);
+/* x2n[k] = x^(2^k) mod P, k = 0..66: a zero BYTE is x^8, so bit k of a
+ * 64-bit byte length is x2n[k + 3].  Filled by squaring; no period of
+ * the polynomial is assumed. */
+static uint32_t x2n[67];
+static int x2n_ready = 0;
+
+/* at load, before any thread can call in */
+__attribute__((constructor)) static void init_x2n(void) {
+    if (x2n_ready) return;
+    uint32_t p = 1u << 30;                  /* x^1 */
+    for (int k = 0; k < 67; k++) {
+        x2n[k] = p;
+        p = gf2_mulmod(p, p);
+    }
+    x2n_ready = 1;
 }
 
+/* crc * x^(8 len) mod P: one multiplication per set bit of len. */
 uint32_t ceph_tpu_crc32c_zeros(uint32_t crc, uint64_t len) {
-    if (len == 0) return crc;
-    uint32_t even[32], odd[32];
-    /* odd = matrix for one zero *bit*: shift right, feed poly */
-    odd[0] = POLY_REFLECTED;
-    for (int i = 1; i < 32; i++)
-        odd[i] = 1u << (i - 1);
-    gf2_square(even, odd);   /* 2 bits */
-    gf2_square(odd, even);   /* 4 bits */
-    /* now loop: apply for each set bit of byte-length, matrices advance
-     * 8*2^k bits = 2^(k+3) */
-    uint64_t n = len;
-    /* start with matrix for 1 byte (8 bits): square 4-bit matrix once */
-    gf2_square(even, odd);   /* 8 bits = 1 byte */
-    uint32_t (*cur)[32] = &even, (*next)[32] = &odd;
-    do {
-        if (n & 1)
-            crc = gf2_times(*cur, crc);
-        n >>= 1;
-        if (!n) break;
-        gf2_square(*next, *cur);
-        uint32_t (*t)[32] = cur; cur = next; next = t;
-    } while (1);
+    init_x2n();
+    for (int k = 3; len && crc; len >>= 1, k++)
+        if (len & 1)
+            crc = gf2_mulmod(x2n[k], crc);
     return crc;
 }
 

@@ -178,12 +178,22 @@ def test_seeded_overwrites_match_the_reference(cluster, geometry):
     assert delta["ec_plain_drains"] == n_writes
     assert delta["ec_drain_submits"] == n_writes
     assert delta["ec_fused_kernel_drains"] == 0
-    # O(object), not O(write): every overwrite clones and re-hashes
+    # the rollback generation is O(object): every overwrite clones
     # the WHOLE shard object on each of the k+m shards
     assert delta["ec_shard_clone_bytes"] == \
         n_writes * n_shards * shard_bytes
+    # chunk_crc is O(write): each shard hashes the chunk bytes it
+    # wrote (one stripe unit per stripe the write touches) and patches
+    # its attr from them.  None of these writes forces a whole
+    # re-hash: all lie inside the object, none changes its size, and
+    # the first one has no attr yet but seeds the patch from the
+    # write_full's hinfo (valid, and as long as the shard object)
+    stripes_written = sum(-(-(off + n) // width) - off // width
+                          for off, n in writes)
     assert delta["ec_shard_chunk_crc_bytes"] == \
-        n_writes * n_shards * shard_bytes
+        stripes_written * n_shards * SU
+    assert delta["ec_shard_chunk_crc_patches"] == n_writes * n_shards
+    assert delta["ec_shard_chunk_crc_rehashes"] == 0
     # each sub-write tells its shard that the write before it is
     # rolled forward: that write's generation goes, the last one stays
     assert delta["ec_shard_generations_trimmed"] == \

@@ -175,6 +175,40 @@ def test_scrub_detects_bitrot_in_overwritten_object():
     assert res.clean and res.repaired
 
 
+def test_overwrite_does_not_launder_earlier_bitrot():
+    """A byte that rotted BEFORE an overwrite of another part of the
+    object stays a crc_mismatch after it: the shard patches its
+    chunk_crc from the bytes the overwrite changed and never reads the
+    rest.  The parent (PR 30) passes this scrub CLEAN: its whole-object
+    re-hash after every overwrite hashed the rotten byte into a fresh,
+    matching chunk_crc."""
+    from ceph_tpu.store.object_store import Transaction
+    backend, store = make_backend()
+    rng = np.random.default_rng(6)
+    oid = put(backend, "g7", rng.integers(0, 256, 4 * K * CHUNK,
+                                          dtype=np.uint8), 1)
+    put(backend, "g7", rng.integers(0, 256, 40, dtype=np.uint8), 2,
+        offset=3)
+    # rot a byte of shard 1 in the LAST stripe, then overwrite in the
+    # first: the second overwrite's extent is chunk bytes [0, CHUNK)
+    cid = spg_t(pg_t(1, 0), 1)
+    goid = shard_oid(oid, 1)
+    data = bytearray(store.read(cid, goid).tobytes())
+    data[3 * CHUNK + 7] ^= 0xFF
+    txn = Transaction()
+    txn.write(goid, 0, np.frombuffer(bytes(data), dtype=np.uint8))
+    store.queue_transactions(cid, [txn])
+    put(backend, "g7", rng.integers(0, 256, 40, dtype=np.uint8), 3,
+        offset=50)
+    res = scrub_mod.scrub_pg(backend, [oid], deep=True)
+    assert any(e.kind == "crc_mismatch" and e.shard == 1
+               for e in res.errors), res.errors
+    res = scrub_mod.scrub_pg(backend, [oid], deep=True, repair=True)
+    assert res.clean and res.repaired
+    res = scrub_mod.scrub_pg(backend, [oid], deep=True)
+    assert res.clean, res.errors
+
+
 def test_overwrite_survives_crash_replay(tmp_path):
     """FileStore: overwrite + kill (no clean umount) + remount replays
     the WAL; generation objects, hinfo flags, and chunk crcs all come
