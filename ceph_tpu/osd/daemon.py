@@ -92,8 +92,10 @@ class MessengerShardBackend(ShardBackend):
             # PeeringState min_size gating and degraded-write tolerance.
             self.degraded_shards.add(shard)
             self.daemon._pg_unclean(self.pgid)
+            self.perf.inc("ec_sub_writes_skipped_down")
             on_commit(shard)
             return
+        self.perf.inc("ec_sub_writes_sent")
         wire_entries = [entry_to_wire(e) for e in (log_entries or [])]
         if osd == self.daemon.osd_id:
             try:
@@ -1874,9 +1876,14 @@ class OSDDaemon:
         window until the map gives the slot a home.  True = no hole:
         the `clean` transition was made."""
         from ..crush.map import CRUSH_ITEM_NONE
-        holes = len(acting) < width or any(
+        holes = max(0, width - len(acting)) + sum(
             o == CRUSH_ITEM_NONE or not self.osdmap.is_up(o)
             for o in acting)
+        state = self.pgs.get(pgid)
+        if state is not None and state.kind == "ec":
+            # readable per PG (and so per pool) in `perf dump`: the
+            # share of a pool's PGs that serve degraded
+            state.backend.perf.set("ec_acting_holes", holes)
         if holes:
             with self.pg_lock:
                 self._pgs_undersized.add(pgid)

@@ -150,6 +150,8 @@ class LocalShardBackend(ShardBackend):
         # top: tracked op for wire-plane trace stitching — local
         # shards have no wire, so it is accepted and unused here
         slog = self.shard_logs[shard]
+        if self.perf:
+            self.perf.inc("ec_sub_writes_sent")
         if log_entries and at_version is not None:
             slog.append_to_txn(txn, log_entries, at_version)
         self.store.queue_transactions(self.cids[shard], [txn])
@@ -261,6 +263,10 @@ def _build_ec_perf(name: str):
                              "sub-write/encode failures absorbed")
             .add_gauge("ec_inflight_depth",
                        "drains in flight after last submit")
+            .add_gauge("ec_acting_holes",
+                       "shards of the acting set without a live "
+                       "holder when the PG's last recovery pass "
+                       "ended: > 0 = active+undersized+degraded")
             # object-metadata sweeps (ShardBackend.probe): how often
             # the primary's own shard answered, and what the rest cost
             # on the wire (docs/PIPELINE.md "Authoritative local shard")
@@ -292,6 +298,30 @@ def _build_ec_perf(name: str):
             .add_u64_counter("ec_plain_drains",
                              "drains that launched plain (no-crc) "
                              "parity for non-append extents")
+            # the degraded half of a pre-read (docs/PIPELINE.md
+            # "Overwrites on a degraded PG"): a data shard's holder
+            # is down, so the stripe comes from parity and a decode
+            .add_u64_counter("ec_rmw_reconstructs",
+                             "pre-reads that needed parity: a data "
+                             "shard failed, the extent was "
+                             "reconstructed")
+            .add_u64_counter("ec_rmw_parity_reads",
+                             "parity sub-reads those pre-reads sent")
+            .add_histogram("lat_ec_rmw_reconstruct",
+                           "per reconstructing pre-read: every data "
+                           "shard answered (some failed) -> parity "
+                           "read, decode wait, extent reconstructed")
+            .add_histogram("lat_ec_decode_wait",
+                           "per reconstruct: the blocking wait on "
+                           "the decode launch's ticket")
+            .add_u64_counter("ec_sub_writes_sent",
+                             "shard transactions handed to a live "
+                             "holder (k+m an op on a whole acting "
+                             "set)")
+            .add_u64_counter("ec_sub_writes_skipped_down",
+                             "shard transactions not sent: the "
+                             "shard's holder is down (a hole in the "
+                             "acting set, left to recovery)")
             .add_time_avg("ec_drain_assemble",
                           "host assemble+launch time per drain")
             .add_time_avg("ec_drain_device",
@@ -835,6 +865,7 @@ class ECBackend:
         candidates = [s for s in range(self.n) if s not in tried]
         glock = threading.Lock()
         done = [False]
+        t0 = time.perf_counter()
 
         def on_done(shard, data):
             with glock:
@@ -844,13 +875,27 @@ class ECBackend:
                     return
                 done[0] = True
                 have = dict(got)
-            self._rmw_read_complete(op, oid, e, self._reconstruct_read(
-                oid, have, chunk_len, e.length))
+            logical = self._reconstruct_read(oid, have, chunk_len,
+                                             e.length)
+            self.perf.hinc("lat_ec_rmw_reconstruct",
+                           time.perf_counter() - t0)
+            # inside the op's `prepare` phase (no phase anchor)
+            op.top.mark_event("ec_rmw_reconstruct")
+            self._rmw_read_complete(op, oid, e, logical)
 
         if len(candidates) + len(got) < self.k:
             raise ErasureCodeError(5, f"unrecoverable: {oid} extent {e}")
-        for s in candidates[: self.k - len(got)]:
-            self.shards.sub_read(s, oid, chunk_off, chunk_len, on_done)
+        ask = candidates[: self.k - len(got)]
+        self.perf.inc("ec_rmw_reconstructs")
+        self.perf.inc("ec_rmw_parity_reads", len(ask))
+        # the sends of the second round (and, where a parity shard is
+        # this OSD's own, its read and what follows); the wait for the
+        # replies crosses threads and is `lat_ec_rmw_reconstruct`'s
+        with span("ec.rmw_parity_read", device_profiler().enabled,
+                  pgid=self.perf.name):
+            for s in ask:
+                self.shards.sub_read(s, oid, chunk_off, chunk_len,
+                                     on_done)
 
     def _rmw_read_complete(self, op, oid, e, logical) -> None:
         with self.lock:
@@ -1524,7 +1569,7 @@ class ECBackend:
 
     def _reconstruct_read(self, oid: hobject_t,
                           have: dict[int, np.ndarray],
-                          chunk_len: int, span: int) -> np.ndarray:
+                          chunk_len: int, nbytes: int) -> np.ndarray:
         """Reconstruct-on-read: rebuild the missing data shards of a
         degraded read (a client's, or an overwrite's pre-read) through
         the batched decode path — the mesh
@@ -1532,40 +1577,48 @@ class ECBackend:
         queue (co-batched with other PGs' repair decodes).  Sub-chunked
         codes (CLAY) keep the dict-decode path: a partial chunk run
         does not respect their plane layout."""
-        if self.perf:
-            self.perf.inc("ec_reconstruct_reads")
-            self.perf.inc("ec_reconstruct_read_bytes", span)
-        use = dict(list(sorted(have.items()))[: self.k])
-        if self.ec_impl.get_sub_chunk_count() != 1:
-            return ec_util.decode(self.sinfo, self.ec_impl, use, span)
-        survivors = tuple(sorted(use))
-        erasures = [s for s in range(self.n) if s not in use]
-        targets = tuple(s for s in range(self.k) if s not in use)
-        dec = None
-        if self.mesh_codec is not None:
-            try:
-                avail = np.stack([use[s] for s in survivors])
-                rows = self.mesh_codec.decode_flat(avail, survivors,
-                                                   targets)
-                dec = np.zeros((self.n, chunk_len), dtype=np.uint8)
+        with span("ec.reconstruct", device_profiler().enabled,
+                  pgid=self.perf.name):
+            if self.perf:
+                self.perf.inc("ec_reconstruct_reads")
+                self.perf.inc("ec_reconstruct_read_bytes", nbytes)
+            use = dict(list(sorted(have.items()))[: self.k])
+            if self.ec_impl.get_sub_chunk_count() != 1:
+                return ec_util.decode(self.sinfo, self.ec_impl, use, nbytes)
+            survivors = tuple(sorted(use))
+            erasures = [s for s in range(self.n) if s not in use]
+            targets = tuple(s for s in range(self.k) if s not in use)
+            dec = None
+            if self.mesh_codec is not None:
+                try:
+                    avail = np.stack([use[s] for s in survivors])
+                    rows = self.mesh_codec.decode_flat(avail, survivors,
+                                                       targets)
+                    dec = np.zeros((self.n, chunk_len), dtype=np.uint8)
+                    for s, d in use.items():
+                        dec[s] = d
+                    for i, t in enumerate(targets):
+                        dec[t] = rows[i]
+                except Exception as e:  # noqa: BLE001 — mesh died mid-read
+                    self._disable_mesh(e)
+                    dec = None
+            if dec is None:
+                dense = np.zeros((self.n, chunk_len), dtype=np.uint8)
                 for s, d in use.items():
-                    dec[s] = d
-                for i, t in enumerate(targets):
-                    dec[t] = rows[i]
-            except Exception as e:  # noqa: BLE001 — mesh died mid-read
-                self._disable_mesh(e)
-                dec = None
-        if dec is None:
-            dense = np.zeros((self.n, chunk_len), dtype=np.uint8)
-            for s, d in use.items():
-                dense[s] = d
-            dec = np.asarray(self._launch_queue.submit_decode(
-                self.ec_impl, dense, erasures, owner=id(self)).result())
-        nstripes = chunk_len // self.sinfo.chunk_size
-        logical = dec[: self.k] \
-            .reshape(self.k, nstripes, self.sinfo.chunk_size) \
-            .transpose(1, 0, 2).reshape(-1)
-        return logical[:span]
+                    dense[s] = d
+                ticket = self._launch_queue.submit_decode(
+                    self.ec_impl, dense, erasures, owner=id(self))
+                # the calling thread blocks here until the decode launch
+                # has run and its result is on the host
+                with span("ec.decode_wait", device_profiler().enabled,
+                          pgid=self.perf.name) as sp:
+                    dec = np.asarray(ticket.result())
+                self.perf.hinc("lat_ec_decode_wait", sp.wall_s)
+            nstripes = chunk_len // self.sinfo.chunk_size
+            logical = dec[: self.k] \
+                .reshape(self.k, nstripes, self.sinfo.chunk_size) \
+                .transpose(1, 0, 2).reshape(-1)
+            return logical[:nbytes]
 
     # -- recovery (reference continue_recovery_op :570) ---------------------
     #

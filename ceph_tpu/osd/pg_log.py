@@ -245,10 +245,39 @@ class ShardPGLog:
         """Replace this shard's log with the authoritative one (a stale
         shard rejoining: its data is healed by recovery, its history by
         adoption — reference PGLog::merge_log for the divergent-free
-        case)."""
+        case).  Every object with an entry this shard never applied —
+        written, overwritten or deleted while its holder was away — is
+        MISSING here from now on (reference pg_missing_t, built from
+        the same comparison): the stale head object goes in the
+        transaction that adopts the log, so recovery, which rebuilds
+        the shard objects a holder lacks, rebuilds it from k current
+        shards, and no read is ever answered from the old bytes.
+
+        What this shard logged and the authoritative log does not hold
+        is DIVERGENT: a write the holder applied before it went away
+        and the others rolled back.  It is undone first, the way a
+        current shard undoes it (`rollback_to`: the kept generation
+        becomes the head again, so neither the bytes nor the generation
+        stay behind); what cannot be undone locally is removed, and so
+        missing like the rest."""
+        theirs = {(e.version, e.oid) for e in entries}
+        oldest = min((e.version for e in entries), default=head)
+        mine = self.log.entries
+        # a write nobody else kept is the END of this log: only what
+        # follows the last entry both logs hold is undone (entries a
+        # PG merge folded in lie between shared ones and stay)
+        shared = max((i for i, e in enumerate(mine)
+                      if e.version <= oldest
+                      or (e.version, e.oid) in theirs), default=-1)
+        if shared < len(mine) - 1:
+            self.rollback_to(mine[shared].version if shared >= 0
+                             else eversion_t())
         txn = _txn()
         txn.touch(self.moid)
         txn.omap_clear(self.moid)
+        for oid in {e.oid for e in entries
+                    if e.version > self.info.last_update}:
+            txn.remove(ghobject_t(oid, shard=self.shard))
         if entries:
             txn.omap_setkeys(self.moid, {
                 _omap_key(e): json.dumps(entry_to_wire(e)).encode()

@@ -86,6 +86,14 @@ def _codec_label(plugin) -> str:
         return type(plugin).__name__
 
 
+# batch kind -> its name in the flight recorder and in the per-kind
+# counters of launched rows, `ec_host_launch_{in,out}_bytes.<name>`
+_KIND_NAMES = {"x": "fused_encode", "c": "plain_encode", "d": "decode",
+               "r": "clay_repair"}
+_KIND_BYTE_KEYS = {kind: (f"ec_host_launch_in_bytes.{name}",
+                          f"ec_host_launch_out_bytes.{name}")
+                   for kind, name in _KIND_NAMES.items()}
+
 # launch-queue counter <- the exact count a fused submit handle carries
 _HANDLE_COUNTERS = (("ec_h2d_bytes", "h2d_bytes"),
                     ("ec_h2d_const_bytes", "h2d_const_bytes"),
@@ -306,6 +314,10 @@ def _build_queue_perf(name: str):
             .add_u64_counter("ec_host_decode_launches",
                              "recovery/reconstruct decode super-batch "
                              "launches")
+            .add_u64_counter("ec_host_decode_runs",
+                             "decode submissions those launches "
+                             "carried (runs per decode launch = "
+                             "this / ec_host_decode_launches)")
             .add_u64_counter("ec_host_repair_launches",
                              "CLAY repair-plan super-batch launches")
             .add_gauge("ec_host_occupancy_pct",
@@ -611,10 +623,33 @@ class ECLaunchQueue:
                 self.perf.inc("ec_host_cross_pg_launches")
             if batch.kind == "d":
                 self.perf.inc("ec_host_decode_launches")
+                self.perf.inc("ec_host_decode_runs", nruns)
             elif batch.kind == "r":
                 self.perf.inc("ec_host_repair_launches")
             self.perf.set("ec_host_occupancy_pct", round(occupancy, 2))
+            self._count_kind_bytes(batch.kind, subs)
         return batch
+
+    def _count_kind_bytes(self, kind: str, subs: "list[_Sub]") -> None:
+        """The rows this launch handed to its kernel and the rows it
+        took back, in bytes, unpadded, under its kind's name — what
+        was launched, no more: which of it was needed is for whoever
+        reads the counters to say.  fused / plain: the k data rows in,
+        the m parity rows out (a fused launch's crcs are not rows);
+        decode: the k survivor rows in (the submission carries all
+        k+m, erased ones zero), one row out per ERASED shard, the
+        parity shard a pre-read never asked for included; clay_repair:
+        the helper rows in."""
+        key_in, key_out = _KIND_BYTE_KEYS[kind]
+        if kind == "r":
+            self.perf.dinc(key_in, sum(s.nbytes for s in subs))
+            return
+        width = sum(r.shape[1] for s in subs for r in s.runs)
+        plugin = subs[0].plugin
+        rows_out = len(subs[0].extra) if kind == "d" \
+            else plugin.get_coding_chunk_count()
+        self.perf.dinc(key_in, plugin.get_data_chunk_count() * width)
+        self.perf.dinc(key_out, rows_out * width)
 
     def _note_launch_error(self) -> None:
         with self._stats_lock:
@@ -634,8 +669,7 @@ class ECLaunchQueue:
         # covers the dispatch (and a first-bucket compile)
         prof = device_profiler()
         rec = prof.begin(
-            {"x": "fused_encode", "c": "plain_encode",
-             "d": "decode", "r": "clay_repair"}.get(kind, kind),
+            _KIND_NAMES.get(kind, kind),
             codec=_codec_label(subs[0].plugin),
             runs=sum(s.n_runs for s in subs),
             nbytes=sum(s.nbytes for s in subs),
@@ -707,6 +741,14 @@ class ECLaunchQueue:
                 handle = ("np", np.asarray(plugin.decode_chunks(
                     big, list(subs[0].extra))))
                 padded = int(big.size)
+                if self.perf and getattr(plugin, "jit_backed", False):
+                    # the k survivor rows in, the erased rows out (the
+                    # decode matrix is device-resident)
+                    self.perf.inc(
+                        "ec_h2d_bytes",
+                        plugin.get_data_chunk_count() * big.shape[1])
+                    self.perf.inc("ec_d2h_bytes",
+                                  len(subs[0].extra) * big.shape[1])
             else:
                 bigs = [s.runs[0] for s in subs]
                 big = np.concatenate(bigs, axis=1) if len(bigs) > 1 \

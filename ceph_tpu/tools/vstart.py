@@ -228,11 +228,15 @@ class Cluster:
     # -- quiescence (the "all PGs active+clean" gate; reference
     #    qa/tasks/ceph_manager.wait_for_clean) -----------------------------
 
-    def _active_clean_once(self) -> tuple[bool, str]:
-        """One clean-state probe: every PG of every pool has a live
-        primary and a full acting set, every up OSD is on the current
-        map with peering settled, no recovery pending or running, and
-        no client ops in flight on any EC pipeline."""
+    def _active_once(self, clean: bool = True) -> tuple[bool, str]:
+        """One quiescence probe.  clean: every PG of every pool has a
+        live primary and a full acting set, every up OSD is on the
+        current map with peering settled, no recovery pending or
+        running, and no client ops in flight on any EC pipeline —
+        active+clean.  Not clean: the same with OSDs allowed to be
+        down and acting sets to have holes, as long as every PG keeps
+        its pool's min_size — active, undersized+degraded allowed,
+        none peering, none down."""
         from ..crush.map import CRUSH_ITEM_NONE
         from ..osd.types import pg_t
         m = self.mon.osdmap
@@ -242,7 +246,9 @@ class Cluster:
             if osd is None:
                 continue          # decommissioned (remove_osd)
             if not m.is_up(osd.osd_id):
-                return False, f"osd.{osd.osd_id} down"
+                if clean:
+                    return False, f"osd.{osd.osd_id} down"
+                continue          # down, not out: its PGs are degraded
             if osd.osdmap.epoch < epoch:
                 return False, (f"osd.{osd.osd_id} on epoch "
                                f"{osd.osdmap.epoch} < {epoch}")
@@ -274,10 +280,11 @@ class Cluster:
                     return False, f"pg {pgid} unmapped"
                 alive = sum(1 for o in acting
                             if o != CRUSH_ITEM_NONE and m.is_up(o))
-                if primary < 0 or alive < pool.size:
+                need = pool.size if clean else pool.min_size
+                if primary < 0 or alive < need:
                     return False, (f"pg {pgid} acting {alive}/"
-                                   f"{pool.size}")
-        return True, "active+clean"
+                                   f"{need}")
+        return True, "active+clean" if clean else "active"
 
     def wait_active_clean(self, timeout: float = 180.0,
                           stable_for: float = 1.0) -> None:
@@ -287,11 +294,25 @@ class Cluster:
         Event-driven settling for thrash tests: a liveness regression
         surfaces as the named stuck condition instead of hiding behind
         a wall-clock grace."""
+        self._wait_settled(True, timeout, stable_for)
+
+    def wait_active(self, timeout: float = 180.0,
+                    stable_for: float = 1.0) -> None:
+        """Block until every PG of every pool is active — peered on
+        the current map with at least min_size live shards, recovery
+        passes and in-flight ops drained; undersized and degraded
+        allowed (an OSD down and not yet out) — and stays so, or
+        raise with the blocking condition (a PG under min_size is
+        down and never gets there)."""
+        self._wait_settled(False, timeout, stable_for)
+
+    def _wait_settled(self, clean: bool, timeout: float,
+                      stable_for: float) -> None:
         deadline = time.time() + timeout
         stable_since = None
         why = "never probed"
         while time.time() < deadline:
-            ok, why = self._active_clean_once()
+            ok, why = self._active_once(clean)
             if ok:
                 if stable_since is None:
                     stable_since = time.time()
@@ -301,7 +322,8 @@ class Cluster:
                 stable_since = None
             time.sleep(0.2)
         raise TimeoutError(
-            f"cluster not active+clean within {timeout}s: {why}")
+            f"cluster not {'active+clean' if clean else 'active'} "
+            f"within {timeout}s: {why}")
 
     def stop(self) -> None:
         for c in self._clients:

@@ -19,7 +19,11 @@ call real (`rados bench` defaults on BASELINE.json configs[1]):
   read back           every acked object, bytes compared
   degraded            kill + mark down a data-shard holder; read a
                       sample through the window (reconstruct-on-read
-                      decodes on the device); write through it
+                      decodes on the device); write through it; and
+                      overwrite 4 KiB blocks of an object that lost a
+                      data shard (a degraded read-modify-write: the
+                      pre-read reconstructs its stripe, a plain launch
+                      makes the parity), read back at once
   recover             revive; wait active+clean (grouped recovery
                       decode on the device); read everything back
   deep scrub          every OSD: errors == 0, device bytes > 0
@@ -77,6 +81,7 @@ class Size:
     writers: int = 16
     degraded_reads: int = 32
     degraded_writes: int = 8
+    degraded_overwrites: int = 8
     stripe_bytes: int = 1 << 20       # codec cross-check stripe
     clean_timeout_s: float = 300.0
 
@@ -177,13 +182,15 @@ def prewarm_plan(size: Size, profiler) -> dict:
     """Compile, as set-up, the launch shapes this run's object size
     produces: n concurrent writes are n runs of object_bytes/k (the
     pow2 bucketing collapses 1..writers runs to these), and degraded
-    reads / recovery decode whole chunks with 1..m shards missing."""
+    reads / recovery decode whole chunks with 1..m shards missing; a
+    degraded overwrite decodes and encodes one stripe unit a shard."""
     from ceph_tpu.ec import ErasureCodePluginRegistry
     from ceph_tpu.ec.interface import Profile
     from ceph_tpu.ops import prewarm
     codec = ErasureCodePluginRegistry.instance().factory(
         "jax", Profile(dict(PROFILE)))
     chunk = size.object_bytes // codec.get_data_chunk_count()
+    unit = int(PROFILE["stripe_unit"])
     counts, n = [], 1
     while n < size.writers:
         counts.append(n)
@@ -192,7 +199,9 @@ def prewarm_plan(size: Size, profiler) -> dict:
     plan = prewarm.PrewarmPlan(
         codec, profiler=profiler, budget_s=float(DEADLINE_S),
         run_shapes=[(chunk,) * n for n in counts],
-        plain_widths=[], decode_widths=[chunk])
+        plain_widths=[unit] if size.degraded_overwrites else [],
+        decode_widths=[chunk, unit] if size.degraded_overwrites
+        else [chunk])
     st = plan.run()
     _require(not st["truncated"] and not st["skipped"],
              f"prewarm entries failed to compile: {st['errors']}")
@@ -207,7 +216,8 @@ def prewarm_plan(size: Size, profiler) -> dict:
 _EC_KEYS = ("ec_drain_submits", "ec_fused_kernel_drains",
             "ec_fused_fallback_drains", "ec_drain_errors",
             "ec_mesh_errors", "ec_host_queue_drains",
-            "ec_reconstruct_reads", "ec_read_timeouts",
+            "ec_reconstruct_reads", "ec_rmw_reconstructs",
+            "ec_read_timeouts",
             "ec_repair_helper_bytes", "ec_repair_reconstructed_bytes",
             "ec_scrub_device_bytes", "ec_scrub_host_bytes")
 
@@ -246,6 +256,24 @@ def _read_and_compare(client, payloads, names, readers: int) -> int:
     parts = [names[w::readers] for w in range(readers)]
     with ThreadPoolExecutor(max_workers=readers) as ex:
         return sum(ex.map(work, [p for p in parts if p]))
+
+
+def _overwrite_blocks(client, payloads, name: str, size: Size,
+                      seed: int) -> int:
+    """4 KiB overwrites of an object written whole, one at a time,
+    walking its stripes and the chunks within them; `payloads[name]`
+    follows.  Returns how many were acknowledged."""
+    k, unit = int(PROFILE["k"]), int(PROFILE["stripe_unit"])
+    stripes = max(1, size.object_bytes // (k * unit))
+    io = client.open_ioctx(POOL)
+    now = bytearray(payloads[name])
+    for i in range(size.degraded_overwrites):
+        off = (i % stripes) * k * unit + (i // stripes % k) * unit
+        block = _payload(seed, 1_000_000 + i, unit)
+        io.write(name, block, off)
+        now[off:off + unit] = block
+    payloads[name] = bytes(now)
+    return size.degraded_overwrites
 
 
 def run(size: Size = Size(), seed: int = 1,
@@ -350,6 +378,9 @@ def run(size: Size = Size(), seed: int = 1,
             ph["degraded_writes"] = _write_objects(
                 client, deg_names, payloads, min(size.writers, 4))
             names = names + deg_names
+            ph["degraded_overwrites"] = _overwrite_blocks(
+                client, payloads, names[0], size, seed)
+            _read_and_compare(client, payloads, names[:1], 1)
         with rep.phase("recover", "serving"):
             cluster.revive_osd(victim)
             cluster.wait_active_clean(timeout=size.clean_timeout_s)
@@ -412,6 +443,9 @@ def run(size: Size = Size(), seed: int = 1,
     _require(out["aot"]["errors"] == 0, f"AOT errors: {out['aot']}")
     _require(c["ec_reconstruct_reads"] > 0,
              "no degraded read was served by reconstruct-on-read")
+    _require(c["ec_rmw_reconstructs"] == size.degraded_overwrites,
+             f"{size.degraded_overwrites} degraded overwrites, "
+             f"{c['ec_rmw_reconstructs']} reconstructing pre-reads")
     _require(c["ec_host_decode_launches"] > 0
              and c["ec_repair_reconstructed_bytes"] > 0,
              "recovery rebuilt nothing through the decode launch path")

@@ -17,6 +17,7 @@ import chip_smoke  # noqa: E402
 TINY = chip_smoke.Size(osds=12, pg_num=8, objects=10,
                        object_bytes=128 << 10, writers=4,
                        degraded_reads=6, degraded_writes=3,
+                       degraded_overwrites=5,
                        stripe_bytes=64 << 10, clean_timeout_s=120)
 
 
@@ -34,7 +35,8 @@ def test_refuses_to_run_without_a_chip():
 
 def test_phases_green_at_tiny_size_on_the_cpu_twin():
     """Every phase — cross-check, prewarm, boot, write, read back,
-    degraded reads + writes, recovery, deep scrub — at a tiny size,
+    degraded reads + writes + overwrites, recovery, deep scrub — at a
+    tiny size,
     served by the XLA twin and saying so; the same counters the chip
     run gates on are zero here too."""
     rep = chip_smoke.run(TINY, seed=7, require_platform="cpu")
@@ -47,6 +49,10 @@ def test_phases_green_at_tiny_size_on_the_cpu_twin():
     assert rep["bytes_acked"] == rep["ops_acked"] * TINY.object_bytes
     c = rep["counters"]
     assert c["ec_reconstruct_reads"] > 0
+    # the degraded read-modify-write: each 4 KiB overwrite of the
+    # object that lost a data shard reconstructs its stripe first
+    assert c["ec_rmw_reconstructs"] == TINY.degraded_overwrites
+    assert rep["phases"]["degraded"]["degraded_overwrites"] == 5
     assert c["ec_host_decode_launches"] > 0
     assert c["ec_repair_reconstructed_bytes"] > 0
     for key in ("ec_drain_errors", "ec_mesh_errors",
