@@ -39,15 +39,31 @@ CTRL_COMP = 0xFFF3    # compressed: meta={"a": algo}, data = comp(frame)
 _REGISTRY: dict[int, type["Message"]] = {}
 
 
+def _pack_head(tid: int, seq: int, meta_len: int, data_len: int) -> bytes:
+    """The fixed header, its own crc in the last four bytes."""
+    head = _HEADER.pack(MAGIC, tid, seq, meta_len, data_len, 0)[:-4]
+    return head + struct.pack("<I", _crc.crc32c(head, 0xFFFFFFFF))
+
+
 def encode_frame(tid: int, seq: int, meta: dict, data: bytes = b"") -> bytes:
     """Assemble one crc-protected wire frame (shared by typed messages
     and the messenger's control frames)."""
     meta_raw = json.dumps(meta, separators=(",", ":")).encode()
-    head = _HEADER.pack(MAGIC, tid, seq, len(meta_raw), len(data), 0)
-    hcrc = _crc.crc32c(head[:-4], 0xFFFFFFFF)
-    head = head[:-4] + struct.pack("<I", hcrc)
+    head = _pack_head(tid, seq, len(meta_raw), len(data))
     pcrc = _crc.crc32c(data, _crc.crc32c(meta_raw, 0xFFFFFFFF))
     return head + meta_raw + data + struct.pack("<I", pcrc)
+
+
+_ACK_META = b"{}"
+_ACK_PCRC = struct.pack("<I", _crc.crc32c(_ACK_META, 0xFFFFFFFF))
+
+
+def encode_ack(seq: int) -> bytes:
+    """encode_frame(CTRL_ACK, seq, {}) byte for byte, without the json
+    and payload-crc work: the meta is constant, only the header's crc
+    depends on seq."""
+    return (_pack_head(CTRL_ACK, seq, len(_ACK_META), 0)
+            + _ACK_META + _ACK_PCRC)
 
 
 def register_message(cls: type["Message"]) -> type["Message"]:
@@ -106,10 +122,7 @@ class Message:
                               separators=(",", ":")).encode()
         parts = self.data_parts()
         dlen = sum(len(p) for p in parts)
-        head = _HEADER.pack(MAGIC, self.type_id, seq, len(meta_raw),
-                            dlen, 0)
-        hcrc = _crc.crc32c(head[:-4], 0xFFFFFFFF)
-        head = head[:-4] + struct.pack("<I", hcrc)
+        head = _pack_head(self.type_id, seq, len(meta_raw), dlen)
         c = _crc.crc32c(meta_raw, 0xFFFFFFFF)
         for p in parts:
             c = _crc.crc32c(p, c)

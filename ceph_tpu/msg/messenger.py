@@ -12,11 +12,30 @@ out_seq/in_seq, ack frames, session resume + replay on reconnect):
   the replay window.  On reconnect the peer's HELLO tells the sender
   what arrived, so replay starts exactly after it and the receive path
   drops any already-seen seq — exactly-once delivery per session.
+- Acks ride.  An ack does nothing but trim that window, so it is not
+  worth a frame: a session OWES one while in_seq > last_acked, and
+  pays by writing the CTRL_ACK ahead of the next data frame to that
+  peer, in the same transport call (_write_raw) — no segment, no
+  wake-up and no system call of its own at either end.  At the rates
+  the cluster runs (a frame every ~100 ms per session) "ack when the
+  pipe goes idle" was an ack frame for every frame delivered, half of
+  all frames on the wire.  A stand-alone ack (_send_ack) is written
+  only when ACK_EVERY_FRAMES or ACK_EVERY_BYTES are owed, when the
+  debt is ACK_DELAY_S old (one timer per session, armed while it
+  owes), or at once for a replayed duplicate; a HELLO states in_seq
+  and so pays too.  (The reference's messages carry an ack_seq in
+  their header for the same reason.)
+- One write per frame: a frame's parts (head+meta, payload buffers,
+  crc) leave in ONE transport call (_write_once: joined when small,
+  writelines → one sendmsg when large, so a payload is never copied
+  into a frame buffer).  Part by part, TCP_NODELAY made each part a
+  segment and the peer's read_frame woke for each.
 - The Session owns the live TCP stream; Connections are facades over it,
   so a server reply issued after the client reconnected rides the new
   stream (the reference rebinds AsyncConnection to the existing session
   the same way on reconnect_ok).
-- Lossy connections (heartbeats may opt in) skip retention and resume.
+- Lossy connections (heartbeats may opt in) skip retention, resume
+  and acks: nothing is retained, so there is nothing to trim.
 
 Fault injection (reference ms_inject_socket_failures / ms_inject_delay_*
 in src/common/options.cc:1071-1092): per-messenger knobs that randomly
@@ -48,7 +67,7 @@ from concurrent.futures import TimeoutError as _FuturesTimeout
 from typing import Callable
 
 from .message import (CTRL_ACK, CTRL_COMP, CTRL_ENC, CTRL_HELLO, Message,
-                      encode_frame)
+                      encode_ack, encode_frame)
 from ..common import spans
 from .msgr_ledger import MsgrLedger, msgr_ledger
 
@@ -73,6 +92,42 @@ def _grow_socket_buffers(writer: asyncio.StreamWriter,
 # many retained frames the session is torn down (abnormal reset, like the
 # reference's session reset after policy limits) rather than leaking.
 UNACKED_HARD_CAP = 65536
+
+# What a lossless session may owe its peer before it pays with a
+# CTRL_ACK frame of its own (module doc, "acks ride"): frames delivered,
+# payload bytes delivered (bounds what a sender of 4 MiB frames retains
+# for a quiet reverse direction), seconds owed.  The timer is longer
+# than any round trip on purpose: the reverse frame carries the ack.
+ACK_EVERY_FRAMES = 64
+ACK_EVERY_BYTES = 8 << 20
+ACK_DELAY_S = 1.0
+
+# Buffers that leave in one transport call are joined first up to this
+# total (one `send`); past it they go as they are (`writelines`, one
+# `sendmsg`), so a large payload is never copied into a frame buffer.
+JOIN_UP_TO = 64 << 10
+
+
+def _write_once(writer: asyncio.StreamWriter, bufs) -> None:
+    """Hand `bufs` (non-empty buffers: a frame's parts, an ack ahead
+    of them) to the transport in ONE call — one system call and one
+    TCP segment where the socket takes it, so the peer's read_frame
+    wakes once for the whole frame."""
+    if len(bufs) == 1:
+        writer.write(bufs[0])
+        return
+    total = 0
+    for b in bufs:
+        total += len(b)
+    if total <= JOIN_UP_TO:
+        writer.write(b"".join(bufs))
+        return
+    if writer.transport.is_closing():
+        # transport.write() drops bytes on a lost connection and
+        # drain() then raises; writelines() has no such check and
+        # would queue them behind a writer callback on a dead fd
+        raise ConnectionResetError("wire closing")
+    writer.writelines(bufs)
 
 
 def _parse_raw(raw: bytes) -> tuple[int, int, bytes, bytes, int]:
@@ -142,6 +197,10 @@ class Session:
         self.broken = False
         self.down_since: float | None = None
         self.last_acked = 0       # highest seq we have acked to the peer
+        # what is owed since last_acked: payload bytes delivered, and
+        # the one timer armed while in_seq > last_acked
+        self.owed_bytes = 0
+        self.ack_timer: asyncio.TimerHandle | None = None
         # auth state (per wire epoch; re-derived on every HELLO):
         # conn_key signs/encrypts this connection, auth_identity is the
         # verified peer {entity, caps} (reference CephXAuthorizer
@@ -227,6 +286,24 @@ class Session:
         self._dec_ctr += 1
         return pt
 
+    def owes_ack(self) -> bool:
+        return self.lossless and self.in_seq > self.last_acked
+
+    def ack_paid(self) -> None:
+        """The peer has been told in_seq: by a CTRL_ACK, or by the
+        HELLO of a new wire, which states it."""
+        self.last_acked = self.in_seq
+        self.owed_bytes = 0
+        timer, self.ack_timer = self.ack_timer, None
+        if timer is not None:
+            timer.cancel()
+
+    def take_ack(self) -> bytes:
+        """The CTRL_ACK frame for everything delivered so far; the
+        caller writes it (through wire_prepare, in write order)."""
+        self.ack_paid()
+        return encode_ack(self.in_seq)
+
     def reset_epoch(self) -> None:
         """Abandon this session's delivery state and start a fresh epoch
         in place: new nonce (receiver will not dedup against the old seq
@@ -239,7 +316,7 @@ class Session:
         self.peer_cookie = None
         self.out_seq = 0
         self.in_seq = 0
-        self.last_acked = 0
+        self.ack_paid()
         self.unacked.clear()
         self.broken = False
         self.drop_wire()
@@ -422,10 +499,15 @@ class Connection:
         try:
             sess = self.session
             parts = raw if isinstance(raw, tuple) else (raw,)
+            # the ack this session owes rides this write, ahead of the
+            # frame: no segment and no wake-up of its own at the peer
+            ack = sess.take_ack() if sess.owes_ack() else None
             if sess.comp is not None or \
                     (sess.secure and sess.conn_key):
-                # compression/encryption wrap the whole frame: join
-                # first
+                # compression/encryption wrap a whole frame: join
+                # first.  Each frame is wrapped in the order it is
+                # written (the AES-GCM nonce is a strict counter)
+                bufs = [] if ack is None else [sess.wire_prepare(ack)]
                 joined = b"".join(parts)
                 wired = sess.wire_prepare(joined)
                 if m.ledger.enabled:
@@ -434,12 +516,13 @@ class Connection:
                         compressed=sess.comp is not None and
                         len(joined) >= sess.comp_min,
                         encrypted=bool(sess.secure and sess.conn_key))
-                writer.write(wired)
+                bufs.append(wired)
             else:
-                # writev-style: payload buffers go to the transport
-                # as-is, never copied into one frame buffer
-                for p in parts:
-                    writer.write(p)
+                bufs = parts if ack is None else (ack, *parts)
+            _write_once(writer, bufs)
+            if m.ledger.enabled:
+                m.ledger.note_wire(1, frames=1,
+                                   rode=0 if ack is None else 1)
         finally:
             if row is not None:
                 row.__exit__(None, None, None)
@@ -522,12 +605,13 @@ class Connection:
             # over at 0, so our dedup window must too, or we would
             # silently drop its first in_seq frames as replays.
             sess.in_seq = 0
-            sess.last_acked = 0
             sess.peer_cookie = cookie
         sess.reader, sess.writer = reader, writer
+        sess.ack_paid()           # our HELLO stated in_seq
         frames = sess.replay_frames(int(meta.get("in_seq", 0)))
         if frames and m.ledger.enabled:
             m.stats.note_replay(self._peer_label(), len(frames))
+            m.ledger.note_wire(len(frames), frames=len(frames))
         for raw in frames:
             writer.write(sess.wire_prepare(raw))
         await writer.drain()
@@ -553,17 +637,38 @@ class Connection:
                 self.last_error = str(e)
         self._closed = True
 
-    async def _send_ack(self) -> None:
+    def _owe_ack(self, nbytes: int) -> None:
+        """A frame of `nbytes` payload was delivered on a lossless
+        session.  Its ack waits for the next frame _send writes to
+        this peer (_write_raw) and is a frame of its own only at the
+        ACK_EVERY_* limits or after ACK_DELAY_S."""
+        sess = self.session
+        sess.owed_bytes += nbytes
+        if sess.in_seq - sess.last_acked >= ACK_EVERY_FRAMES or \
+                sess.owed_bytes >= ACK_EVERY_BYTES:
+            self._send_ack()
+        elif sess.ack_timer is None:
+            sess.ack_timer = self.messenger._loop.call_later(
+                ACK_DELAY_S, self._ack_due)
+
+    def _ack_due(self) -> None:
+        # not cancelled, so not paid since it was armed
+        self.session.ack_timer = None
+        self._send_ack()
+
+    def _send_ack(self) -> None:
+        """A CTRL_ACK frame of its own (reactor thread only)."""
         sess = self.session
         writer = sess.writer
         if writer is None:
-            return
+            return  # the next HELLO states in_seq
         try:
-            sess.last_acked = sess.in_seq
-            writer.write(sess.wire_prepare(
-                encode_frame(CTRL_ACK, sess.in_seq, {})))
+            writer.write(sess.wire_prepare(sess.take_ack()))
         except (ConnectionError, OSError):
             pass  # peer will learn our in_seq from the next HELLO
+        led = self.messenger.ledger
+        if led.enabled:
+            led.note_wire(1, acks=1)
 
     async def _close(self) -> None:
         self._closed = True
@@ -899,6 +1004,7 @@ class Messenger:
             if auth_reply is not None:
                 reply_meta["auth_reply"] = auth_reply
             writer.write(encode_frame(CTRL_HELLO, 0, reply_meta))
+            sess.ack_paid()       # the HELLO stated in_seq
             # The client's in_seq only counts frames of THIS session
             # epoch if it has seen our cookie; a stale epoch's in_seq
             # must trim nothing or undelivered replies would be lost.
@@ -907,6 +1013,7 @@ class Messenger:
             frames = sess.replay_frames(peer_in)
             if frames and self.ledger.enabled:
                 self.stats.note_replay(conn._peer_label(), len(frames))
+                self.ledger.note_wire(len(frames), frames=len(frames))
             for raw in frames:
                 writer.write(sess.wire_prepare(raw))
             await writer.drain()
@@ -990,7 +1097,7 @@ class Messenger:
                 if conn.lossless and seq <= sess.in_seq:
                     # replayed frame we already delivered: re-ack, drop
                     # (reference ProtocolV2 in_seq dedup on session resume)
-                    await conn._send_ack()
+                    conn._send_ack()
                     continue
                 row = spans.annotation("msgr.decode") \
                     if spans.tracing_now else None
@@ -1009,6 +1116,8 @@ class Messenger:
                         Message.HEADER_SIZE + len(meta_raw) +
                         len(data) + 4)
                 sess.in_seq = seq
+                if sess.lossless:
+                    conn._owe_ack(len(data))
                 if self.recv_filter is not None and \
                         self.recv_filter(msg):
                     # injected receive-side loss (partition testing):
@@ -1060,13 +1169,6 @@ class Messenger:
                             await asyncio.get_event_loop() \
                                 .run_in_executor(None, self.dispatcher,
                                                  conn, msg)
-                # Batch acks: piggyback-style — ack when the pipe goes
-                # idle or every 64 frames, not per message (reference
-                # ProtocolV2 acks lazily from the write path too).
-                buffered = getattr(reader, "_buffer", None)
-                if (buffered is not None and len(buffered) == 0) or \
-                        sess.in_seq - sess.last_acked >= 64:
-                    await conn._send_ack()
         except (asyncio.IncompleteReadError, ConnectionError, OSError):
             # Wire died under us.  Mark the wire down (starts the prune
             # clock for accepted sessions); client conns re-dial so

@@ -85,6 +85,18 @@ def _build_ledger_perf(name: str = "msgr_ledger"):
             .add_u64_counter("msgr_reactor_lag_events",
                              "reactor lag probes that fired a FULL "
                              "extra interval late (the tick-lag rule)")
+            .add_u64_counter("msgr_frames_out",
+                             "data frames handed to a transport "
+                             "(every messenger of the process; "
+                             "replayed frames count again)")
+            .add_u64_counter("msgr_socket_writes",
+                             "calls that handed bytes to a transport: "
+                             "data frames, stand-alone acks, replays")
+            .add_u64_counter("msgr_acks_out",
+                             "CTRL_ACK frames written on their own")
+            .add_u64_counter("msgr_acks_piggybacked",
+                             "CTRL_ACK frames that rode a data "
+                             "frame's write")
             .add_gauge("msgr_dispatch_queued",
                        "dispatch-executor submissions currently "
                        "queued or running")
@@ -418,6 +430,23 @@ class MsgrLedger:
         n = self._dispatch_pending - 1
         self._dispatch_pending = n if n > 0 else 0
         self.perf.set("msgr_dispatch_queued", self._dispatch_pending)
+
+    # -- socket writes (called behind the enabled gate) ----------------------
+
+    def note_wire(self, writes: int, frames: int = 0, acks: int = 0,
+                  rode: int = 0) -> None:
+        """`writes` calls handed bytes to a transport; between them
+        they carried `frames` data frames, `acks` acks of their own
+        and `rode` acks ahead of a data frame (the counts behind
+        wire_writes_per_frame / wire_acks_per_frame)."""
+        inc = self.perf.inc
+        inc("msgr_socket_writes", writes)
+        if frames:
+            inc("msgr_frames_out", frames)
+        if acks:
+            inc("msgr_acks_out", acks)
+        if rode:
+            inc("msgr_acks_piggybacked", rode)
 
     # -- reactor lag probe ---------------------------------------------------
 

@@ -3,12 +3,15 @@
 import struct
 import threading
 import time
+from collections import Counter
 
 import numpy as np
 import pytest
 
 from ceph_tpu.msg import Message, Messenger
 from ceph_tpu.msg import messages as M
+from ceph_tpu.msg import messenger as messenger_mod
+from ceph_tpu.msg.message import CTRL_ACK, encode_ack, encode_frame
 from ceph_tpu.osd.types import eversion_t, ghobject_t, hobject_t, pg_t, spg_t
 from ceph_tpu.store.object_store import Transaction
 
@@ -31,6 +34,11 @@ def test_envelope_corruption_detected():
     raw[10] ^= 0xFF
     with pytest.raises(ValueError):
         Message.parse_header(bytes(raw[:Message.HEADER_SIZE]))
+
+
+@pytest.mark.parametrize("seq", [0, 1, 64, 2 ** 40])
+def test_ack_frame_is_the_control_frame_it_always_was(seq):
+    assert encode_ack(seq) == encode_frame(CTRL_ACK, seq, {})
 
 
 def test_payload_crc_detected():
@@ -140,26 +148,47 @@ def test_exactly_once_under_socket_failures():
     client.shutdown()
 
 
-def test_mid_burst_wire_drop_no_duplicates():
+@pytest.mark.parametrize("echo", [False, True],
+                         ids=["no_ack_owed", "acks_owed"])
+def test_mid_burst_wire_drop_no_duplicates(echo):
     """Abort the TCP stream in the middle of a burst; the unacked window
-    replays and receiver-side dedup keeps delivery exactly-once."""
-    got = []
+    replays and receiver-side dedup keeps delivery exactly-once — also
+    when the wire dies with acks owed in both directions (the server
+    echoes, so each side holds frames whose ack was still waiting for
+    a frame to ride)."""
+    got, replies = [], []
+
+    def serve(conn, msg):
+        got.append(msg.from_osd)
+        if echo:
+            conn.send_message(M.MOSDPing(msg.from_osd, is_reply=True))
+
     server = Messenger("server")
-    server.add_dispatcher(lambda conn, msg: got.append(msg.from_osd))
+    server.add_dispatcher(serve)
     addr = server.bind(("127.0.0.1", 0))
     client = Messenger("client")
+    client.add_dispatcher(lambda conn, msg: replies.append(msg.from_osd))
     conn = client.connect(addr)
     for i in range(40):
         conn.send_message(M.MOSDPing(from_osd=i))
         if i == 20:
+            if echo:
+                assert _wait(lambda: replies)
             # hard-abort the live wire from the reactor thread
             client._run_sync(_abort_wire(conn))
-    deadline = time.time() + 15
-    while len(got) < 40 and time.time() < deadline:
-        time.sleep(0.02)
+    want = 40 if echo else 0
+    assert _wait(lambda: len(got) >= 40 and len(replies) >= want, 15)
     assert got == list(range(40))
+    assert sorted(replies) == list(range(want))
     server.shutdown()
     client.shutdown()
+
+
+def _wait(pred, timeout=10.0):
+    deadline = time.time() + timeout
+    while not pred() and time.time() < deadline:
+        time.sleep(0.01)
+    return bool(pred())
 
 
 async def _abort_wire(conn):
@@ -294,5 +323,174 @@ def test_large_payload():
     while not got and time.time() < deadline:
         time.sleep(0.02)
     assert got and got[0].data == payload
+    server.shutdown()
+    client.shutdown()
+
+
+# -- acks ride the next frame to the peer (messenger.py module doc) ----------
+
+def _wire_counts(m):
+    d = m.ledger.perf.dump()
+    return Counter({k: d[k] for k in (
+        "msgr_frames_out", "msgr_socket_writes", "msgr_acks_out",
+        "msgr_acks_piggybacked")})
+
+
+def _echo_pair(lossless=True, **kw):
+    """A server that answers every ping and a client that collects the
+    answers -> (server, client, conn, got, replies)."""
+    got, replies = [], []
+    server = Messenger("osd.0", **kw.get("server", {}))
+    server.add_dispatcher(lambda conn, msg: (
+        got.append(msg.from_osd),
+        conn.send_message(M.MOSDPing(msg.from_osd, is_reply=True))))
+    addr = server.bind(("127.0.0.1", 0))
+    client = Messenger("osd.1", **kw.get("client", {}))
+    client.add_dispatcher(lambda conn, msg: replies.append(msg.from_osd))
+    return (server, client, client.connect(addr, lossless=lossless),
+            got, replies)
+
+
+def test_burst_and_replies_are_acked_by_the_frames_that_follow():
+    """N frames one way and N replies back: the acks ride, so at most
+    1 + N/64 are frames of their own, and both replay windows are
+    empty once the last debt has waited out ACK_DELAY_S."""
+    n = 256
+    server, client, conn, got, replies = _echo_pair()
+    before = _wire_counts(client)
+    for i in range(n):
+        conn.send_message(M.MOSDPing(from_osd=i))
+    assert _wait(lambda: len(replies) >= n)
+    srv_sess = next(iter(server._sessions.values()))
+    assert _wait(lambda: not conn.session.unacked
+                 and not srv_sess.unacked,
+                 messenger_mod.ACK_DELAY_S + 2.0)
+    assert not conn.session.owes_ack() and not srv_sess.owes_ack()
+    assert conn.session.ack_timer is None and srv_sess.ack_timer is None
+    d = _wire_counts(client) - before
+    assert d["msgr_frames_out"] == 2 * n
+    assert d["msgr_acks_out"] <= 1 + n // 64, d
+    assert d["msgr_acks_piggybacked"] >= n // 2, d
+    assert d["msgr_socket_writes"] == \
+        d["msgr_frames_out"] + d["msgr_acks_out"]
+    server.shutdown()
+    client.shutdown()
+
+
+def test_one_way_stream_of_large_frames_is_acked_by_bytes(monkeypatch):
+    """4 MiB frames one way with nothing coming back: the receiver
+    pays every ACK_EVERY_BYTES, long before the timer (moved out of
+    reach here) or 64 frames would."""
+    monkeypatch.setattr(messenger_mod, "ACK_DELAY_S", 3600.0)
+    got = []
+    server = Messenger("server")
+    server.add_dispatcher(lambda conn, msg: got.append(len(msg.data)))
+    addr = server.bind(("127.0.0.1", 0))
+    client = Messenger("client")
+    conn = client.connect(addr)
+    before = _wire_counts(client)
+    payload = bytes(4 << 20)
+    n = 4
+    for i in range(n):
+        conn.send_message(M.MOSDOp(
+            spg_t(pg_t(1, 1), 0), hobject_t(1, f"big{i}"),
+            [["write", 0, len(payload)]], payload))
+    assert _wait(lambda: len(got) >= n, 30)
+    assert _wait(lambda: not conn.session.unacked)
+    d = _wire_counts(client) - before
+    assert d["msgr_acks_out"] == \
+        n * len(payload) // messenger_mod.ACK_EVERY_BYTES
+    # a 4 MiB frame left in one call, its payload never joined
+    assert d["msgr_socket_writes"] == n + d["msgr_acks_out"]
+    server.shutdown()
+    client.shutdown()
+
+
+def test_lossy_session_never_acks():
+    """Nothing is retained for a lossy session, so nothing is acked:
+    no frame of its own, none riding, no timer."""
+    server, client, conn, got, replies = _echo_pair(lossless=False)
+    before = _wire_counts(client)
+    for i in range(100):
+        conn.send_message(M.MOSDPing(from_osd=i))
+    assert _wait(lambda: len(replies) >= 100)
+    time.sleep(0.1)
+    d = _wire_counts(client) - before
+    assert d["msgr_frames_out"] == 200
+    assert d["msgr_acks_out"] == 0 and d["msgr_acks_piggybacked"] == 0
+    assert d["msgr_socket_writes"] == 200
+    sessions = [conn.session] + [c.session for c in server._accepted]
+    assert len(sessions) == 2
+    for sess in sessions:
+        assert not sess.lossless and sess.ack_timer is None
+        assert sess.last_acked == 0 and not sess.unacked
+    server.shutdown()
+    client.shutdown()
+
+
+def test_secure_session_decrypts_an_ack_riding_a_data_frame():
+    """The ack and the frame behind it are each wrapped in the order
+    they are written: the receiver's strict nonce counter accepts
+    both, so nothing is rejected and no wire is reset."""
+    pytest.importorskip("cryptography")
+    from ceph_tpu.auth import CephxAuth
+    sk = b"\x21" * 16
+    server, client, conn, got, replies = _echo_pair(
+        server={"auth": CephxAuth("osd.0", service_key=sk),
+                "secure": True},
+        client={"auth": CephxAuth("osd.1", service_key=sk),
+                "secure": True})
+    before = _wire_counts(client)
+    for i in range(50):     # ping-pong: every frame carries an ack
+        conn.send_message(M.MOSDPing(from_osd=i))
+        assert _wait(lambda: len(replies) > i)
+    assert got == list(range(50)) and replies == list(range(50))
+    assert conn.session.secure and conn.session._enc_ctr >= 50
+    d = _wire_counts(client) - before
+    assert d["msgr_acks_piggybacked"] >= 90, d
+    assert conn.last_error is None
+    assert client.stats.totals()["reconnects"] == 0
+    server.shutdown()
+    client.shutdown()
+
+
+def test_one_socket_write_per_data_frame_by_exact_count():
+    """The system calls themselves, counted by a profile hook on the
+    reactor threads: a data frame on an idle session costs one `send`
+    (or `sendmsg`), where three parts and an ack frame cost four."""
+    got = []
+    server = Messenger("server")
+    server.add_dispatcher(lambda conn, msg: got.append(msg.oid.name))
+    server.fast_dispatch = lambda msg: True
+    addr = server.bind(("127.0.0.1", 0))
+    client = Messenger("client")
+    conn = client.connect(addr)
+    payload = bytes(4096)
+
+    def op(i):              # three parts: head + meta, data, crc
+        return M.MOSDOp(spg_t(pg_t(1, 2), 0), hobject_t(1, str(i)),
+                        [["write", 0, len(payload)]], payload)
+
+    conn.send_message(op(-1))                       # HELLO exchange
+    assert _wait(lambda: got)
+    calls = Counter()
+
+    def hook(frame, event, arg):
+        if event == "c_call" and arg.__name__ in ("send", "sendmsg") \
+                and threading.current_thread().name.startswith(
+                    "msgr-reactor"):
+            calls[arg.__name__] += 1
+
+    n = 200
+    threading.setprofile_all_threads(hook)
+    try:
+        for i in range(n):
+            conn.send_message(op(i))
+            time.sleep(0.001)           # an idle socket for each frame
+        assert _wait(lambda: len(got) > n)
+    finally:
+        threading.setprofile_all_threads(None)
+    assert got[1:] == [str(i) for i in range(n)]
+    assert sum(calls.values()) <= 1.1 * n, calls
     server.shutdown()
     client.shutdown()
