@@ -58,9 +58,17 @@ class TraceContext:
         self.origin_ts = origin_ts
 
     @classmethod
-    def new(cls) -> "TraceContext":
-        return cls(uuid.uuid4().hex[:16], uuid.uuid4().hex[:8],
-                   None, time.time())
+    def new(cls, parent: "TraceContext | None" = None) -> "TraceContext":
+        """A context whose origin is NOW: the root of a new trace, or
+        with `parent` (the S3 request a RADOS op is made for) a span
+        of the parent's trace under the parent's span — unlike
+        `child()` it does not inherit the parent's origin_ts, which
+        downstream timelines read as this op's own submit time."""
+        if parent is None:
+            return cls(uuid.uuid4().hex[:16], uuid.uuid4().hex[:8],
+                       None, time.time())
+        return cls(parent.trace_id, uuid.uuid4().hex[:8],
+                   parent.span_id, time.time())
 
     def child(self) -> "TraceContext":
         """A child span of this one (same trace, fresh span id)."""

@@ -227,7 +227,9 @@ class Objecter:
                   data: bytes = b"", timeout: float = 30.0,
                   attempts: int = 3, snap: int = 0,
                   snapc: list | None = None,
-                  qos_class: str | None = None) -> M.MOSDOpReply:
+                  qos_class: str | None = None,
+                  parent_trace: TraceContext | None = None
+                  ) -> M.MOSDOpReply:
         # an expired ticket would make every OSD reconnect fail
         # permanently; refresh before it lapses (reference
         # CephxTicketManager renewal)
@@ -239,8 +241,10 @@ class Objecter:
                 pass
         oid = hobject_t(pool=pool_id, name=name, snap=snap)
         # root trace span: origin_ts stamps "objecter submit" on every
-        # downstream timeline of this request
-        trace = TraceContext.new()
+        # downstream timeline of this request.  A caller that serves a
+        # request of its own (the S3 gateway) hands its context in, so
+        # the op joins that request's trace_id
+        trace = TraceContext.new(parent_trace)
         top = self.op_tracker.create(
             "osd_op", f"{pool_id}/{name} {[op[0] for op in ops]}",
             trace)

@@ -14,6 +14,7 @@ from __future__ import annotations
 import errno
 from concurrent.futures import ThreadPoolExecutor, Future
 
+from ..common.options import Config
 from ..osdc import Objecter
 
 
@@ -25,7 +26,14 @@ class RadosError(Exception):
 
 class RadosClient:
     def __init__(self, mon_addr, name: str = "client", auth=None,
-                 secure: bool = False, compress: str | None = None):
+                 secure: bool = False, compress: str | None = None,
+                 conf: dict | None = None):
+        # the client's own configuration (the [client] section of a
+        # ceph.conf): services built on this client — the S3 gateway's
+        # rgw_* options — read it
+        self.conf = Config()
+        for key, value in (conf or {}).items():
+            self.conf.set(key, value)
         self.objecter = Objecter(mon_addr, name, auth=auth,
                                  secure=secure, compress=compress)
         self._pool = ThreadPoolExecutor(max_workers=16,
@@ -128,10 +136,11 @@ class IoCtx:
             raise RadosError(-r, out.get("error", "snap rm"))
 
     def _submit(self, name: str, ops: list, data: bytes = b"",
-                snap: int = 0) -> bytes:
+                snap: int = 0, parent_trace=None) -> bytes:
         reply = self.client.objecter.op_submit(
             self.pool_id, name, ops, data, snap=snap,
-            snapc=self.snapc, qos_class=self.qos_class)
+            snapc=self.snapc, qos_class=self.qos_class,
+            parent_trace=parent_trace)
         if reply.result != 0:
             raise RadosError(-reply.result, f"op on {name}")
         return reply.data

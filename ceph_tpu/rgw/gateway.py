@@ -29,10 +29,13 @@ constructed without credentials.
 from __future__ import annotations
 
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from xml.sax.saxutils import escape
 
+from ..common import spans
+from ..common.perf_counters import PerfCountersCollection
 from . import sigv4
 from .store import RGWError, RGWStore
 
@@ -43,9 +46,19 @@ def _xml_error(code: str, msg: str) -> bytes:
             f"<Message>{escape(msg)}</Message></Error>").encode()
 
 
+class _Server(ThreadingHTTPServer):
+    # the listen backlog: socketserver's 5 makes the 6th of a burst of
+    # connecting clients wait out a SYN retransmit (1 s) — a COSBench
+    # stage opens all its workers' connections at once
+    request_queue_size = 128
+
+
 class _Handler(BaseHTTPRequestHandler):
     protocol_version = "HTTP/1.1"
     server_version = "ceph-tpu-rgw/1.0"
+    # a reply leaves as headers, then body: with Nagle on, the body of
+    # every GET would wait for the client's delayed ACK of the headers
+    disable_nagle_algorithm = True
 
     # quiet request logging (the daemon's dout owns the log surface)
     def log_message(self, fmt, *args):  # noqa: A003
@@ -67,7 +80,11 @@ class _Handler(BaseHTTPRequestHandler):
                content_length: str | None = None) -> None:
         """content_length overrides the header for HEAD replies that
         advertise the RESOURCE's size rather than the (empty) body's."""
+        # counted BEFORE the reply leaves: a client that has its answer
+        # finds the request in the counters
+        self._account(status)
         self.send_response(status)
+        self.send_header("x-amz-request-id", self._req.trace.trace_id)
         self.send_header("Content-Type", content_type)
         self.send_header("Content-Length",
                          content_length if content_length is not None
@@ -81,8 +98,53 @@ class _Handler(BaseHTTPRequestHandler):
     def _fail(self, e: RGWError) -> None:
         self._reply(e.status, _xml_error(e.code, str(e)))
 
+    def parse_request(self) -> bool:
+        # the request line has just been read: the request's clock
+        # starts here (a keep-alive connection's wait for its next
+        # request lies before it)
+        self._t_request = time.perf_counter()
+        return super().parse_request()
+
     def _route(self) -> None:
+        """One request: a tally and a trace id for whatever RADOS ops
+        it makes (store.py RequestTally), the span `rgw.put` around a
+        plain object PUT, and the `rgw` counters at its end."""
+        st = self.gw.store
+        req = self._req = st.begin_request(self._t_request)
+        self._put_bytes = None
+        self._counted = False
         parsed = urllib.parse.urlsplit(self.path)
+        self._plain_put = self._is_plain_put(parsed)
+        sp = spans.begin("rgw.put", trace_id=req.trace.trace_id) \
+            if self._plain_put else None
+        try:
+            self._serve(parsed)
+        finally:
+            st.end_request()
+            spans.end(sp)
+            self._account(0)        # a handler that died without a reply
+
+    def _account(self, status: int) -> None:
+        if not self._counted:       # once a request
+            self._counted = True
+            self.gw.account(self._req, status, self._put_bytes
+                            if self._plain_put else None)
+
+    def _is_plain_put(self, parsed) -> bool:
+        """PUT /bucket/key with a body of its own: no sub-resource,
+        no part of an upload, no server-side copy, not Swift."""
+        if self.command != "PUT" or \
+                self.headers.get("x-amz-copy-source"):
+            return False
+        bucket, _, key = urllib.parse.unquote(
+            parsed.path).lstrip("/").partition("/")
+        if not key or bucket in ("auth", "swift"):
+            return False
+        return not ({"acl", "partNumber"} & {
+            k for k, _ in urllib.parse.parse_qsl(
+                parsed.query, keep_blank_values=True)})
+
+    def _serve(self, parsed) -> None:
         path = urllib.parse.unquote(parsed.path)
         if path == "/auth" or path.startswith("/auth/") or \
                 path == "/swift" or path.startswith("/swift/"):
@@ -95,7 +157,9 @@ class _Handler(BaseHTTPRequestHandler):
             # not SigV4.
             self._swift_route(parsed, path)
             return
+        auth_span = spans.begin("rgw.auth")
         body = self._read_body()
+        self._put_bytes = len(body)
         # identity: the verified access key, or None for anonymous
         # requests (no Authorization header).  Anonymous requests pass
         # routing and face the ACL checks — a BAD signature still
@@ -116,6 +180,7 @@ class _Handler(BaseHTTPRequestHandler):
                         body, auth["secret"], auth["amzdate"],
                         auth["datestamp"], auth["seed_sig"])
             except sigv4.SigError as e:
+                spans.end(auth_span)
                 self._reply(403, _xml_error("SignatureDoesNotMatch",
                                             str(e)))
                 return
@@ -132,8 +197,12 @@ class _Handler(BaseHTTPRequestHandler):
                     dict(self.headers), self.gw.creds)
                 self._identity = auth["access_key"]
             except sigv4.SigError as e:
+                spans.end(auth_span)
                 self._reply(403, _xml_error("AccessDenied", str(e)))
                 return
+        spans.end(auth_span)
+        # the frontend's share so far: request line to verified body
+        self._req.lat["frontend"] = time.perf_counter() - self._req.t0
         parts = path.lstrip("/").split("/", 1)
         bucket = parts[0]
         key = parts[1] if len(parts) > 1 else None
@@ -799,7 +868,15 @@ class S3Gateway:
         self.creds = creds          # access_key -> secret; None = open
         from .swift import SwiftFrontend
         self.swift = SwiftFrontend(self.store, creds)
-        self.httpd = ThreadingHTTPServer(addr, _Handler)
+        # `perf dump`: the store's `rgw` set beside the `objecter` set
+        # of the client the gateway speaks RADOS through
+        self.perf = PerfCountersCollection()
+        self.perf.add(self.store.perf)
+        self.perf.add(client.objecter.perf)
+        if self.asok is not None:
+            self.asok.register_command(
+                "perf dump", lambda cmd: self.perf_dump())
+        self.httpd = _Server(addr, _Handler)
         self.httpd.gateway = self
         self.addr = self.httpd.server_address[:2]
         self._thread = threading.Thread(
@@ -856,6 +933,31 @@ class S3Gateway:
             return self.store.bucket_stats(cmd["bucket"])
         except (RGWError, KeyError) as e:
             return {"error": str(e)}
+
+    def perf_dump(self) -> dict:
+        """{"rgw": {...}, "objecter": {...}}: what `perf dump` on the
+        gateway's admin socket returns."""
+        return self.perf.dump()
+
+    def account(self, req, status: int, put_bytes: int | None) -> None:
+        """A request's reply is ready to leave: count it, and for a
+        plain object PUT answered 200 (`put_bytes` set) split its
+        time."""
+        perf = self.store.perf
+        perf.inc("rgw_req")
+        if status >= 400 or status == 0:
+            perf.inc("rgw_failed")
+        if put_bytes is None or status != 200:
+            return
+        perf.inc("rgw_put")
+        perf.inc("rgw_put_bytes", put_bytes)
+        perf.inc("rgw_put_rados_ops", req.ops)
+        perf.hinc("rgw_put_lat", time.perf_counter() - req.t0)
+        for key, kind in (("rgw_put_frontend_lat", "frontend"),
+                          ("rgw_put_data_lat", "data_write"),
+                          ("rgw_put_index_lat", "index"),
+                          ("rgw_put_account_lat", "account")):
+            perf.hinc(key, req.lat.get(kind, 0.0))
 
     def shutdown(self) -> None:
         self._lc_stop.set()

@@ -38,16 +38,11 @@ import json
 import threading
 import time
 
-from ..common.options import SCHEMA
 from ..common.util import next_pow2
 from ..rados.client import RadosError
 from .bucket_index import _Layout, shard_of
 
 BUCKETS_OBJ = "buckets"
-
-
-def _opt(name: str):
-    return SCHEMA[name].default
 
 
 class Resharder:
@@ -169,11 +164,11 @@ class Resharder:
         new = _Layout(bucket, rs["shards"], gen)
         # grace: writers that fetched bucket meta just before the
         # marker landed must drain before the copy snapshots old shards
-        dwell = _opt("rgw_reshard_grace_s") - (
+        dwell = self.store.conf.get("rgw_reshard_grace_s") - (
             time.time() - rs.get("started", 0.0))
         if dwell > 0:
             time.sleep(dwell)
-        batch = _opt("rgw_reshard_batch")
+        batch = self.store.conf.get("rgw_reshard_batch")
         copied = 0
         for plane in ("index", "versions"):
             start_at = int(rs["progress"].get(plane, 0))
@@ -219,8 +214,8 @@ class Resharder:
             return {"skipped": "sweep already running"}
         try:
             stats = {"resumed": 0, "started": 0, "errors": 0}
-            max_objs = _opt("rgw_max_objs_per_shard")
-            cap = _opt("rgw_reshard_max_shards")
+            max_objs = self.store.conf.get("rgw_max_objs_per_shard")
+            cap = self.store.conf.get("rgw_reshard_max_shards")
             for bucket, bmeta in self.store.list_buckets():
                 try:
                     if (bmeta.get("reshard") or {}).get("state") \

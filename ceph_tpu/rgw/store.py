@@ -34,8 +34,12 @@ import hashlib
 import json
 import time
 
-from ..common.options import SCHEMA
-from ..rados.client import RadosError
+import threading
+
+from ..common import spans
+from ..common.perf_counters import PerfCountersBuilder
+from ..common.tracked_op import TraceContext
+from ..rados.client import IoCtx, RadosError
 from .bucket_index import BucketIndex
 from .reshard import Resharder
 
@@ -68,14 +72,104 @@ def _version_oid(bucket: str, version_id: str, key: str) -> str:
     return f"vr_{len(bucket)}_{bucket}_{version_id}_{key}"
 
 
+def _build_perf():
+    """The gateway's perf set `rgw` (docs/TRACING.md "The S3
+    gateway").  The `rgw_put_*` histograms take ONE sample per plain
+    object PUT answered 200, so their sums split `rgw_put_lat`."""
+    b = (PerfCountersBuilder("rgw")
+         .add_u64_counter("rgw_req", "S3/Swift requests answered")
+         .add_u64_counter("rgw_failed", "requests answered with a "
+                          "status of 400 or above")
+         .add_u64_counter("rgw_put", "plain object PUTs answered 200")
+         .add_u64_counter("rgw_put_bytes", "body bytes of those PUTs")
+         .add_u64_counter("rgw_put_rados_ops", "RADOS ops issued on "
+                          "behalf of those PUTs, authorization "
+                          "included"))
+    for key, desc in (
+            ("rgw_put_lat", "request line read -> reply ready to "
+             "leave (its write to the socket is not in)"),
+            ("rgw_put_frontend_lat", "body read, signature check "
+             "(sha256 of the payload) and the ETag's md5: the part "
+             "that is no RADOS call"),
+            ("rgw_put_data_lat", "writes to the data pool"),
+            ("rgw_put_index_lat", "every cls rgw call of the PUT: "
+             "bucket registry and index shard, summed"),
+            ("rgw_put_account_lat", "every cls user call of the PUT: "
+             "quota reservation, stats, release, summed")):
+        b.add_histogram(key, desc)
+    return b.create_perf_counters()
+
+
+class RequestTally:
+    """What one S3 request has cost so far, kept for the handler
+    thread that serves it (`RGWStore.begin_request`): the store's
+    pool handles add every RADOS op they submit on that thread, by
+    kind, and hand the request's trace context to the objecter, so an
+    OSD's `dump_historic_ops` joins to the request by trace_id."""
+
+    __slots__ = ("trace", "t0", "ops", "lat")
+
+    def __init__(self, t0: float):
+        self.trace = TraceContext.new()
+        self.t0 = t0
+        self.ops = 0
+        self.lat: dict[str, float] = {}
+
+
+def _op_kind(ops: list) -> str:
+    """Which share of a request a RADOS op belongs to: the object
+    class it calls (rgw = bucket registry and index, user = quota and
+    stats), else what it does to the object."""
+    op = ops[0]
+    if op[0] == "call":
+        cls = op[1].split(".", 1)[0]
+        return {"rgw": "index", "user": "account"}.get(cls, "other")
+    if op[0] in ("writefull", "write", "append"):
+        return "data_write"
+    return "data_read" if op[0] == "read" else "other"
+
+
+class _StoreIoCtx(IoCtx):
+    """The store's handle on one of its pools.  Every op it submits
+    is counted by pool (`rgw_rados_ops.<pool>`), runs inside a span
+    `rgw.<kind>` and, when the calling thread serves an S3 request,
+    is added to that request's tally and carries its trace."""
+
+    def __init__(self, io: IoCtx, store: "RGWStore"):
+        super().__init__(io.client, io.pool_id, io.pool_name)
+        self._store = store
+        self._ops_key = f"rgw_rados_ops.{io.pool_name}"
+
+    def _submit(self, name: str, ops: list, data: bytes = b"",
+                snap: int = 0, parent_trace=None) -> bytes:
+        kind = _op_kind(ops)
+        req = self._store.current_request()
+        self._store.perf.dinc(self._ops_key)
+        sp = spans.begin(f"rgw.{kind}")
+        try:
+            return super()._submit(
+                name, ops, data, snap,
+                parent_trace=req.trace if req else parent_trace)
+        finally:
+            sp.end()
+            if req is not None:
+                req.ops += 1
+                req.lat[kind] = req.lat.get(kind, 0.0) + sp.wall_s
+
+
 class RGWStore:
     def __init__(self, client, ec_profile: str | None = None,
                  pg_num: int = 8, modlog: bool = False,
                  usage_log: bool = False):
         self.client = client
+        # rgw_* options come from the client's configuration (the
+        # [client.rgw] section of a deployment's ceph.conf)
+        self.conf = client.conf
+        self.perf = _build_perf()
+        self._requests = threading.local()
         self._ensure_pools(ec_profile, pg_num)
-        self.meta = client.open_ioctx(META_POOL)
-        self.data = client.open_ioctx(DATA_POOL)
+        self.meta = _StoreIoCtx(client.open_ioctx(META_POOL), self)
+        self.data = _StoreIoCtx(client.open_ioctx(DATA_POOL), self)
         self._cls(self.meta, BUCKETS_OBJ, "dir_init")
         # zone mod-log: one journal object recording WHAT changed
         # (reference rgw_datalog/bilog, the feed of rgw_data_sync.cc);
@@ -96,8 +190,7 @@ class RGWStore:
         # acl/lifecycle share one row); concurrent HTTP handler threads
         # must not interleave their RMWs or the second write silently
         # drops the first's field
-        import threading as _threading
-        self._bmeta_lock = _threading.Lock()
+        self._bmeta_lock = threading.Lock()
         # every index/versions plane access routes through the shard
         # layer (shard selection, dual-write during reshard, merged
         # listing); quota admission is a cls_user reservation — no
@@ -115,7 +208,19 @@ class RGWStore:
         # gateway's own acked writes.
         from collections import OrderedDict as _OD
         self._cursor_cache: dict = _OD()
-        self._cursor_mu = _threading.Lock()
+        self._cursor_mu = threading.Lock()
+
+    # -- the request a handler thread serves ---------------------------------
+
+    def begin_request(self, t0: float) -> RequestTally:
+        req = self._requests.open = RequestTally(t0)
+        return req
+
+    def end_request(self) -> None:
+        self._requests.open = None
+
+    def current_request(self) -> RequestTally | None:
+        return getattr(self._requests, "open", None)
 
     def _ensure_pools(self, ec_profile, pg_num) -> None:
         for name, kind in ((META_POOL, "replicated"),
@@ -252,8 +357,8 @@ class RGWStore:
                 self._user_oid(user), "user", "reserve",
                 json.dumps({
                     "objects": add_objects, "bytes": add_bytes,
-                    "ttl": SCHEMA["rgw_quota_reservation_ttl_s"
-                                  ].default}).encode())
+                    "ttl": self.conf.get(
+                        "rgw_quota_reservation_ttl_s")}).encode())
         except RadosError as e:
             if e.errno == errno.EDQUOT:
                 raise RGWError(403, "QuotaExceeded",
@@ -319,7 +424,7 @@ class RGWStore:
         if not bucket or "/" in bucket:
             raise RGWError(400, "InvalidBucketName", bucket)
         if shards is None:
-            shards = SCHEMA["rgw_bucket_index_shards"].default
+            shards = self.conf.get("rgw_bucket_index_shards")
         shards = int(shards)
         if shards < 1:
             raise RGWError(400, "InvalidArgument",
@@ -669,7 +774,7 @@ class RGWStore:
             if same else len(body)
         token = self._quota_gate(owner, q_obj, q_bytes)
         try:
-            etag = hashlib.md5(body).hexdigest()
+            etag = self._etag(body)
             self._modlog("sync", bucket, key)
             if bmeta.get("versioning") == "Enabled":
                 self._archive_null_version(bucket, key)
@@ -714,6 +819,17 @@ class RGWStore:
             # accounting has landed (or the op died): the reservation
             # hands back to the shared totals
             self._quota_release(owner, token)
+
+    def _etag(self, body: bytes) -> str:
+        """md5 of a PUT's body; its time is the frontend's, not a
+        RADOS call's, in the request's tally."""
+        t0 = time.perf_counter()
+        etag = hashlib.md5(body).hexdigest()
+        req = self.current_request()
+        if req is not None:
+            req.lat["frontend"] = req.lat.get("frontend", 0.0) \
+                + time.perf_counter() - t0
+        return etag
 
     def get_object_version(self, bucket: str, key: str,
                            version_id: str) -> tuple[bytes, dict]:
@@ -1274,7 +1390,7 @@ class RGWStore:
         bucket limit check`): objects per shard vs
         rgw_max_objs_per_shard, with OK / WARN (>50% of the reshard
         threshold) / OVER status."""
-        max_objs = SCHEMA["rgw_max_objs_per_shard"].default
+        max_objs = self.conf.get("rgw_max_objs_per_shard")
         out = []
         for bucket, bmeta in self.list_buckets():
             lay = self.index.read_layout(bucket, bmeta)

@@ -171,7 +171,7 @@ class Cluster:
 
     def client(self) -> RadosClient:
         c = RadosClient(self.mon_addrs, auth=self._client_auth(),
-                        secure=self.secure).connect()
+                        secure=self.secure, conf=self.conf).connect()
         self._clients.append(c)
         return c
 

@@ -17,7 +17,7 @@ import chip_smoke  # noqa: E402
 TINY = chip_smoke.Size(osds=12, pg_num=8, objects=10,
                        object_bytes=128 << 10, writers=4,
                        degraded_reads=6, degraded_writes=3,
-                       degraded_overwrites=5,
+                       degraded_overwrites=5, s3_object_bytes=16 << 10,
                        stripe_bytes=64 << 10, clean_timeout_s=120)
 
 
@@ -35,8 +35,8 @@ def test_refuses_to_run_without_a_chip():
 
 def test_phases_green_at_tiny_size_on_the_cpu_twin():
     """Every phase — cross-check, prewarm, boot, write, read back,
-    degraded reads + writes + overwrites, recovery, deep scrub — at a
-    tiny size,
+    degraded reads + writes + overwrites, recovery, deep scrub, S3
+    ingest — at a tiny size,
     served by the XLA twin and saying so; the same counters the chip
     run gates on are zero here too."""
     rep = chip_smoke.run(TINY, seed=7, require_platform="cpu")
@@ -60,6 +60,12 @@ def test_phases_green_at_tiny_size_on_the_cpu_twin():
         assert c[key] == 0
     assert rep["scrub"]["errors"] == 0
     assert rep["scrub"]["objects"] == rep["ops_acked"]
+    # the S3 step: an 11-shard bucket from the configuration, 8 signed
+    # PUTs on EC k4m2 read back and listed, ten RADOS ops a PUT
+    s3 = rep["phases"]["s3"]
+    assert (s3["ops"], s3["bytes"], s3["index_shards"],
+            s3["rados_ops_per_put"]) == (
+        8, 8 * TINY.s3_object_bytes, 11, 10.0)
     assert {p["window"] for p in rep["phases"].values()} == \
         {"setup", "serving"}
     assert rep["fused_point"]["source"] == "default (cpu)"

@@ -12,7 +12,6 @@ import threading
 
 import pytest
 
-from ceph_tpu.common.options import SCHEMA
 from ceph_tpu.rgw.bucket_index import shard_of
 from ceph_tpu.rgw.store import RGWError, RGWStore
 from ceph_tpu.tools.vstart import Cluster
@@ -273,7 +272,8 @@ def test_reshard_interrupted_resumes(st):
 def test_reshard_autoscale_trigger(st, monkeypatch):
     """Entry count past shards*rgw_max_objs_per_shard triggers the
     sweep's pow2 scale-up, capped by rgw_reshard_max_shards."""
-    monkeypatch.setattr(SCHEMA["rgw_max_objs_per_shard"], "default", 10)
+    monkeypatch.setitem(st.conf._layers["override"],
+                        "rgw_max_objs_per_shard", 10)
     st.create_bucket("auto", shards=1)
     for i in range(35):
         st.put_object("auto", f"a{i:03d}", b"z")
