@@ -952,6 +952,7 @@ class S3Gateway:
         perf.inc("rgw_put")
         perf.inc("rgw_put_bytes", put_bytes)
         perf.inc("rgw_put_rados_ops", req.ops)
+        perf.inc("rgw_put_account_writes", req.account_writes)
         perf.hinc("rgw_put_lat", time.perf_counter() - req.t0)
         for key, kind in (("rgw_put_frontend_lat", "frontend"),
                           ("rgw_put_data_lat", "data_write"),

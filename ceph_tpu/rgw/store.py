@@ -84,7 +84,16 @@ def _build_perf():
          .add_u64_counter("rgw_put_bytes", "body bytes of those PUTs")
          .add_u64_counter("rgw_put_rados_ops", "RADOS ops issued on "
                           "behalf of those PUTs, authorization "
-                          "included"))
+                          "included")
+         .add_u64_counter("rgw_put_account_writes", "cls user calls "
+                          "made for those PUTs that rewrote an account "
+                          "object: a reserve that came back with a "
+                          "token, every add_stats, every release sent")
+         .add_u64_counter("rgw_quota_gates", "quota gates passed "
+                          "(cls user reserve calls), any verdict")
+         .add_u64_counter("rgw_quota_reservations", "of those, gates "
+                          "that came back with a reservation token (a "
+                          "limit is set on the owner)"))
     for key, desc in (
             ("rgw_put_lat", "request line read -> reply ready to "
              "leave (its write to the socket is not in)"),
@@ -95,7 +104,7 @@ def _build_perf():
             ("rgw_put_index_lat", "every cls rgw call of the PUT: "
              "bucket registry and index shard, summed"),
             ("rgw_put_account_lat", "every cls user call of the PUT: "
-             "quota reservation, stats, release, summed")):
+             "quota gate, stats, release, summed")):
         b.add_histogram(key, desc)
     return b.create_perf_counters()
 
@@ -107,12 +116,13 @@ class RequestTally:
     kind, and hand the request's trace context to the objecter, so an
     OSD's `dump_historic_ops` joins to the request by trace_id."""
 
-    __slots__ = ("trace", "t0", "ops", "lat")
+    __slots__ = ("trace", "t0", "ops", "account_writes", "lat")
 
     def __init__(self, t0: float):
         self.trace = TraceContext.new()
         self.t0 = t0
         self.ops = 0
+        self.account_writes = 0
         self.lat: dict[str, float] = {}
 
 
@@ -287,37 +297,57 @@ class RGWStore:
     def _user_oid(user: str) -> str:
         return f"user.{user}"
 
+    def _account_write(self) -> None:
+        """A cls user call of the open request rewrote an account
+        object (`rgw_put_account_writes`)."""
+        req = self.current_request()
+        if req is not None:
+            req.account_writes += 1
+
     def _user_stats(self, user: str | None, bucket: str,
-                    d_objects: int, d_bytes: int) -> None:
+                    d_objects: int, d_bytes: int,
+                    token: str | None = None) -> str | None:
         """Server-side stats delta on the owner's account object.
         Accounting tracks the CURRENT index view (archived version
         rows and version surgery are not separately charged — noted
-        deviation from the reference's full-olh accounting)."""
+        deviation from the reference's full-olh accounting).  `token`
+        is the user's quota reservation for this growth: the stats
+        call takes it back in the same rewrite.  -> the token if no
+        call carried it (a zero delta), else None."""
         if not user or (d_objects == 0 and d_bytes == 0):
-            return
+            return token
+        delta = {"bucket": bucket, "objects": d_objects,
+                 "bytes": d_bytes}
+        if token:
+            delta["token"] = token
         self.meta.execute(self._user_oid(user), "user", "add_stats",
-                          json.dumps({"bucket": bucket,
-                                      "objects": d_objects,
-                                      "bytes": d_bytes}).encode())
+                          json.dumps(delta).encode())
+        self._account_write()
+        return None
 
     def _account_overwrite(self, bucket: str, key: str | None,
                            cur: dict | None, cur_owner: str | None,
-                           new_owner: str | None,
-                           new_bytes: int) -> None:
+                           new_owner: str | None, new_bytes: int,
+                           token: str | None = None) -> str | None:
         """Post-success accounting for a write that displaced `cur`:
         release the OLD owner's charge and charge the NEW owner — a
         cross-owner overwrite must not leave the previous owner paying
         for bytes that no longer exist (and the clamp in cls_user must
-        never eat the new owner's charge)."""
+        never eat the new owner's charge).  `token` is the NEW owner's
+        reservation from `_quota_gate`; -> what is left of it for
+        `_quota_release` (None once a stats call has retired it)."""
         if cur is not None and cur_owner == new_owner:
-            self._user_stats(new_owner, bucket, 0,
-                             new_bytes - cur.get("size", 0))
+            token = self._user_stats(new_owner, bucket, 0,
+                                     new_bytes - cur.get("size", 0),
+                                     token)
         else:
             if cur is not None:
                 self._user_stats(cur_owner, bucket, -1,
                                  -cur.get("size", 0))
-            self._user_stats(new_owner, bucket, 1, new_bytes)
+            token = self._user_stats(new_owner, bucket, 1, new_bytes,
+                                     token)
         self._usage(new_owner, "put_obj", bucket, key, new_bytes)
+        return token
 
     def get_user_header(self, user: str) -> dict:
         raw = self.meta.execute(self._user_oid(user), "user",
@@ -332,26 +362,27 @@ class RGWStore:
 
     def _quota_gate(self, user: str | None, add_objects: int,
                     add_bytes: int) -> str | None:
-        """Admit-or-403 a write against the owner's quota AND reserve
-        its growth (reference RGWQuotaHandler::check_quota before
-        every put).  Check and reservation are ONE atomic cls_user
-        call on the user object — the OSD serializes class calls per
-        object, so racing writers from ANY process or host see each
-        other's live reservations and cannot jointly overshoot
-        max_bytes/max_objects (this closes the process-local pending
-        pot's documented cross-process window).  Returns a reservation
-        token; every successful gate must be paired with a
-        `_quota_release(user, token)` once the op's accounting has
-        landed (or the op failed).  A writer that dies in between
-        stops counting against the quota after
-        rgw_quota_reservation_ttl_s.
-
-        Residual boundary effect: between `_user_stats` landing and
-        the release, growth is briefly counted twice (reservation +
-        totals), which can only falsely DENY at the boundary, never
-        falsely admit."""
+        """Admit-or-403 a write against the owner's quota AND, where
+        the owner has a limit, reserve its growth (reference
+        RGWQuotaHandler::check_quota before every put).  Check and
+        reservation are ONE atomic cls_user call on the user object —
+        the OSD serializes class calls per object, so racing writers
+        from ANY process or host see each other's live reservations
+        and cannot jointly overshoot max_bytes/max_objects (this
+        closes the process-local pending pot's documented
+        cross-process window).  The call is made for every write and
+        decides on the committed record: nothing about a user's quota
+        is remembered here.  Returns a reservation token — "" for an
+        owner with no limit, whose account object the call did not
+        rewrite.  The op hands the token to its stats call
+        (`_account_overwrite`), which takes the reservation back in
+        the rewrite that applies the delta; `_quota_release(user,
+        what is left)` covers an op that failed or whose stats were a
+        zero delta.  A writer that dies in between stops counting
+        against the quota after rgw_quota_reservation_ttl_s."""
         if not user:
             return None
+        self.perf.inc("rgw_quota_gates")
         try:
             raw = self.meta.execute(
                 self._user_oid(user), "user", "reserve",
@@ -364,15 +395,21 @@ class RGWStore:
                 raise RGWError(403, "QuotaExceeded",
                                f"user {user}: {e}") from e
             raise
-        return json.loads(raw.decode())["token"]
+        token = json.loads(raw.decode())["token"]
+        if token:
+            self.perf.inc("rgw_quota_reservations")
+            self._account_write()
+        return token
 
     def _quota_release(self, user: str | None,
                        token: str | None) -> None:
-        """Return a gate's reservation (accounting landed or op died)."""
+        """Return a gate's reservation that no stats call took back
+        (the op died, or its delta was zero)."""
         if not user or not token:
             return
         self.meta.execute(self._user_oid(user), "user", "release",
                           json.dumps({"token": token}).encode())
+        self._account_write()
 
     def _usage(self, user: str | None, op: str, bucket: str,
                key: str | None, nbytes: int) -> None:
@@ -788,8 +825,8 @@ class RGWStore:
                 self.index.add(bucket, "index", key,
                                {**meta, "version_id": vid},
                                bmeta=bmeta)
-                self._account_overwrite(bucket, key, cur, cur_owner,
-                                        owner, len(body))
+                token = self._account_overwrite(
+                    bucket, key, cur, cur_owner, owner, len(body), token)
                 self._publish(bucket, key, "s3:ObjectCreated:Put",
                               len(body), bmeta=bmeta)
                 self._modlog("sync", bucket, key)   # post-success
@@ -809,15 +846,15 @@ class RGWStore:
                                       "null", bmeta=bmeta)
             for m in reap:
                 self._reap_manifest(bucket, m)
-            self._account_overwrite(bucket, key, cur, cur_owner, owner,
-                                    len(body))
+            token = self._account_overwrite(
+                bucket, key, cur, cur_owner, owner, len(body), token)
             self._publish(bucket, key, "s3:ObjectCreated:Put",
                           len(body), bmeta=bmeta)
             self._modlog("sync", bucket, key)       # post-success
             return etag
         finally:
-            # accounting has landed (or the op died): the reservation
-            # hands back to the shared totals
+            # what the stats did not take back: the op died before
+            # them, or its delta was zero
             self._quota_release(owner, token)
 
     def _etag(self, body: bytes) -> str:
@@ -1235,8 +1272,8 @@ class RGWStore:
                     except RadosError:
                         pass
             self._rm_upload_bookkeeping(bucket, key, upload_id)
-            self._account_overwrite(bucket, key, cur, cur_owner, owner,
-                                    total)
+            token = self._account_overwrite(
+                bucket, key, cur, cur_owner, owner, total, token)
             self._publish(bucket, key,
                           "s3:ObjectCreated:CompleteMultipartUpload",
                           total, bmeta=bmeta)

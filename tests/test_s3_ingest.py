@@ -125,15 +125,16 @@ def test_index_shards_equal_on_their_replicas(sound):
 def test_reader_on_the_runs_own_dumps(sound):
     got = READER.read(sound)
     assert set(got) == set(READER.METRICS)
-    # authorization 1, bucket row 2, index look-ups 2, reserve, write,
-    # index add, stats, release
-    assert got["rgw_rados_ops_per_put"] == 10.0
+    # authorization 1, bucket row 2, index look-ups 2, quota gate
+    # (the user has no limit: it reserves nothing and no release
+    # follows), write, index add, stats
+    assert got["rgw_rados_ops_per_put"] == 9.0
     split = sum(got[k] for k in (
         "rgw_frontend_ms_mean", "rgw_data_write_ms_mean",
         "rgw_index_ms_per_put", "rgw_account_ms_per_put"))
     assert 0.8 * got["rgw_put_ms_mean"] < split <= got["rgw_put_ms_mean"]
-    # nine of a PUT's ten ops go to the replicated pool
-    assert 0.85 < got["rgw_index_ops_share"] < 0.95
+    # eight of a PUT's nine ops go to the replicated pool
+    assert 0.84 < got["rgw_index_ops_share"] < 0.94
     assert got["client_outside_rgw_ms_mean"] > 0
     # a cell without a gateway, a program without the counters
     assert READER.read({"run": {"ops": []}}) == {}
@@ -241,9 +242,14 @@ def test_rgw_counters_move_by_the_exact_counts(live):
     assert delta("rgw_req") == n and delta("rgw_failed") == 0
     assert delta("rgw_put") == n
     assert delta("rgw_put_bytes") == n * SIZE
-    assert delta("rgw_put_rados_ops") == 10 * n
-    assert delta(f"rgw_rados_ops.{meta}") == 9 * n
+    assert delta("rgw_put_rados_ops") == 9 * n
+    assert delta(f"rgw_rados_ops.{meta}") == 8 * n
     assert delta(f"rgw_rados_ops.{data}") == n
+    # the user has no limit: every PUT passes the gate, none reserves,
+    # and the account object is rewritten once a PUT, by the stats
+    assert delta("rgw_quota_gates") == n
+    assert delta("rgw_quota_reservations") == 0
+    assert delta("rgw_put_account_writes") == n
     for key in ("rgw_put_lat", "rgw_put_frontend_lat", "rgw_put_data_lat",
                 "rgw_put_index_lat", "rgw_put_account_lat"):
         assert after[key]["count"] - before[key]["count"] == n, key
@@ -295,10 +301,38 @@ def test_counters_are_exact_under_concurrent_puts(live):
     assert not errors and not any(t.is_alive() for t in threads)
     after = _rgw(live)
     assert after["rgw_put"] - before["rgw_put"] == 32
-    assert after["rgw_put_rados_ops"] - before["rgw_put_rados_ops"] == 320
+    assert after["rgw_put_rados_ops"] - before["rgw_put_rados_ops"] == 288
+    assert after["rgw_put_account_writes"] - \
+        before["rgw_put_account_writes"] == 32
+    assert after["rgw_quota_gates"] - before["rgw_quota_gates"] == 32
+    assert after["rgw_quota_reservations"] == \
+        before["rgw_quota_reservations"]
     assert after["rgw_put_bytes"] - before["rgw_put_bytes"] == 32 * SIZE
     rows = GEN.list_bucket(live["conn"], "counted", 7)
     assert len(rows) == len({k for k, _, _ in rows}) == 5 + 32
+
+
+def test_a_limited_users_put_is_nine_ops_and_two_account_writes(live):
+    """The other branch of the rule: with a limit set the gate
+    reserves, and the stats call takes the reservation back."""
+    conn, store = live["conn"], live["gw"].store
+    user = live["spec"]["access_key"]
+    store.set_user_quota(user, max_objects=1_000_000)
+    try:
+        before = _rgw(live)
+        n = 3
+        for i in range(n):
+            assert conn.request("PUT", f"/counted/lim{i}",
+                                body=bytes([i]) * SIZE)[0] == 200
+        after = _rgw(live)
+        assert "pending" not in store.get_user_header(user)
+    finally:
+        store.set_user_quota(user)
+    for key, want in (("rgw_put", n), ("rgw_put_rados_ops", 9 * n),
+                      ("rgw_put_account_writes", 2 * n),
+                      ("rgw_quota_gates", n),
+                      ("rgw_quota_reservations", n)):
+        assert after[key] - before[key] == want, key
 
 
 def test_span_tree_of_one_put(live, monkeypatch):
@@ -327,7 +361,7 @@ def test_span_tree_of_one_put(live, monkeypatch):
     assert children.count("rgw.auth") == 1
     assert children.count("rgw.data_write") == 1
     assert children.count("rgw.index") == 6
-    assert children.count("rgw.account") == 3
+    assert children.count("rgw.account") == 2
     assert all(parent == "rgw.put" for name, parent in seen
                if name != "rgw.put")
 
