@@ -256,7 +256,7 @@ def account_threads(label: str, name_prefix: str) -> None:
     _thread_groups[label] = name_prefix
 
 
-def _thread_cpu_s(native_id: int) -> float:
+def thread_cpu_s(native_id: int) -> float:
     """utime + stime of one thread of this process, from /proc (10 ms
     ticks; 0.0 where there is no /proc)."""
     try:
@@ -272,7 +272,7 @@ def thread_groups() -> dict[str, float]:
     a gauge: a thread that exits takes its seconds with it."""
     live = [(t.name, t.native_id) for t in threading.enumerate()
             if t.native_id]
-    return {label: sum(_thread_cpu_s(i) for name, i in live
+    return {label: sum(thread_cpu_s(i) for name, i in live
                        if name.startswith(prefix))
             for label, prefix in list(_thread_groups.items())}
 

@@ -66,6 +66,19 @@ def encode_ack(seq: int) -> bytes:
             + _ACK_META + _ACK_PCRC)
 
 
+def type_name(tid: int) -> str:
+    """The class name of message type `tid`, as `type(msg).__name__`
+    gives it for a decoded message; `type_<tid>` for one this process
+    cannot decode."""
+    cls = _REGISTRY.get(tid)
+    return cls.__name__ if cls is not None else f"type_{tid}"
+
+
+def frame_type_name(raw: bytes) -> str:
+    """`type_name` of an encoded frame, read from its header."""
+    return type_name(int.from_bytes(raw[4:6], "little"))
+
+
 def register_message(cls: type["Message"]) -> type["Message"]:
     tid = cls.type_id
     assert tid not in _REGISTRY, f"duplicate message type {tid}"

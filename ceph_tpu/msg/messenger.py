@@ -67,9 +67,11 @@ from concurrent.futures import TimeoutError as _FuturesTimeout
 from typing import Callable
 
 from .message import (CTRL_ACK, CTRL_COMP, CTRL_ENC, CTRL_HELLO, Message,
-                      encode_ack, encode_frame)
+                      encode_ack, encode_frame, frame_type_name,
+                      type_name)
 from ..common import spans
-from .msgr_ledger import MsgrLedger, msgr_ledger
+from .msgr_ledger import (FrameTx, MsgrLedger, ReactorSelector,
+                          frame_sampled, msgr_ledger, sample_offset)
 
 Dispatcher = Callable[["Connection", Message], None]
 
@@ -151,16 +153,41 @@ def _parse_raw(raw: bytes) -> tuple[int, int, bytes, bytes, int]:
     return tid, seq, meta_raw, data, pcrc
 
 
-async def read_frame(reader: asyncio.StreamReader
+class _RxStamps:
+    """A read loop's clock readings of the frame in its hands (the
+    wire ledger's "a frame's trip"): `t_head` when the header read
+    returned — 0 for a frame the sampling rule leaves out — and
+    `t_body` when the body read did."""
+
+    __slots__ = ("sess", "now_ns", "t_head", "t_body")
+
+    def __init__(self, sess: "Session", now_ns):
+        self.sess = sess
+        self.now_ns = now_ns
+        self.t_head = self.t_body = 0
+
+
+async def read_frame(reader: asyncio.StreamReader,
+                     rx: _RxStamps | None = None
                      ) -> tuple[int, int, bytes, bytes, int]:
     """Read one wire frame -> (tid, seq, meta_raw, data, pcrc); raises
     ValueError on corruption (bad magic / header crc).  Two reads per
     frame (header, then body in one readexactly + slice) — each await
     is a potential reactor suspension, and the EC fan-out pays it per
-    shard reply."""
+    shard reply.  `rx` (the read loop's, while the wire ledger is on)
+    takes the clock after each read for a frame the sampling rule
+    picks: one test a frame, and the header's parse (≈1 us) falls to
+    what came before it.  A wrapped frame (ENC, COMP) shows its own
+    seq only once unwrapped, so it is stamped here and tested there."""
     head = await reader.readexactly(Message.HEADER_SIZE)
     tid, seq, meta_len, data_len = Message.parse_header(head)
+    if rx is not None:
+        timed = frame_sampled(seq, rx.sess.sample_off) \
+            if tid < CTRL_HELLO else tid in (CTRL_ENC, CTRL_COMP)
+        rx.t_head = rx.now_ns() if timed else 0
     body = await reader.readexactly(meta_len + data_len + 4)
+    if rx is not None and rx.t_head:
+        rx.t_body = rx.now_ns()
     meta_raw = body[:meta_len]
     data = body[meta_len:meta_len + data_len]
     (pcrc,) = struct.unpack("<I", body[-4:])
@@ -179,6 +206,9 @@ class Session:
         # discard its old seq window instead of dedup-dropping the fresh
         # one (reference ProtocolV2 client_cookie semantics).
         self.nonce = nonce or uuid.uuid4().hex[:12]
+        # where this session starts in the frame-sampling rule (both
+        # ends derive it from the nonce; msgr_ledger.frame_sampled)
+        self.sample_off = sample_offset(self.nonce)
         # Epoch cookies (reference ProtocolV2 client_cookie/server_cookie):
         # local_cookie identifies THIS session object; peer_cookie is the
         # last cookie seen from the peer.  A seq number is only meaningful
@@ -312,6 +342,7 @@ class Session:
         cached Connection keep working (at-least-once across the reset;
         the overflow already lost the old window)."""
         self.nonce = uuid.uuid4().hex[:12]
+        self.sample_off = sample_offset(self.nonce)
         self.local_cookie = uuid.uuid4().hex[:12]
         self.peer_cookie = None
         self.out_seq = 0
@@ -397,12 +428,22 @@ class Connection:
     # -- sending (thread-safe entry) ---------------------------------------
 
     def send_message(self, msg: Message) -> None:
-        self.messenger._run_soon(self._send(msg))
+        m = self.messenger
+        # the start of the frame's `hop` (wire ledger, "a frame's
+        # trip"): read on the caller's thread, before the frame has
+        # the seq that decides whether it is sampled
+        led = m.ledger
+        m._run_soon(self._send(msg, led.now_ns() if led.enabled else 0))
 
-    async def _send(self, msg: Message) -> None:
+    async def _send(self, msg: Message, t_call: int = 0) -> None:
         sess = self.session
         m = self.messenger
+        # `t_call` nonzero: the ledger is on.  Two more readings every
+        # frame pays, because seq is assigned under the lock
+        now_ns = m.ledger.now_ns
+        t_in = now_ns() if t_call else 0
         async with sess.send_lock:
+            t_lock = now_ns() if t_call else 0
             if sess.broken:
                 if not self.can_reconnect:
                     # accepted side cannot dial; the peer's next
@@ -410,12 +451,20 @@ class Connection:
                     return
                 sess.reset_epoch()
             sess.out_seq += 1
+            tx = None
+            if t_call and frame_sampled(sess.out_seq, sess.sample_off):
+                tx = FrameTx(
+                    type(msg).__name__,
+                    (sess.nonce, self.can_reconnect, sess.out_seq),
+                    t_call, t_in, t_lock)
             # trace-only (common/spans.py: the reactors' CPU is
             # accounted by thread) and never across an await, so
             # msgr.send is two rows a frame — encode here, socket
-            # write in _write_raw
+            # write in _write_raw; type and seq join them to the
+            # receiver's msgr.decode / msgr.dispatch rows
             row = spans.annotation(
-                "msgr.send", type=type(msg).__name__) \
+                "msgr.send", type=type(msg).__name__,
+                seq=sess.out_seq) \
                 if spans.tracing_now else None
             try:
                 raw = msg.encode_parts(sess.out_seq)
@@ -423,6 +472,8 @@ class Connection:
             finally:
                 if row is not None:
                     row.__exit__(None, None, None)
+            if tx is not None:
+                tx.t_enc = now_ns()
             if sess.broken:       # overflow tripped by this very frame
                 if not self.can_reconnect:
                     return
@@ -430,6 +481,7 @@ class Connection:
                 sess.out_seq = 1            # fresh epoch
                 raw = msg.encode_parts(1)
                 sess.record_out(1, raw)
+                tx = None                   # (its trip is not timed)
             if m.inject_dispatch_stall > 0:
                 # fault injection (conf ms_inject_dispatch_stall): the
                 # assembled frame sits in the send queue while the
@@ -440,12 +492,20 @@ class Connection:
                 if sess.writer is None:
                     if not self.can_reconnect:
                         return  # replayed when the peer reconnects
+                    # a wire that was up and dropped (down_since) is
+                    # re-dialled HERE when a frame finds it gone before
+                    # the read loop does: the same reconnect round
+                    # _reconnect counts, and counted once — if this
+                    # dial fails, by _reconnect below
+                    redial = sess.down_since is not None
                     await self._connect()
+                    if redial and m.ledger.enabled:
+                        m.stats.note_reconnect(self._peer_label())
                     if self.lossless:
                         # _connect's replay already carried raw
                         self._note_sent(msg, raw)
                         return
-                await self._write_raw(raw)
+                await self._write_raw(raw, type(msg).__name__, tx)
                 self._note_sent(msg, raw)
             except (ConnectionError, OSError, asyncio.TimeoutError,
                     asyncio.IncompleteReadError, ValueError) as e:
@@ -476,10 +536,13 @@ class Connection:
             # say "5.1 s in the send queue to osd.7"
             top.mark_event(f"msgr_send({peer})")
 
-    async def _write_raw(self, raw: bytes) -> None:
+    async def _write_raw(self, raw: bytes, mtype: str,
+                         tx: FrameTx | None = None) -> None:
         """Single choke point for outgoing bytes: fault injection hooks
         live here (reference ms_inject_socket_failures / ms_inject_delay
-        applied in AsyncConnection::write)."""
+        applied in AsyncConnection::write).  `mtype`: the message's
+        type, for the trace row and the by-type frame count; `tx`: the
+        stamps of a sampled frame, whose `write` phase ends here."""
         m = self.messenger
         if m.inject_delay_prob > 0 and \
                 m._inject_rng.random() < m.inject_delay_prob:
@@ -494,10 +557,14 @@ class Connection:
             # wire dropped while we slept in the injected delay (the
             # accepted-conn read loop nulls it without the send lock)
             raise ConnectionResetError("wire dropped during delayed write")
-        row = spans.annotation("msgr.send") \
+        led = m.ledger
+        sess = self.session
+        row = spans.annotation("msgr.send", type=mtype,
+                               seq=sess.out_seq) \
             if spans.tracing_now else None
         try:
-            sess = self.session
+            if tx is not None:
+                led.frame_depart(tx)
             parts = raw if isinstance(raw, tuple) else (raw,)
             # the ack this session owes rides this write, ahead of the
             # frame: no segment and no wake-up of its own at the peer
@@ -510,7 +577,7 @@ class Connection:
                 bufs = [] if ack is None else [sess.wire_prepare(ack)]
                 joined = b"".join(parts)
                 wired = sess.wire_prepare(joined)
-                if m.ledger.enabled:
+                if led.enabled:
                     m.stats.note_wrapped(
                         self._peer_label(), len(wired),
                         compressed=sess.comp is not None and
@@ -520,13 +587,16 @@ class Connection:
             else:
                 bufs = parts if ack is None else (ack, *parts)
             _write_once(writer, bufs)
-            if m.ledger.enabled:
-                m.ledger.note_wire(1, frames=1,
-                                   rode=0 if ack is None else 1)
+            t_w = led.frame_sent(tx) if tx is not None else 0
+            if led.enabled:
+                led.note_wire(1, (mtype,),
+                              rode=0 if ack is None else 1)
         finally:
             if row is not None:
                 row.__exit__(None, None, None)
         await writer.drain()
+        if t_w:
+            led.frame_drained(tx, t_w)
 
     async def _connect(self) -> None:
         """Open the TCP stream and run the HELLO exchange: send our
@@ -556,6 +626,8 @@ class Connection:
             authorizer = m.auth.build_authorizer(secure=m.secure)
             hello_meta["auth"] = authorizer
         writer.write(encode_frame(CTRL_HELLO, 0, hello_meta))
+        if m.ledger.enabled:
+            m.ledger.note_hello()
         await writer.drain()
         tid, _seq, meta_raw, _data, _pcrc = await asyncio.wait_for(
             read_frame(reader), timeout=5.0)
@@ -607,11 +679,13 @@ class Connection:
             sess.in_seq = 0
             sess.peer_cookie = cookie
         sess.reader, sess.writer = reader, writer
+        sess.down_since = None
         sess.ack_paid()           # our HELLO stated in_seq
         frames = sess.replay_frames(int(meta.get("in_seq", 0)))
         if frames and m.ledger.enabled:
             m.stats.note_replay(self._peer_label(), len(frames))
-            m.ledger.note_wire(len(frames), frames=len(frames))
+            m.ledger.note_wire(len(frames),
+                               [frame_type_name(f) for f in frames])
         for raw in frames:
             writer.write(sess.wire_prepare(raw))
         await writer.drain()
@@ -765,6 +839,7 @@ class Messenger:
         self.stats = self.ledger.register_messenger(self.entity)
         # pin this messenger to one loop of the pool for its lifetime
         self._loop = self._pick_loop()
+        self.stats.reactor = self._loops.index(self._loop)
 
     # -- reactor pool -------------------------------------------------------
 
@@ -782,12 +857,19 @@ class Messenger:
                 from concurrent.futures import ThreadPoolExecutor
                 cls._executor = ThreadPoolExecutor(
                     max_workers=96, thread_name_prefix="msgr-dispatch")
+                meters = []
                 for i in range(cls.REACTORS):
-                    loop = asyncio.new_event_loop()
+                    # a selector that times itself: the loop's wall
+                    # time as asleep + running (wire ledger, "reactor
+                    # loops")
+                    meter = ReactorSelector()
+                    meters.append(meter)
+                    loop = asyncio.SelectorEventLoop(meter)
                     loop.set_default_executor(cls._executor)
 
-                    def run(loop=loop):
+                    def run(loop=loop, meter=meter):
                         asyncio.set_event_loop(loop)
+                        meter.loop_started()
                         loop.run_forever()
 
                     t = threading.Thread(target=run,
@@ -798,7 +880,7 @@ class Messenger:
                     cls._loop_threads.append(t)
                 # arm the per-reactor loop-lag probe on the fresh pool
                 # (wire-plane flight recorder, msg/msgr_ledger.py)
-                msgr_ledger().attach_reactors(cls._loops)
+                msgr_ledger().attach_reactors(cls._loops, meters=meters)
                 # the reactor threads' whole CPU (wire work and the
                 # handlers fast-dispatched inline on them) is the
                 # `host_spans` set's `msgr.reactor_cpu`
@@ -861,10 +943,13 @@ class Messenger:
         unreachable peer must not head-of-line-block the other
         shards' sends behind its reconnect timeouts)."""
 
+        # one `hop` start for the batch (send_message has the why)
+        t_call = self.ledger.now_ns() if self.ledger.enabled else 0
+
         async def _send_group(conn, msgs):
             for m in msgs:
                 try:
-                    await conn._send(m)
+                    await conn._send(m, t_call)
                 except Exception:  # noqa: BLE001 - per-conn isolation
                     import traceback
                     traceback.print_exc()
@@ -945,6 +1030,8 @@ class Messenger:
                 try:
                     writer.write(encode_frame(CTRL_HELLO, 0, {
                         "entity": self.entity, "auth_error": str(e)}))
+                    if self.ledger.enabled:
+                        self.ledger.note_hello()
                     await writer.drain()
                 except (ConnectionError, OSError):
                     pass
@@ -1004,6 +1091,8 @@ class Messenger:
             if auth_reply is not None:
                 reply_meta["auth_reply"] = auth_reply
             writer.write(encode_frame(CTRL_HELLO, 0, reply_meta))
+            if self.ledger.enabled:
+                self.ledger.note_hello()
             sess.ack_paid()       # the HELLO stated in_seq
             # The client's in_seq only counts frames of THIS session
             # epoch if it has seen our cookie; a stale epoch's in_seq
@@ -1013,7 +1102,8 @@ class Messenger:
             frames = sess.replay_frames(peer_in)
             if frames and self.ledger.enabled:
                 self.stats.note_replay(conn._peer_label(), len(frames))
-                self.ledger.note_wire(len(frames), frames=len(frames))
+                self.ledger.note_wire(
+                    len(frames), [frame_type_name(f) for f in frames])
             for raw in frames:
                 writer.write(sess.wire_prepare(raw))
             await writer.drain()
@@ -1068,14 +1158,26 @@ class Messenger:
     async def _read_loop(self, conn: Connection,
                          reader: asyncio.StreamReader) -> None:
         sess = conn.session
+        led = self.ledger
+        rx = _RxStamps(sess, led.now_ns)
         try:
             while not conn._closed and reader is sess.reader:
-                tid, seq, meta_raw, data, pcrc = await read_frame(reader)
+                # t_head: nonzero while this frame's trip is timed
+                # (wire ledger, "a frame's trip")
+                if led.enabled:
+                    tid, seq, meta_raw, data, pcrc = \
+                        await read_frame(reader, rx)
+                    t_head = rx.t_head
+                else:
+                    tid, seq, meta_raw, data, pcrc = \
+                        await read_frame(reader)
+                    t_head = 0
                 if reader is not sess.reader:
                     # epoch reset while we were blocked in read_frame: a
                     # buffered old-epoch frame must not touch the fresh
                     # epoch's seq window (in_seq poisoning)
                     break
+                wrapped = tid in (CTRL_ENC, CTRL_COMP)
                 if tid == CTRL_ENC:
                     if sess.conn_key is None:
                         raise ValueError("encrypted frame on plain session")
@@ -1089,6 +1191,10 @@ class Messenger:
                     algo = json.loads(meta_raw.decode()).get("a", "")
                     inner = sess.wire_decompress(algo, data)
                     tid, seq, meta_raw, data, pcrc = _parse_raw(inner)
+                if t_head and wrapped and not (
+                        tid < CTRL_HELLO and
+                        frame_sampled(seq, sess.sample_off)):
+                    t_head = 0    # unwrapped: the rule leaves it out
                 if tid == CTRL_ACK:
                     sess.trim_acked(seq)
                     continue
@@ -1099,13 +1205,22 @@ class Messenger:
                     # (reference ProtocolV2 in_seq dedup on session resume)
                     conn._send_ack()
                     continue
-                row = spans.annotation("msgr.decode") \
+                if t_head:
+                    slot, t_arr = led.frame_claim(
+                        (sess.nonce, not conn.can_reconnect, seq),
+                        t_head)
+                row = spans.annotation("msgr.decode",
+                                       type=type_name(tid), seq=seq) \
                     if spans.tracing_now else None
                 try:
                     msg = Message.decode(tid, seq, meta_raw, data, pcrc)
                 finally:
                     if row is not None:
                         row.__exit__(None, None, None)
+                # the arguments of led.frame_delivered, for whichever
+                # thread runs the handler's first line
+                frame = (slot, type(msg).__name__, t_arr, rx.t_body,
+                         led.now_ns()) if t_head else None
                 # ingest stamp for op tracking (reference
                 # Message::recv_stamp set by the messenger): dispatch
                 # latency is attributable even when the executor queues
@@ -1134,8 +1249,11 @@ class Messenger:
                         # (a trace row only: lat_msgr_dispatch and
                         # the span table keep to executor-run handlers)
                         row = spans.annotation(
-                            "msgr.dispatch." + type(msg).__name__) \
+                            "msgr.dispatch." + type(msg).__name__,
+                            seq=seq) \
                             if spans.tracing_now else None
+                        if frame is not None:
+                            led.frame_delivered(*frame)
                         try:
                             self.dispatcher(conn, msg)
                         except Exception:  # noqa: BLE001
@@ -1149,15 +1267,14 @@ class Messenger:
                         # synchronously / block on nested RPCs; the
                         # ledger times queue wait + handler run so
                         # "dispatcher slow" is attributable
-                        led = self.ledger
                         if led.enabled:
                             t_sub = led.dispatch_submit()
 
                             def _timed(d=self.dispatcher, c=conn,
-                                       mm=msg, t=t_sub):
+                                       mm=msg, t=t_sub, fr=frame):
                                 t_run = led.dispatch_run(
                                     t, "msgr.dispatch."
-                                    + type(mm).__name__)
+                                    + type(mm).__name__, fr)
                                 try:
                                     d(c, mm)
                                 finally:

@@ -45,6 +45,28 @@ surface on the asyncio reactor pool:
   recovery_blame, and the per-messenger counter set registers into
   each daemon's perf collection for ceph_tpu_msgr_* exporter gauges.
 
+* **A frame's trip** — one data frame in SAMPLE_ONE_IN, chosen by a
+  rule of its (session, seq) that sender and receiver evaluate alike
+  (`frame_sampled`: nothing travels), has its one-way trip cut into
+  FRAME_PHASES on one clock: `send_message` called -> on the reactor
+  (`hop`) -> session send lock held (`sendlock`) -> encoded and
+  retained (`encode`) -> handed to the transport (`write`) -> header
+  in the receiver's hands (`transit`) -> body read (`body_read`) ->
+  decoded (`decode`) -> the handler's first line (`to_handler`).  The
+  phases partition the trip; each is a `lat_frame_<phase>` histogram
+  and, by message type, a dotted key of the set
+  (`frame_ns.<Type>.<phase>`).  The two ends meet in a bounded
+  in-process table (`frame_depart` / `frame_claim`); a receiver that
+  finds no slot there (the peer is another process) records no
+  transit and counts `msgr_frame_samples_unpaired`.
+
+* **Reactor loops** — the pool's loops run on a selector that times
+  itself (`ReactorSelector`): per reactor, seconds asleep in `select`,
+  seconds running between two sleeps, wake-ups, iterations, and at
+  dump time the thread's CPU from /proc.  running - cpu is the time a
+  reactor had work and was not on a CPU (waiting for the GIL, or
+  preempted).
+
 * **Always on, null when off** — enabled by default (conf ms_ledger);
   disabled, every entry point returns after ONE attribute check and
   allocates nothing (the NULL_TRACKED rule).  What the on path costs
@@ -62,11 +84,13 @@ totals without n_daemons-fold inflation.
 from __future__ import annotations
 
 import collections
+import selectors
 import threading
 import time
+import zlib
 
 from ..common import spans
-from ..common.perf_counters import PerfCountersBuilder
+from ..common.perf_counters import PerfCounters, PerfCountersBuilder
 
 # per-peer by-type maps are bounded: past this many distinct message
 # type names, further types count under "other" (a fuzzer or a newer
@@ -75,45 +99,228 @@ TYPE_CAP = 32
 OTHER_TYPE = "other"
 
 
+# A data frame's one-way trip, cut where the clock is read (module
+# doc); in this order the phases partition it.
+FRAME_PHASES = ("hop", "sendlock", "encode", "write",
+                "transit", "body_read", "decode", "to_handler")
+_TX_PHASES = FRAME_PHASES[:4]        # the sender's own
+_RX_PHASES = FRAME_PHASES[5:]        # the receiver's own
+# a by-type row: [sender halves, receiver halves, transits, ns per
+# phase..., drain ns] — drain (write end -> `drain()` returned: how
+# long the session's send lock stays held past the write) lies beside
+# the partition, not in it
+_ROW_PHASE0 = 3
+_ROW_TRANSIT = _ROW_PHASE0 + len(_TX_PHASES)
+_ROW_DRAIN = _ROW_PHASE0 + len(FRAME_PHASES)
+# one data frame in this many is timed
+SAMPLE_ONE_IN = 16
+# write-end stamps kept for receivers that have not come for them yet
+FLIGHT_CAP = 1024
+
+
+def frame_sampled(seq: int, off: int = 0) -> bool:
+    """Is frame `seq` of a session (whose `sample_offset` is `off`)
+    one of the SAMPLE_ONE_IN that are timed?  Both ends ask this of
+    the seq the frame carries, so they agree with nothing on the wire.
+    A multiplicative (Weyl) hash of seq, not `seq % 16`: a session
+    that alternates two message kinds — or cycles through 3, 4 or 16 —
+    still gives each kind its share (tests/test_frame_trip.py)."""
+    return ((seq + off) * 0x6A09E667) & 0xFFFFFFFF < 0x10000000
+
+
+def sample_offset(nonce: str) -> int:
+    """Where in the rule's cycle a session starts: from its nonce,
+    which both ends hold, so that the k+m sessions of one fan-out do
+    not all pick the same op's frames."""
+    return zlib.crc32(nonce.encode())
+
+
+class FrameTx:
+    """The sender's stamps of one sampled frame, made by
+    `Connection._send` once the frame has its seq: message type, the
+    flight-table key (session nonce, sender is the connector, seq) and
+    the clock at `send_message` called, `_send` entered, send lock
+    held, encode done; `entry` is the flight-table slot that takes the
+    write-end stamp."""
+
+    __slots__ = ("mtype", "key", "t_call", "t_in", "t_lock", "t_enc",
+                 "entry")
+
+    def __init__(self, mtype: str, key: tuple, t_call: int, t_in: int,
+                 t_lock: int):
+        self.mtype = mtype
+        self.key = key
+        self.t_call = t_call
+        self.t_in = t_in
+        self.t_lock = t_lock
+        self.t_enc = 0
+        self.entry = None
+
+
+def trip_means(row: dict) -> dict:
+    """One `MsgrLedger.frame_rows` row (or the difference of two) as
+    mean microseconds per phase, each over the halves that recorded
+    it: the sender's, the receiver's, for `transit` those that met."""
+    over = dict.fromkeys(_TX_PHASES + ("drain",), row["n"])
+    over.update(dict.fromkeys(_RX_PHASES, row["rx_n"]))
+    over["transit"] = row["transit_n"]
+    return {"n": row["n"], "rx_n": row["rx_n"],
+            "transit_n": row["transit_n"],
+            "mean_us": {p: round(ns / over[p] / 1e3, 3)
+                        for p, ns in row["ns"].items() if over[p] > 0}}
+
+
+class _LedgerCounters(PerfCounters):
+    """The ledger's set plus the rows it renders only when dumped
+    (by message type, by reactor): the hot path adds to plain lists
+    and never builds a key string."""
+
+    rows = None     # (the plain dump) -> {key: number}; the ledger's
+
+    def dump(self) -> dict:
+        out = super().dump()
+        if self.rows is not None:
+            out.update(self.rows(out))
+        return out
+
+    def schema(self) -> dict:
+        out = super().schema()
+        if self.rows is not None:
+            for key, val in self.rows(super().dump()).items():
+                out[key] = "gauge" if isinstance(val, float) else "u64"
+        return out
+
+
+class ReactorSelector(selectors.DefaultSelector):
+    """The selector of one reactor loop, timing itself: every second
+    since the loop started is either asleep in `select` or running
+    (callbacks, coroutine steps, polls that could not block), so
+    `select_ns + run_ns` is the loop's wall time and nothing is a
+    difference.  Two clock reads and a few adds per sleep, none for a
+    poll; while the process's ledger is off, one check and nothing
+    counted (the account then has a gap and resumes at the next
+    stamp).  `account()` is read from other threads at dump time."""
+
+    _clock = staticmethod(time.perf_counter_ns)
+
+    def __init__(self):
+        super().__init__()
+        self.native_id = 0      # the loop thread's, for /proc
+        self.select_ns = 0      # asleep in select
+        self.run_ns = 0         # between two sleeps
+        self.sleeps = 0         # selects that were allowed to block
+        self.iterations = 0     # every select, polls too
+        self._last_ns = 0       # end of the last counted interval
+        self._asleep_since = 0  # nonzero while blocked in select
+
+    def loop_started(self) -> None:
+        """Called by the loop's own thread before `run_forever`."""
+        self.native_id = threading.get_native_id()
+        self._last_ns = self._clock()
+
+    def select(self, timeout=None):
+        led = MsgrLedger._host
+        if led is None or not led.enabled:
+            self._last_ns = 0
+            return super().select(timeout)
+        self.iterations += 1
+        if timeout is not None and timeout <= 0:
+            return super().select(timeout)
+        t0 = self._clock()
+        if self._last_ns:
+            self.run_ns += t0 - self._last_ns
+        self._asleep_since = t0
+        try:
+            return super().select(timeout)
+        finally:
+            t1 = self._last_ns = self._clock()
+            self._asleep_since = 0
+            self.select_ns += t1 - t0
+            self.sleeps += 1
+
+    def account(self) -> tuple[float, float]:
+        """(seconds asleep, seconds running) up to now: the interval
+        in progress is added to the side the loop is on."""
+        asleep, running = self.select_ns, self.run_ns
+        since, last, now = self._asleep_since, self._last_ns, \
+            self._clock()
+        if since:
+            asleep += max(0, now - since)
+        elif last:
+            running += max(0, now - last)
+        return asleep * 1e-9, running * 1e-9
+
+
 def _build_ledger_perf(name: str = "msgr_ledger"):
     """The process-shared set: reactor + dispatch-executor health
     (registered into ONE daemon per process — see module doc)."""
-    return (PerfCountersBuilder(name)
-            .add_u64_counter("msgr_dispatches",
-                             "handler runs completed through the "
-                             "shared dispatch executor")
-            .add_u64_counter("msgr_reactor_lag_events",
-                             "reactor lag probes that fired a FULL "
-                             "extra interval late (the tick-lag rule)")
-            .add_u64_counter("msgr_frames_out",
-                             "data frames handed to a transport "
-                             "(every messenger of the process; "
-                             "replayed frames count again)")
-            .add_u64_counter("msgr_socket_writes",
-                             "calls that handed bytes to a transport: "
-                             "data frames, stand-alone acks, replays")
-            .add_u64_counter("msgr_acks_out",
-                             "CTRL_ACK frames written on their own")
-            .add_u64_counter("msgr_acks_piggybacked",
-                             "CTRL_ACK frames that rode a data "
-                             "frame's write")
-            .add_gauge("msgr_dispatch_queued",
-                       "dispatch-executor submissions currently "
-                       "queued or running")
-            .add_gauge("msgr_dispatch_queued_hwm",
-                       "high-water of msgr_dispatch_queued")
-            .add_gauge("msgr_reactor_lag_worst",
-                       "worst last-probe loop lag across reactors "
-                       "(seconds)")
-            .add_histogram("lat_msgr_reactor_lag",
-                           "per-probe reactor loop lag "
-                           "(scheduled vs actual fire time)")
-            .add_histogram("lat_msgr_qwait",
-                           "dispatch-executor queue wait "
-                           "(submit -> handler start)")
-            .add_histogram("lat_msgr_dispatch",
-                           "dispatch handler run time")
-            .create_perf_counters())
+    b = _ledger_perf_builder(name)
+    return _LedgerCounters(b.name, b._counters)
+
+
+def _ledger_perf_builder(name: str) -> PerfCountersBuilder:
+    b = (PerfCountersBuilder(name)
+         .add_u64_counter("msgr_dispatches",
+                          "handler runs completed through the "
+                          "shared dispatch executor")
+         .add_u64_counter("msgr_reactor_lag_events",
+                          "reactor lag probes that fired a FULL "
+                          "extra interval late (the tick-lag rule)")
+         .add_u64_counter("msgr_frames_out",
+                          "data frames handed to a transport "
+                          "(every messenger of the process; "
+                          "replayed frames count again)")
+         .add_u64_counter("msgr_socket_writes",
+                          "calls that handed bytes to a transport: "
+                          "data frames, stand-alone acks, replays")
+         .add_u64_counter("msgr_acks_out",
+                          "CTRL_ACK frames written on their own")
+         .add_u64_counter("msgr_acks_piggybacked",
+                          "CTRL_ACK frames that rode a data "
+                          "frame's write")
+         .add_u64_counter("msgr_frame_samples_unpaired",
+                          "sampled frames delivered whose sender "
+                          "left no write-end stamp here (another "
+                          "process, a replay): no transit recorded")
+         .add_u64_counter("msgr_frame_stamps_evicted",
+                          "write-end stamps that fell out of the "
+                          "flight table unclaimed (peer in another "
+                          "process, dead wire, filtered frame)")
+         .add_gauge("msgr_dispatch_queued",
+                    "dispatch-executor submissions currently "
+                    "queued or running")
+         .add_gauge("msgr_dispatch_queued_hwm",
+                    "high-water of msgr_dispatch_queued")
+         .add_gauge("msgr_reactor_lag_worst",
+                    "worst last-probe loop lag across reactors "
+                    "(seconds)")
+         .add_histogram("lat_msgr_reactor_lag",
+                        "per-probe reactor loop lag "
+                        "(scheduled vs actual fire time)")
+         .add_histogram("lat_msgr_qwait",
+                        "dispatch-executor queue wait "
+                        "(submit -> handler start)")
+         .add_histogram("lat_msgr_dispatch",
+                        "dispatch handler run time"))
+    for phase, what in zip(FRAME_PHASES, (
+            "send_message called -> Connection._send entered on the "
+            "reactor",
+            "-> the session's send lock held",
+            "-> encode_parts + record_out done",
+            "-> the transport took the frame (_write_once returned)",
+            "-> the receiver's header read returned (in-process "
+            "peers only)",
+            "-> the body read returned",
+            "-> Message.decode done (unwrap included)",
+            "-> the handler's first line (inline or on the "
+            "executor)")):
+        b.add_histogram(f"lat_frame_{phase}",
+                        f"sampled data frames, 1 in {SAMPLE_ONE_IN}: "
+                        + what)
+    return b.add_histogram("lat_frame_drain",
+                           "sampled data frames: write end -> "
+                           "writer.drain() returned (beside the "
+                           "phases, not one of them)")
 
 
 def _build_msgr_perf(name: str = "msgr"):
@@ -217,6 +424,9 @@ class MsgrStats:
             collections.OrderedDict()
         self.sendq_hwm = 0
         self.sync_timeouts = 0
+        # which loop of the pool the messenger is pinned to (it sets
+        # this once it has picked one)
+        self.reactor: int | None = None
 
     def _peer(self, key: str) -> ConnStats:
         p = self._peers.get(key)
@@ -298,6 +508,7 @@ class MsgrStats:
             "encrypt_bytes": d["msgr_encrypt_bytes"],
             "sendq_hwm": self.sendq_hwm,
             "peers": len(self._peers),
+            "reactor": self.reactor,
         }
 
     def conn_rows(self) -> list[dict]:
@@ -326,6 +537,9 @@ class MsgrLedger:
     # eviction only drops the LEDGER's reference — the messenger keeps
     # its own stats object working)
     MESSENGER_CAP = 128
+    # the reactor pool's selectors, in reactor order: process-wide as
+    # the pool is, so a ledger made after `reset_host` still sees them
+    reactor_meters: list[ReactorSelector] = []
 
     def __init__(self, perf=None, enabled: bool = True,
                  peer_cap: int = 256, probe_interval: float = 0.25,
@@ -338,9 +552,30 @@ class MsgrLedger:
         self.warn_s = float(warn_s)
         self.window_s = float(window_s)
         self.perf = perf if perf is not None else _build_ledger_perf()
+        if isinstance(self.perf, _LedgerCounters):
+            self.perf.rows = self._dump_rows
         self._lock = threading.Lock()
         self._messengers: collections.OrderedDict[str, MsgrStats] = \
             collections.OrderedDict()
+        # a frame's trip (module doc).  The clock of every stamp: an
+        # attribute so a test can inject one; perf_counter_ns is the
+        # clock `spans` uses, and one process has one
+        self.now_ns = time.perf_counter_ns
+        # (nonce, sender is connector, seq) -> [write-end stamp,
+        # header-arrival stamp], each 0 until it is known; oldest
+        # evicted past FLIGHT_CAP
+        self._flight: collections.OrderedDict[tuple, list] = \
+            collections.OrderedDict()
+        # message type -> [sender halves, receiver halves, transits,
+        # ns per FRAME_PHASES..., drain ns]; bounded as _type_inc is.
+        # Sampled frames only (tens a second), so under a lock: the
+        # sums are exact
+        self._frame_rows: dict[str, list] = {}
+        self._frame_lock = threading.Lock()
+        # data frames written by message type (replays count again,
+        # as in msgr_frames_out), and HELLO frames
+        self._frames_by_type: dict[str, int] = {}
+        self._hellos = 0
         # reactor probe state: idx -> (wall ts, last lag); lag events
         # (ts, reactor, lag) in a bounded window deque
         self._reactor_lag: dict[int, tuple[float, float]] = {}
@@ -407,12 +642,16 @@ class MsgrLedger:
             self.perf.set("msgr_dispatch_queued_hwm", n)
         return time.perf_counter()
 
-    def dispatch_run(self, t_submit: float, span: str):
+    def dispatch_run(self, t_submit: float, span: str, frame=None):
         """The handler started running: close the queue-wait clock and
         open its span (common/spans.py: `msgr.dispatch.<MsgType>` for
         a message handler, the caller's own name for a continuation
-        handed to Messenger.submit_dispatch).  Returns the open span;
-        hand it to dispatch_done in a `finally`."""
+        handed to Messenger.submit_dispatch).  `frame`: the arguments
+        of `frame_delivered` for a sampled frame, whose `to_handler`
+        ends here.  Returns the open span; hand it to dispatch_done
+        in a `finally`."""
+        if frame is not None:
+            self.frame_delivered(*frame)
         sp = spans.begin(span)
         self.perf.hinc("lat_msgr_qwait",
                        max(0.0, sp.t0 * 1e-9 - t_submit))
@@ -433,32 +672,146 @@ class MsgrLedger:
 
     # -- socket writes (called behind the enabled gate) ----------------------
 
-    def note_wire(self, writes: int, frames: int = 0, acks: int = 0,
+    def note_wire(self, writes: int, mtypes=(), acks: int = 0,
                   rode: int = 0) -> None:
         """`writes` calls handed bytes to a transport; between them
-        they carried `frames` data frames, `acks` acks of their own
-        and `rode` acks ahead of a data frame (the counts behind
-        wire_writes_per_frame / wire_acks_per_frame)."""
+        they carried one data frame per entry of `mtypes` (its message
+        type), `acks` acks of their own and `rode` acks ahead of a
+        data frame (the counts behind wire_writes_per_frame /
+        wire_acks_per_frame / wire_frames_per_op)."""
         inc = self.perf.inc
         inc("msgr_socket_writes", writes)
-        if frames:
-            inc("msgr_frames_out", frames)
+        if mtypes:
+            inc("msgr_frames_out", len(mtypes))
+            for mtype in mtypes:
+                _type_inc(self._frames_by_type, mtype)
         if acks:
             inc("msgr_acks_out", acks)
         if rode:
             inc("msgr_acks_piggybacked", rode)
 
+    def note_hello(self) -> None:
+        """A CTRL_HELLO frame was written (a dial, an accept's reply,
+        a refusal)."""
+        self._hellos += 1
+
+    # -- a frame's trip (called behind the enabled gate) ---------------------
+
+    def _frame_row(self, mtype: str) -> list:
+        row = self._frame_rows.get(mtype)
+        if row is None:
+            if len(self._frame_rows) >= TYPE_CAP:
+                mtype = OTHER_TYPE
+            row = self._frame_rows.setdefault(
+                mtype, [0] * (_ROW_DRAIN + 1))
+        return row
+
+    def frame_depart(self, tx: FrameTx) -> None:
+        """A sampled frame is about to be handed to its transport:
+        put its slot `[write returned, header arrived]` into the
+        flight table BEFORE the write — under the GIL the receiving
+        reactor often has the whole frame, handler started, before
+        the sender is back from the system call."""
+        tx.entry = entry = [0, 0]
+        with self._frame_lock:
+            self._flight[tx.key] = entry
+            evicted = len(self._flight) - FLIGHT_CAP
+            for _ in range(evicted):
+                self._flight.popitem(last=False)
+        if evicted > 0:
+            self.perf.inc("msgr_frame_stamps_evicted", evicted)
+
+    def frame_sent(self, tx: FrameTx) -> int:
+        """`_write_once` returned: stamp it (under the table's lock:
+        `frame_claim` orders itself against this reading), and record
+        the sender's four phases.  `write` ends at this stamp — or at
+        the header's arrival, if a receiver of this process has
+        claimed the frame already: its trip did not wait for the
+        sender's return.  Returns the stamp (where `lat_frame_drain`
+        starts)."""
+        hinc = self.perf.hinc
+        with self._frame_lock:
+            t_w = tx.entry[0] = self.now_ns()
+            stamps = (tx.t_call, tx.t_in, tx.t_lock, tx.t_enc,
+                      tx.entry[1] or t_w)
+            row = self._frame_row(tx.mtype)
+            row[0] += 1
+            for i, phase in enumerate(_TX_PHASES):
+                dt = stamps[i + 1] - stamps[i]
+                row[_ROW_PHASE0 + i] += dt
+                hinc("lat_frame_" + phase, dt * 1e-9)
+        return t_w
+
+    def frame_drained(self, tx: FrameTx, t_w: int) -> None:
+        dt = self.now_ns() - t_w
+        with self._frame_lock:
+            self._frame_row(tx.mtype)[_ROW_DRAIN] += dt
+            self.perf.hinc("lat_frame_drain", dt * 1e-9)
+
+    def frame_claim(self, key: tuple, t_head: int) -> tuple:
+        """The receiver knows which frame it holds (seq read, the
+        frame unwrapped): take the sender's slot out of the table.
+        Returns (slot or None, t_arr) — `t_arr` is where the
+        receiver's share of the trip starts: the header's arrival, or
+        the sender's write-end stamp if that is already there and
+        later (the sender then counted up to it).  A slot not stamped
+        yet is told the arrival, so the sender ends `write` there.
+        No slot: the sender is another process, or the stamp fell out
+        (a replay, a frame older than FLIGHT_CAP samples)."""
+        with self._frame_lock:
+            entry = self._flight.pop(key, None)
+            if entry is None:
+                return None, t_head
+            t_w = entry[0]
+            if t_w > t_head:
+                return entry, t_w
+            if not t_w:
+                entry[1] = t_head
+            return entry, t_head
+
+    def frame_delivered(self, entry, mtype: str, t_arr: int,
+                        t_body: int, t_dec: int) -> None:
+        """First line of a sampled frame's handler, on whichever
+        thread runs it (`entry`, `t_arr`: what `frame_claim` gave):
+        record the receiver's phases, and `transit` — write returned
+        -> header arrived, 0 where the header came first — if the
+        sender is a messenger of this process: never a guess."""
+        t_h = self.now_ns()
+        hinc = self.perf.hinc
+        # where the sender's stamp overtook the header, the reads
+        # that ended before it are the sender's time already
+        t_body = max(t_body, t_arr)
+        with self._frame_lock:
+            row = self._frame_row(mtype)
+            row[1] += 1
+            if entry is not None:
+                t_w = entry[0]
+                dt = t_arr - t_w if 0 < t_w < t_arr else 0
+                row[2] += 1
+                row[_ROW_TRANSIT] += dt
+                hinc("lat_frame_transit", dt * 1e-9)
+            else:
+                self.perf.inc("msgr_frame_samples_unpaired")
+            stamps = (t_arr, t_body, t_dec, t_h)
+            for i, phase in enumerate(_RX_PHASES):
+                dt = stamps[i + 1] - stamps[i]
+                row[_ROW_TRANSIT + 1 + i] += dt
+                hinc("lat_frame_" + phase, dt * 1e-9)
+
     # -- reactor lag probe ---------------------------------------------------
 
-    def attach_reactors(self, loops, interval: float | None = None
-                        ) -> None:
+    def attach_reactors(self, loops, interval: float | None = None,
+                        meters=None) -> None:
         """Arm the self-rescheduling lag probe on each reactor loop
         (messenger._ensure_pool calls this right after pool creation).
         Probes keep firing while the ledger is disabled — the off-path
         cost is one attribute check per interval — so re-enabling
-        needs no re-arm."""
+        needs no re-arm.  `meters`: the loops' ReactorSelectors, in
+        the same order (the per-reactor rows of the set)."""
         if interval is not None:
             self.probe_interval = float(interval)
+        if meters is not None:
+            MsgrLedger.reactor_meters = list(meters)
         for idx, loop in enumerate(loops):
             token = object()
             self._probe_tokens[id(loop)] = token
@@ -506,6 +859,64 @@ class MsgrLedger:
             self.perf.inc("msgr_reactor_lag_events")
             self._lag_events.append((now, reactor, lag))
 
+    # -- rows rendered at dump time ------------------------------------------
+
+    def reactor_rows(self) -> list[dict]:
+        """One row per reactor loop, from its selector's account and
+        /proc: `running_s` = wall - asleep is measured, not a
+        difference; `stalled_s` = running - cpu is the time the loop
+        had work and was not on a CPU (waiting for the GIL, or
+        preempted: the sockets do not block)."""
+        rows = []
+        for i, sel in enumerate(MsgrLedger.reactor_meters):
+            asleep, running = sel.account()
+            cpu = spans.thread_cpu_s(sel.native_id) \
+                if sel.native_id else 0.0
+            rows.append({
+                "reactor": i, "wall_s": asleep + running,
+                "select_s": asleep, "running_s": running,
+                "cpu_s": cpu, "stalled_s": max(0.0, running - cpu),
+                "sleeps": sel.sleeps, "iterations": sel.iterations})
+        return rows
+
+    def frame_rows(self) -> dict[str, dict]:
+        """{message type: {n (sender halves), rx_n, transit_n,
+        ns: {phase: summed ns, "drain": ...}}} of the sampled
+        frames."""
+        with self._frame_lock:
+            rows = {t: list(r) for t, r in self._frame_rows.items()}
+        return {t: {"n": r[0], "rx_n": r[1], "transit_n": r[2],
+                    "ns": dict(zip(FRAME_PHASES + ("drain",),
+                                   r[_ROW_PHASE0:]))}
+                for t, r in rows.items()}
+
+    def _dump_rows(self, plain: dict) -> dict:
+        """The dotted keys of the set (module doc; the
+        `ec_drains_by_path.<path>` precedent): frames written by
+        message type with the control frames beside them, the sampled
+        trips by type, the reactor loops — the last only while the
+        ledger is on (a reactor's CPU moves whether or not its loop
+        is accounted)."""
+        out = {f"msgr_frames_out_by_type.{t}": n
+               for t, n in list(self._frames_by_type.items())}
+        out["msgr_frames_out_by_type.CTRL_ACK"] = plain["msgr_acks_out"]
+        out["msgr_frames_out_by_type.CTRL_HELLO"] = self._hellos
+        for mtype, row in self.frame_rows().items():
+            out[f"frame_n.{mtype}"] = row["n"]
+            out[f"frame_rx_n.{mtype}"] = row["rx_n"]
+            out[f"frame_transit_n.{mtype}"] = row["transit_n"]
+            for phase, ns in row["ns"].items():
+                out[f"frame_ns.{mtype}.{phase}"] = ns
+        if self.enabled:
+            for r in self.reactor_rows():
+                i = r["reactor"]
+                out[f"reactor_wall_s.{i}"] = r["wall_s"]
+                out[f"reactor_select_s.{i}"] = r["select_s"]
+                out[f"reactor_cpu_s.{i}"] = r["cpu_s"]
+                out[f"reactor_sleeps.{i}"] = r["sleeps"]
+                out[f"reactor_iterations.{i}"] = r["iterations"]
+        return out
+
     # -- aggregation surfaces ------------------------------------------------
 
     def _window_events(self) -> list[tuple[float, int, float]]:
@@ -546,6 +957,22 @@ class MsgrLedger:
                                for i, (_ts, lag)
                                in sorted(self._reactor_lag.items())},
                 "lag_events": self.lag_events_total,
+                # each loop's account (docs/TRACING.md "Reactor
+                # loops"), seconds rounded to the microsecond
+                "loops": [{k: round(v, 6) if isinstance(v, float)
+                           else v for k, v in row.items()}
+                          for row in self.reactor_rows()],
+            },
+            # the sampled trips by message type: mean us per phase
+            # (docs/TRACING.md "A frame's trip"), and every frame
+            # written by type
+            "frames": {
+                "sample_one_in": SAMPLE_ONE_IN,
+                "in_flight": len(self._flight),
+                "out_by_type": dict(self._frames_by_type),
+                "hellos": self._hellos,
+                "trips": {t: trip_means(r)
+                          for t, r in self.frame_rows().items()},
             },
             "dispatch": {
                 "pending": self._dispatch_pending,
@@ -613,6 +1040,8 @@ class MsgrLedger:
         perf histograms are monotonic by design and stay)."""
         with self._lock:
             self._messengers.clear()
+        with self._frame_lock:
+            self._flight.clear()
         self._reactor_lag.clear()
         self._lag_events.clear()
         self.lag_events_total = 0

@@ -3321,8 +3321,28 @@ class OSDDaemon:
                 self._do_client_op(conn, msg, _t0)
             return
         key = (msg.pgid.pgid.pool, msg.oid.name)
-        with self._obj_locks[hash(key) % len(self._obj_locks)]:
-            self._do_client_op(conn, msg, _t0)
+        lock = self._obj_locks[hash(key) % len(self._obj_locks)]
+        top = getattr(msg, "top", NULL_TRACKED)
+        if not top.is_tracked:
+            with lock:
+                self._do_client_op(conn, msg, _t0)
+            return
+        # a tracked op times the lock: how long it waited for the
+        # object and how long it kept it (`lat_obj_lock_wait` /
+        # `_hold` in this OSD's optracker set, one sample an op), and
+        # marks `obj_lock_acquired` on its timeline — an event, not a
+        # phase anchor (docs/TRACING.md "Phases")
+        with lock:
+            t_held = time.perf_counter()
+            top.mark_event("obj_lock_acquired")
+            try:
+                self._do_client_op(conn, msg, _t0)
+            finally:
+                t_free = time.perf_counter()
+        perf = self.op_tracker.perf
+        if perf is not None:
+            perf.hinc("lat_obj_lock_wait", t_held - _t0)
+            perf.hinc("lat_obj_lock_hold", t_free - t_held)
 
     def _do_client_op_safe(self, conn, msg: M.MOSDOp, _t0: float) -> None:
         """Same exception fence as _handle_client_op_safe for the

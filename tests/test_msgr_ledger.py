@@ -188,11 +188,22 @@ async def _abort_wire(conn):
     conn.session.drop_wire()
 
 
-def test_reconnect_and_replay_counted_across_wire_kill():
-    """Hard-abort the live wire mid-burst (the lossless-session test
+async def _abort_wire_and_send(conn, msg):
+    # one reactor step: the frame finds the wire gone before the
+    # read loop does, so Connection._send re-dials, not _reconnect
+    conn.session.drop_wire()
+    await conn._send(msg)
+
+
+@pytest.mark.parametrize("redial_by", ["read_loop", "send"])
+def test_reconnect_and_replay_counted_across_wire_kill(redial_by):
+    """Hard-abort the LIVE wire mid-burst (the lossless-session test
     shape): delivery stays exactly-once AND the ledger counts the
     reconnect round and the replayed unacked frames, per peer and in
-    the messenger totals."""
+    the messenger totals — whichever of the two re-dials: the read
+    loop through _reconnect, or the next frame's _send (a round that
+    went uncounted until PR 36).  The kill waits for a delivery: an
+    abort while the first dial is still in flight kills nothing."""
     MsgrLedger.reset_host()
     server = client = None
     try:
@@ -203,18 +214,24 @@ def test_reconnect_and_replay_counted_across_wire_kill():
         client = Messenger("client")
         conn = client.connect(addr)
         for i in range(30):
+            if i == 16 and redial_by == "send":
+                assert _wait(lambda: len(got) >= 1, timeout=15.0)
+                client._run_sync(_abort_wire_and_send(
+                    conn, M.MOSDPing(from_osd=i)))
+                continue
             conn.send_message(M.MOSDPing(from_osd=i))
-            if i == 15:
+            if i == 15 and redial_by == "read_loop":
+                assert _wait(lambda: len(got) >= 1, timeout=15.0)
                 client._run_sync(_abort_wire(conn))
         assert _wait(lambda: len(got) >= 30, timeout=15.0)
         assert got == list(range(30))        # still exactly-once
         t = client.stats.totals()
-        assert t["reconnects"] >= 1
+        assert t["reconnects"] == 1          # the round, counted once
         assert t["replay_frames"] >= 1
         assert t["msgs_out"] == 30
         row = next(r for r in client.stats.conn_rows()
                    if r["peer"] == "server")
-        assert row["reconnects"] >= 1
+        assert row["reconnects"] == 1
         assert row["replay_frames"] >= 1
         assert row["msgs_out"] == 30
         assert row["out_types"]["MOSDPing"] == 30
