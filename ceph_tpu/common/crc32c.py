@@ -45,12 +45,22 @@ def _crc32c_sw(crc: int, data: bytes) -> int:
 
 def crc32c(data, crc: int = 0xFFFFFFFF) -> int:
     """crc32c of `data` seeded with `crc` (default matches bufferlist's -1
-    convention for standalone checksums)."""
-    buf = bytes(data) if not isinstance(data, (bytes, bytearray)) else data
+    convention for standalone checksums).  `data` is bytes or any other
+    buffer (a bytearray, a view of a received frame's body, an ndarray),
+    which is hashed where it lies, never copied first."""
+    crc &= 0xFFFFFFFF
     lib = native.load()
+    if isinstance(data, bytes):
+        if lib is not None:
+            return lib.ceph_tpu_crc32c(crc, data, len(data))
+        return _crc32c_sw(crc, data)
+    view = memoryview(data)
+    if not view.c_contiguous:
+        return crc32c(bytes(data), crc)
+    arr = np.frombuffer(view, dtype=np.uint8)
     if lib is not None:
-        return lib.ceph_tpu_crc32c(crc & 0xFFFFFFFF, bytes(buf), len(buf))
-    return _crc32c_sw(crc & 0xFFFFFFFF, bytes(buf))
+        return lib.ceph_tpu_crc32c(crc, arr.ctypes.data, arr.size)
+    return _crc32c_sw(crc, arr)
 
 
 def crc32c_rows(rows: np.ndarray, seeds) -> list[int]:

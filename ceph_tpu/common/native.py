@@ -61,7 +61,7 @@ def load() -> ctypes.CDLL | None:
             return None
         lib.ceph_tpu_crc32c.restype = ctypes.c_uint32
         lib.ceph_tpu_crc32c.argtypes = [
-            ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t]
+            ctypes.c_uint32, ctypes.c_void_p, ctypes.c_size_t]
         lib.ceph_tpu_crc32c_zeros.restype = ctypes.c_uint32
         lib.ceph_tpu_crc32c_zeros.argtypes = [ctypes.c_uint32, ctypes.c_uint64]
         lib.ceph_tpu_crc32c_combine.restype = ctypes.c_uint32

@@ -90,6 +90,12 @@ class Message:
     """Base message: subclasses set type_id and implement meta/data."""
 
     type_id: int = 0
+    # May `decode_wire` be handed its data segment as a read-only
+    # memoryview of the received frame's own buffer (a body the
+    # receiver took in place, msg/messenger.py FrameReceiver)?  A kind
+    # says yes only when its decode_wire and every consumer of what it
+    # keeps take any buffer; the others get ONE bytes copy.
+    takes_view = False
 
     def __init__(self) -> None:
         self.seq = 0
@@ -163,6 +169,8 @@ class Message:
         cls = _REGISTRY.get(tid)
         if cls is None:
             raise ValueError(f"unknown message type {tid}")
+        if not (cls.takes_view or isinstance(data, bytes)):
+            data = bytes(data)
         msg = cls.from_wire(json.loads(meta_raw.decode()), data)
         msg.seq = seq
         return msg

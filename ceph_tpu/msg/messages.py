@@ -97,12 +97,16 @@ def txn_to_wire(txn: Transaction) -> tuple[list, bytes]:
     return ops, bytes(blob)
 
 
-def txn_from_wire(ops: list, blob: bytes) -> Transaction:
+def txn_from_wire(ops: list, blob) -> Transaction:
+    """`blob`: bytes, or a read-only view of a received frame's body.
+    A write's payload stays a window onto it (the 512 KiB - 2 MiB of a
+    shard write are not copied here); attrs and omap values, which the
+    store keeps as they are, are cut out as bytes."""
     from ..osd.types import ghobject_t
 
     def get(ref) -> bytes:
         off, ln = ref
-        return blob[off:off + ln]
+        return bytes(blob[off:off + ln])
 
     def j2g(j):
         return ghobject_t(hobj_from_json(j[0]), j[1], j[2])
@@ -113,8 +117,10 @@ def txn_from_wire(ops: list, blob: bytes) -> Transaction:
         if kind == "touch":
             t.touch(j2g(rec[1]))
         elif kind == "write":
+            off, ln = rec[3]
             t.write(j2g(rec[1]), rec[2],
-                    np.frombuffer(get(rec[3]), dtype=np.uint8))
+                    np.frombuffer(blob, dtype=np.uint8, count=ln,
+                                  offset=off))
         elif kind == "zero":
             t.zero(j2g(rec[1]), rec[2], rec[3])
         elif kind == "truncate":
@@ -152,6 +158,9 @@ class MOSDOp(Message):
     concatenated in the data segment in op order."""
 
     type_id = 42
+    # the OSD's op switch slices `data` per op and wraps each slice
+    # (np.frombuffer for payloads, bytes() for keys and values)
+    takes_view = True
 
     def __init__(self, pgid: spg_t, oid: hobject_t, ops: list,
                  data: bytes = b"", tid: int = 0, epoch: int = 0,
@@ -240,6 +249,7 @@ class MOSDECSubOpWrite(Message):
     and its history land in one shard transaction)."""
 
     type_id = 108
+    takes_view = True       # txn_from_wire
 
     def __init__(self, pgid: spg_t, tid: int, at_version: eversion_t,
                  txn: Transaction, log_entries: list | None = None,
@@ -332,6 +342,7 @@ class MOSDECSubOpRead(Message):
 @register_message
 class MOSDECSubOpReadReply(Message):
     type_id = 111
+    takes_view = True       # `data` is only ever np.frombuffer'd
 
     def __init__(self, pgid: spg_t, tid: int, shard: int, result: int,
                  data: bytes = b"", attrs: dict[str, bytes] | None = None,
@@ -378,7 +389,7 @@ class MOSDECSubOpReadReply(Message):
         self.size = meta["size"]
         dlen = meta["dlen"]
         self.data = data[:dlen]
-        blob = json.loads(data[dlen:].decode())
+        blob = json.loads(bytes(data[dlen:]).decode())
         if "a" not in blob:      # pre-omap layout: the blob IS attrs
             blob = {"a": blob}
         self.attrs = {k: bytes.fromhex(v)

@@ -29,7 +29,31 @@ out_seq/in_seq, ack frames, session resume + replay on reconnect):
   crc) leave in ONE transport call (_write_once: joined when small,
   writelines → one sendmsg when large, so a payload is never copied
   into a frame buffer).  Part by part, TCP_NODELAY made each part a
-  segment and the peer's read_frame woke for each.
+  segment and the peer's receiver woke for each.
+- One read per burst of small frames, and a large body in place
+  (FrameReceiver, an asyncio.BufferedProtocol under every connection:
+  the transport `recv_into`s the buffer it is handed, no StreamReader
+  between).  Reads land in one RX_SCRATCH buffer and every frame
+  complete in it is cut out and queued in order — ten pings, or a
+  sub-write reply and the ack ahead of it, cost one system call.  A
+  header that announces a body (meta + data + crc) longer than
+  JOIN_UP_TO gets that body a buffer of its own, and until it is full
+  the kernel copies straight into it, as much a call as the socket
+  holds: a 4 MiB body in the 1-3 reads the kernel needs, where the
+  stream transport's 256 KiB a loop pass took 16, each a wake-up of a
+  waiter that found the buffer short.  Why JOIN_UP_TO: it is the size
+  above which the SEND side stops copying a payload into a frame
+  buffer, so both ends agree on what "large" is and a frame is either
+  joined-and-cut (small, two short copies) or never copied in user
+  space at all; the rule reads only a length off the wire.  The
+  message kinds whose handlers take any buffer (Message.takes_view)
+  get such a body's data as a read-only view of that buffer, which
+  is never written again — whoever keeps it (a store, the extent
+  cache) keeps the buffer alive; the others get one bytes copy.  The
+  payload crc is computed over the view before dispatch, as ever.
+  Back-pressure: complete frames nobody has taken may hold
+  RX_HOLD_MAX; past it the transport is paused until the read loop
+  (parked, say, in a blocking handler) takes them.
 - The Session owns the live TCP stream; Connections are facades over it,
   so a server reply issued after the client reconnected rides the new
   stream (the reference rebinds AsyncConnection to the existing session
@@ -109,11 +133,18 @@ ACK_DELAY_S = 1.0
 # `sendmsg`), so a large payload is never copied into a frame buffer.
 JOIN_UP_TO = 64 << 10
 
+# The receive side (FrameReceiver): the one buffer every read lands in
+# unless a large body is being filled (what asyncio's stream transport
+# read a pass), and the body bytes a connection's complete frames may
+# hold untaken before its transport is paused.
+RX_SCRATCH = 256 << 10
+RX_HOLD_MAX = 8 << 20
+
 
 def _write_once(writer: asyncio.StreamWriter, bufs) -> None:
     """Hand `bufs` (non-empty buffers: a frame's parts, an ack ahead
     of them) to the transport in ONE call — one system call and one
-    TCP segment where the socket takes it, so the peer's read_frame
+    TCP segment where the socket takes it, so the peer's receiver
     wakes once for the whole frame."""
     if len(bufs) == 1:
         writer.write(bufs[0])
@@ -138,11 +169,10 @@ def _parse_raw(raw: bytes) -> tuple[int, int, bytes, bytes, int]:
     or mangled buffer raises ValueError so the read loop's corruption
     path (session-preserving wire reset) handles it — struct.error
     would kill the loop."""
-    import struct as _struct
     try:
         tid, seq, meta_len, data_len = \
             Message.parse_header(raw[:Message.HEADER_SIZE])
-    except (_struct.error, ValueError) as e:
+    except (struct.error, ValueError) as e:
         raise ValueError(f"bad inner frame: {e}") from e
     off = Message.HEADER_SIZE
     if len(raw) < off + meta_len + data_len + 4:
@@ -153,45 +183,233 @@ def _parse_raw(raw: bytes) -> tuple[int, int, bytes, bytes, int]:
     return tid, seq, meta_raw, data, pcrc
 
 
-class _RxStamps:
-    """A read loop's clock readings of the frame in its hands (the
-    wire ledger's "a frame's trip"): `t_head` when the header read
-    returned — 0 for a frame the sampling rule leaves out — and
-    `t_body` when the body read did."""
+class FrameReceiver(asyncio.streams.FlowControlMixin,
+                    asyncio.BufferedProtocol):
+    """The protocol under one messenger connection: the transport
+    reads the socket straight into the buffer `get_buffer` hands it
+    (`recv_into`), and `buffer_updated` cuts what arrived into frames.
 
-    __slots__ = ("sess", "now_ns", "t_head", "t_body")
+    Small frames, many a read: reads land in one scratch buffer, and
+    every frame complete in it — header (magic and header crc checked),
+    then body — is cut out as `bytes` and queued, in order.  A large
+    body in place: a header that announces a body longer than
+    JOIN_UP_TO gets that body a buffer of its own; the prefix that came
+    with the header moves in, and until the body is full `get_buffer`
+    hands out ITS unfilled rest, so the kernel copies to where the
+    frame will live, as much a call as the socket holds.  Such a
+    frame's `data` is a read-only view of that buffer, which is never
+    written again.
 
-    def __init__(self, sess: "Session", now_ns):
+    `next_frame` yields (tid, seq, meta_raw, data, pcrc, t_head,
+    t_body) — the two stamps are the wire ledger's ("a frame's trip"):
+    the clock when a sampled frame's header was parsed and when its
+    last body byte had landed, 0 for a frame the sampling rule leaves
+    out (a wrapped frame, ENC or COMP, shows its own seq only once
+    unwrapped: stamped here, tested by the read loop).  EOF or a lost
+    connection raises IncompleteReadError / the transport's error once
+    the frames complete before it are taken; a bad magic or header crc
+    raises ValueError the same way.
+
+    Back-pressure: while the complete frames nobody has taken hold
+    more than RX_HOLD_MAX the transport is paused, so a read loop
+    parked in a slow handler stops a flooding peer at the socket.
+
+    The write side's StreamWriter drains through this protocol
+    (FlowControlMixin, as asyncio's own StreamReaderProtocol does)."""
+
+    def __init__(self, ledger: MsgrLedger, sess: "Session | None" = None,
+                 on_accept=None):
+        super().__init__()
+        self.ledger = ledger
+        # the session whose sampling rule picks the frames to stamp:
+        # a dialled wire knows it from the start, an accepted one once
+        # the HELLO names it
         self.sess = sess
-        self.now_ns = now_ns
-        self.t_head = self.t_body = 0
+        self._on_accept = on_accept
+        self.transport: asyncio.Transport | None = None
+        self._scratch = bytearray(RX_SCRATCH)
+        self._sview = memoryview(self._scratch)
+        self._lo = self._hi = 0     # scratch[lo:hi]: read, not yet cut
+        # (tid, seq, meta_len, data_len, t_head) of the frame whose
+        # body is still arriving
+        self._head: tuple | None = None
+        # the large body being filled (a view of its own bytearray),
+        # bytes landed, reads that landed
+        self._body: memoryview | None = None
+        self._bfill = self._breads = 0
+        self._frames: collections.deque[tuple] = collections.deque()
+        self._held = 0              # body bytes of the queued frames
+        self._rx_paused = False
+        self._exc: BaseException | None = None
+        self._waiter: asyncio.Future | None = None
 
+    # -- transport callbacks -------------------------------------------------
 
-async def read_frame(reader: asyncio.StreamReader,
-                     rx: _RxStamps | None = None
-                     ) -> tuple[int, int, bytes, bytes, int]:
-    """Read one wire frame -> (tid, seq, meta_raw, data, pcrc); raises
-    ValueError on corruption (bad magic / header crc).  Two reads per
-    frame (header, then body in one readexactly + slice) — each await
-    is a potential reactor suspension, and the EC fan-out pays it per
-    shard reply.  `rx` (the read loop's, while the wire ledger is on)
-    takes the clock after each read for a frame the sampling rule
-    picks: one test a frame, and the header's parse (≈1 us) falls to
-    what came before it.  A wrapped frame (ENC, COMP) shows its own
-    seq only once unwrapped, so it is stamped here and tested there."""
-    head = await reader.readexactly(Message.HEADER_SIZE)
-    tid, seq, meta_len, data_len = Message.parse_header(head)
-    if rx is not None:
-        timed = frame_sampled(seq, rx.sess.sample_off) \
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        if self._on_accept is not None:
+            writer = asyncio.StreamWriter(transport, self, None,
+                                          self._loop)
+            # the task is referenced while it runs (the loop holds
+            # tasks weakly)
+            self._task = self._loop.create_task(
+                self._on_accept(self, writer))
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        body = self._body
+        if body is not None:
+            return body[self._bfill:]
+        return self._sview[self._hi:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        led = self.ledger
+        if led.enabled:
+            led.note_rx_read()
+        if self._exc is not None:
+            return                  # a dead stream's bytes: dropped
+        if self._body is not None:
+            self._bfill += nbytes
+            self._breads += 1
+            if self._bfill < len(self._body):
+                return
+            self._large_done()
+        else:
+            self._hi += nbytes
+            try:
+                self._cut_frames()
+            except ValueError as e:
+                self._fail(e)
+                transport = self.transport
+                if transport is not None:
+                    transport.pause_reading()
+                return
+        if self._frames:
+            if self._held > RX_HOLD_MAX and not self._rx_paused:
+                self._rx_paused = True
+                self.transport.pause_reading()
+            self._wake()
+
+    def eof_received(self) -> None:
+        self._fail(asyncio.IncompleteReadError(b"", None))
+
+    def connection_lost(self, exc) -> None:
+        super().connection_lost(exc)
+        self._fail(exc if exc is not None
+                   else asyncio.IncompleteReadError(b"", None))
+
+    # -- cutting -------------------------------------------------------------
+
+    def _fail(self, exc: BaseException) -> None:
+        if self._exc is None:
+            self._exc = exc
+            self._body = None
+        self._wake()
+
+    def _wake(self) -> None:
+        waiter = self._waiter
+        if waiter is not None and not waiter.done():
+            waiter.set_result(None)
+
+    def _stamp_head(self, tid: int, seq: int) -> int:
+        sess = self.sess
+        if sess is None:
+            return 0
+        timed = frame_sampled(seq, sess.sample_off) \
             if tid < CTRL_HELLO else tid in (CTRL_ENC, CTRL_COMP)
-        rx.t_head = rx.now_ns() if timed else 0
-    body = await reader.readexactly(meta_len + data_len + 4)
-    if rx is not None and rx.t_head:
-        rx.t_body = rx.now_ns()
-    meta_raw = body[:meta_len]
-    data = body[meta_len:meta_len + data_len]
-    (pcrc,) = struct.unpack("<I", body[-4:])
-    return tid, seq, meta_raw, data, pcrc
+        return self.ledger.now_ns() if timed else 0
+
+    def _cut_frames(self) -> None:
+        """Cut every complete frame out of scratch[lo:hi]; leave a
+        partial one where the next read can finish it."""
+        view = self._sview
+        lo, hi = self._lo, self._hi
+        hsize = Message.HEADER_SIZE
+        stamping = self.ledger.enabled
+        while True:
+            head = self._head
+            if head is None:
+                if hi - lo < hsize:
+                    break
+                tid, seq, meta_len, data_len = \
+                    Message.parse_header(bytes(view[lo:lo + hsize]))
+                lo += hsize
+                head = self._head = (
+                    tid, seq, meta_len, data_len,
+                    self._stamp_head(tid, seq) if stamping else 0)
+            tid, seq, meta_len, data_len, t_head = head
+            need = meta_len + data_len + 4
+            if need > JOIN_UP_TO:
+                # a body of its own; what came with the header moves in
+                try:
+                    body = memoryview(bytearray(need))
+                except (MemoryError, OverflowError) as e:
+                    raise ValueError(f"frame body of {need} bytes") from e
+                have = min(hi - lo, need)
+                body[:have] = view[lo:lo + have]
+                lo += have
+                self._body, self._bfill = body, have
+                self._breads = 1 if have else 0
+                if have < need:
+                    break           # lo == hi: the scratch is empty
+                self._large_done()
+                continue
+            if hi - lo < need:
+                break
+            mid = lo + meta_len
+            end = lo + need
+            self._frames.append((
+                tid, seq, bytes(view[lo:mid]), bytes(view[mid:end - 4]),
+                int.from_bytes(view[end - 4:end], "little"), t_head,
+                self.ledger.now_ns() if t_head else 0))
+            self._held += need
+            self._head = None
+            lo = end
+        if lo == hi:
+            lo = hi = 0
+        elif lo:
+            # the partial frame (shorter than JOIN_UP_TO and a header)
+            # goes to the front, so the next read may take the rest of
+            # a whole scratch (the slice assignment is a memmove)
+            n = hi - lo
+            view[:n] = view[lo:hi]
+            lo, hi = 0, n
+        self._lo, self._hi = lo, hi
+
+    def _large_done(self) -> None:
+        """The body in `_body` is full: queue its frame."""
+        body, self._body = self._body, None
+        tid, seq, meta_len, data_len, t_head = self._head
+        self._head = None
+        led = self.ledger
+        if led.enabled:
+            led.note_large_body(self._breads, len(body))
+        self._frames.append((
+            tid, seq, bytes(body[:meta_len]),
+            body[meta_len:meta_len + data_len].toreadonly(),
+            int.from_bytes(body[-4:], "little"), t_head,
+            led.now_ns() if t_head else 0))
+        self._held += len(body)
+
+    # -- the read loop's side ------------------------------------------------
+
+    async def next_frame(self) -> tuple:
+        """The next frame in arrival order; suspends only when none
+        is complete."""
+        while not self._frames:
+            if self._exc is not None:
+                raise self._exc
+            self._waiter = self._loop.create_future()
+            try:
+                await self._waiter
+            finally:
+                self._waiter = None
+        frame = self._frames.popleft()
+        self._held -= len(frame[2]) + len(frame[3]) + 4
+        if self._rx_paused and self._held <= RX_HOLD_MAX:
+            self._rx_paused = False
+            if self._exc is None:
+                self.transport.resume_reading()
+        return frame
 
 
 class Session:
@@ -221,7 +439,9 @@ class Session:
         self.in_seq = 0           # highest seq delivered to the dispatcher
         self.unacked: collections.deque[tuple[int, bytes]] = \
             collections.deque()
-        self.reader: asyncio.StreamReader | None = None
+        # the live wire's receiver; also the wire's epoch token (a read
+        # loop serves `sess.reader is reader` and nothing after it)
+        self.reader: FrameReceiver | None = None
         self.writer: asyncio.StreamWriter | None = None
         self.send_lock = asyncio.Lock()
         self.broken = False
@@ -603,15 +823,13 @@ class Connection:
         entity + in_seq (+ authorizer), read the peer's (+ mutual auth
         proof), trim + replay unacked."""
         assert self.peer_addr is not None
-        # 4 MiB stream buffer: the default 64 KiB limit makes every
-        # 128 KiB shard reply / 1 MiB op reply ping-pong through flow
-        # control pauses (resume_reading wakeups) several times per
-        # frame
-        reader, writer = await asyncio.open_connection(
-            *self.peer_addr, limit=4 << 20)
-        _grow_socket_buffers(writer)
         sess = self.session
         m = self.messenger
+        loop = asyncio.get_running_loop()
+        transport, reader = await loop.create_connection(
+            lambda: FrameReceiver(m.ledger, sess), *self.peer_addr)
+        writer = asyncio.StreamWriter(transport, reader, None, loop)
+        _grow_socket_buffers(writer)
         hello_meta = {
             "entity": m.entity,
             "session": sess.nonce,
@@ -629,8 +847,8 @@ class Connection:
         if m.ledger.enabled:
             m.ledger.note_hello()
         await writer.drain()
-        tid, _seq, meta_raw, _data, _pcrc = await asyncio.wait_for(
-            read_frame(reader), timeout=5.0)
+        tid, _seq, meta_raw, *_ = await asyncio.wait_for(
+            reader.next_frame(), timeout=5.0)
         if tid != CTRL_HELLO:
             writer.close()
             raise ConnectionError(f"expected HELLO, got frame type {tid:#x}")
@@ -987,22 +1205,23 @@ class Messenger:
         """Bind and start accepting; port 0 picks a free port."""
 
         async def _bind():
-            server = await asyncio.start_server(
-                self._on_accept, addr[0], addr[1], limit=4 << 20)
-            return server
+            return await asyncio.get_running_loop().create_server(
+                lambda: FrameReceiver(self.ledger,
+                                      on_accept=self._on_accept),
+                addr[0], addr[1])
 
         self._server = self._run_sync(_bind())
         sock = self._server.sockets[0]
         self.my_addr = sock.getsockname()[:2]
         return self.my_addr
 
-    async def _on_accept(self, reader: asyncio.StreamReader,
+    async def _on_accept(self, reader: FrameReceiver,
                          writer: asyncio.StreamWriter) -> None:
         """Accept = read the peer's HELLO, bind/resume its Session, reply
         with our in_seq, replay anything it is missing."""
         try:
-            tid, _seq, meta_raw, _data, _pcrc = await asyncio.wait_for(
-                read_frame(reader), timeout=10.0)
+            tid, _seq, meta_raw, *_ = await asyncio.wait_for(
+                reader.next_frame(), timeout=10.0)
             if tid != CTRL_HELLO:
                 writer.close()
                 return
@@ -1057,6 +1276,7 @@ class Messenger:
         sess.drop_wire()          # supersede any stale stream
         _grow_socket_buffers(writer)
         sess.reader, sess.writer = reader, writer
+        reader.sess = sess
         sess.auth_identity = auth_identity
         sess.set_conn_key(conn_key, b"\x02")
         sess.secure = bool(auth_identity and
@@ -1156,24 +1376,18 @@ class Messenger:
         self._run_soon(self._read_loop(conn, conn.session.reader))
 
     async def _read_loop(self, conn: Connection,
-                         reader: asyncio.StreamReader) -> None:
+                         reader: FrameReceiver) -> None:
         sess = conn.session
         led = self.ledger
-        rx = _RxStamps(sess, led.now_ns)
         try:
             while not conn._closed and reader is sess.reader:
                 # t_head: nonzero while this frame's trip is timed
-                # (wire ledger, "a frame's trip")
-                if led.enabled:
-                    tid, seq, meta_raw, data, pcrc = \
-                        await read_frame(reader, rx)
-                    t_head = rx.t_head
-                else:
-                    tid, seq, meta_raw, data, pcrc = \
-                        await read_frame(reader)
-                    t_head = 0
+                # (wire ledger, "a frame's trip"); a frame already
+                # complete is taken without suspending
+                tid, seq, meta_raw, data, pcrc, t_head, t_body = \
+                    await reader.next_frame()
                 if reader is not sess.reader:
-                    # epoch reset while we were blocked in read_frame: a
+                    # epoch reset while we were blocked in next_frame: a
                     # buffered old-epoch frame must not touch the fresh
                     # epoch's seq window (in_seq poisoning)
                     break
@@ -1219,7 +1433,7 @@ class Messenger:
                         row.__exit__(None, None, None)
                 # the arguments of led.frame_delivered, for whichever
                 # thread runs the handler's first line
-                frame = (slot, type(msg).__name__, t_arr, rx.t_body,
+                frame = (slot, type(msg).__name__, t_arr, t_body,
                          led.now_ns()) if t_head else None
                 # ingest stamp for op tracking (reference
                 # Message::recv_stamp set by the messenger): dispatch

@@ -51,7 +51,7 @@ surface on the asyncio reactor pool:
   FRAME_PHASES on one clock: `send_message` called -> on the reactor
   (`hop`) -> session send lock held (`sendlock`) -> encoded and
   retained (`encode`) -> handed to the transport (`write`) -> header
-  in the receiver's hands (`transit`) -> body read (`body_read`) ->
+  parsed by the receiver (`transit`) -> body landed (`body_read`) ->
   decoded (`decode`) -> the handler's first line (`to_handler`).  The
   phases partition the trip; each is a `lat_frame_<phase>` histogram
   and, by message type, a dotted key of the set
@@ -278,6 +278,19 @@ def _ledger_perf_builder(name: str) -> PerfCountersBuilder:
          .add_u64_counter("msgr_acks_piggybacked",
                           "CTRL_ACK frames that rode a data "
                           "frame's write")
+         .add_u64_counter("msgr_rx_reads",
+                          "reads that brought bytes from a socket "
+                          "(FrameReceiver.buffer_updated calls; every "
+                          "messenger of the process)")
+         .add_u64_counter("msgr_large_bodies",
+                          "frame bodies longer than JOIN_UP_TO, "
+                          "received in place in a buffer of their own")
+         .add_u64_counter("msgr_large_body_reads",
+                          "reads that landed in such a body, the one "
+                          "that carried its prefix behind the header "
+                          "included")
+         .add_u64_counter("msgr_large_body_bytes",
+                          "bytes of those bodies (meta + data + crc)")
          .add_u64_counter("msgr_frame_samples_unpaired",
                           "sampled frames delivered whose sender "
                           "left no write-end stamp here (another "
@@ -308,9 +321,9 @@ def _ledger_perf_builder(name: str) -> PerfCountersBuilder:
             "-> the session's send lock held",
             "-> encode_parts + record_out done",
             "-> the transport took the frame (_write_once returned)",
-            "-> the receiver's header read returned (in-process "
+            "-> the receiver has parsed the header (in-process "
             "peers only)",
-            "-> the body read returned",
+            "-> the body's last byte has landed",
             "-> Message.decode done (unwrap included)",
             "-> the handler's first line (inline or on the "
             "executor)")):
@@ -689,6 +702,21 @@ class MsgrLedger:
             inc("msgr_acks_out", acks)
         if rode:
             inc("msgr_acks_piggybacked", rode)
+
+    # -- socket reads (called behind the enabled gate) -----------------------
+
+    def note_rx_read(self) -> None:
+        """A read brought bytes from a socket into a receiver."""
+        self.perf.inc("msgr_rx_reads")
+
+    def note_large_body(self, reads: int, nbytes: int) -> None:
+        """A body of `nbytes`, longer than JOIN_UP_TO, is complete in
+        its own buffer after `reads` reads that landed in it (the
+        counts behind wire_reads_per_large_body)."""
+        inc = self.perf.inc
+        inc("msgr_large_bodies")
+        inc("msgr_large_body_reads", reads)
+        inc("msgr_large_body_bytes", nbytes)
 
     def note_hello(self) -> None:
         """A CTRL_HELLO frame was written (a dial, an accept's reply,

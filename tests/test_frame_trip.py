@@ -172,6 +172,56 @@ def test_phases_partition_send_called_to_handler_start(
         server.shutdown()
 
 
+@pytest.mark.parametrize("inline", [True, False],
+                         ids=["inline", "executor"])
+@pytest.mark.parametrize("size", [60 << 10, 1 << 20],
+                         ids=["through_the_scratch", "in_place"])
+def test_phases_partition_with_the_stamps_taken_in_the_receiver(
+        fresh_ledger, size, inline):
+    """The receiver reads the clock for `t_head` where it has parsed
+    the header and for `t_body` where the last body byte has landed —
+    in its scratch, or in the body's own buffer after reads of their
+    own: the eight phases still add up to the trip, and every sample
+    finds its sender's slot."""
+    led = fresh_ledger
+    clock = led.now_ns = FakeClock()
+    server, client, conn, handled = _pair("plain", inline)
+    # (the priming ping left in the dial's replay, unstamped by its
+    # sender: the rule may have picked it, and it then found no slot)
+    unpaired0 = led.perf.dump()["msgr_frame_samples_unpaired"]
+    try:
+        lo = None
+        for i in range(64):
+            lo = len(clock.log)
+            conn.send_message(_mosdop(i, size))
+            assert _wait(lambda: len(handled) > i)
+            if _kind(led, "MOSDOp")["rx_n"]:
+                break
+        assert _wait(lambda: _kind(led, "MOSDOp")["n"] == 1)
+        row = _kind(led, "MOSDOp")
+        assert (row["n"], row["rx_n"], row["transit_n"]) == (1, 1, 1)
+        reads = clock.log[lo:]
+        who = [w for _, w in reads]
+        t_call = [t for t, w in reads if w == "send_message"]
+        t_handler = [t for t, w in reads if w == "frame_delivered"]
+        ns = row["ns"]
+        assert sum(ns[p] for p in FRAME_PHASES) == \
+            t_handler[0] - t_call[0]
+        assert all(ns[p] >= 0 for p in FRAME_PHASES), ns
+        # where the two receive stamps were read
+        assert who.count("_stamp_head") == 1
+        landed = "_large_done" if size > (64 << 10) else "_cut_frames"
+        assert who.count(landed) == 1
+        assert who.index("_stamp_head") < who.index(landed)
+        d = led.perf.dump()
+        assert d["msgr_frame_samples_unpaired"] == unpaired0
+        assert d["msgr_large_bodies"] == \
+            (i + 1 if size > (64 << 10) else 0)
+    finally:
+        client.shutdown()
+        server.shutdown()
+
+
 def test_send_batch_reads_the_hop_start_once_for_the_batch(fresh_ledger):
     led = fresh_ledger
     clock = led.now_ns = FakeClock()
@@ -418,7 +468,8 @@ def test_ledger_off_reads_no_clock_and_moves_no_key(fresh_ledger):
         return {k: v for k, v in led.perf.dump().items()
                 if k.startswith(("lat_frame_", "frame_", "reactor_",
                                  "msgr_frames_out_by_type.",
-                                 "msgr_frame_"))}
+                                 "msgr_frame_", "msgr_rx_",
+                                 "msgr_large_"))}
 
     server, client, conn, handled = _pair("plain", inline=False)
     try:
@@ -426,7 +477,8 @@ def test_ledger_off_reads_no_clock_and_moves_no_key(fresh_ledger):
         for i in range(64):
             conn.send_message(_mosdop(i, 64))
         client.send_batch([(conn, _mosdop(i, 64)) for i in range(8)])
-        assert _wait(lambda: len(handled) >= 72)
+        conn.send_message(_mosdop(99, 1 << 20))  # a body in place
+        assert _wait(lambda: len(handled) >= 73)
         assert clock.log == []
         assert new_keys() == before
         assert not any(k.startswith("reactor_") for k in before)

@@ -66,6 +66,63 @@ def test_disabled_null_path_records_nothing():
     assert b["dispatches"] == 0
 
 
+_RX_KEYS = ("msgr_rx_reads", "msgr_large_bodies",
+            "msgr_large_body_reads", "msgr_large_body_bytes")
+
+
+@pytest.mark.parametrize("enabled", [True, False], ids=["on", "off"])
+def test_receive_counters_move_by_exact_amounts(enabled):
+    """A known exchange handed to a receiver read by read: three
+    pings and the head of a 300 KiB frame in one read, its body in
+    two more, a ping, then a 100 KiB frame whole in one read —
+    `msgr_rx_reads` counts every read, a large body the reads that
+    landed in IT (the scratch read that carried its prefix included),
+    and with the ledger off nothing moves."""
+    from ceph_tpu.msg.message import Message
+    from ceph_tpu.msg.messenger import FrameReceiver
+    from ceph_tpu.osd.types import hobject_t, pg_t, spg_t
+
+    def op(seq, size):
+        return M.MOSDOp(spg_t(pg_t(1, 2), 0), hobject_t(1, f"o{seq}"),
+                        [["write", 0, size]], bytes(size)).encode(seq)
+
+    pings = b"".join(M.MOSDPing(from_osd=i).encode(i + 1)
+                     for i in range(3))
+    big, mid = op(4, 300 << 10), op(6, 100 << 10)
+    hsize = Message.HEADER_SIZE
+    reads = [pings + big[:hsize + 1000],        # scratch, prefix 1000
+             big[hsize + 1000:200_000],         # into the body
+             big[200_000:],                     # the body's last read
+             M.MOSDPing(from_osd=9).encode(5),  # scratch again
+             mid]                               # whole in the scratch
+    led = MsgrLedger(enabled=enabled)
+
+    async def main():
+        rx = FrameReceiver(led)
+
+        class Transport:
+            def pause_reading(self): pass
+            def resume_reading(self): pass
+
+        rx.connection_made(Transport())
+        for chunk in reads:
+            buf = rx.get_buffer(-1)
+            assert len(buf) >= len(chunk)
+            buf[:len(chunk)] = chunk
+            del buf
+            rx.buffer_updated(len(chunk))
+        return [(await rx.next_frame())[1] for _ in range(6)]
+
+    assert asyncio.run(main()) == [1, 2, 3, 4, 5, 6]
+    d = led.perf.dump()
+    if enabled:
+        assert [d[k] for k in _RX_KEYS] == [
+            5, 2, 3 + 1,
+            (len(big) - hsize) + (len(mid) - hsize)]
+    else:
+        assert [d[k] for k in _RX_KEYS] == [0, 0, 0, 0]
+
+
 def test_per_type_counters_and_peer_ring_bound():
     """Per-peer rows: by-type maps count each message type, the
     by-type table overflows into "other" past TYPE_CAP, the per-peer
