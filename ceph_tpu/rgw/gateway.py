@@ -289,10 +289,11 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _require_bucket_perm(self, bucket: str, perm: str,
                              action: str | None = None,
-                             key: str | None = None) -> None:
+                             key: str | None = None) -> dict:
         """AWS combination: explicit policy Deny always wins, policy
         Allow grants without consulting the ACL, otherwise the canned
-        ACL decides."""
+        ACL decides.  Returns the bucket row the decision read, for
+        the rest of the request (reference req_state's bucket_info)."""
         bmeta = self._bucket_meta_or_404(bucket)
         decision = self._policy_eval(
             bmeta, bucket, action or
@@ -301,10 +302,11 @@ class _Handler(BaseHTTPRequestHandler):
         if decision == "Deny":
             raise RGWError(403, "AccessDenied", bucket)
         if decision == "Allow":
-            return
+            return bmeta
         if not self._acl_allows(bmeta.get("owner"),
                                 bmeta.get("acl", "private"), perm):
             raise RGWError(403, "AccessDenied", bucket)
+        return bmeta
 
     def _require_bucket_owner(self, bucket: str) -> None:
         owner, _ = self._bucket_acl(bucket)
@@ -622,9 +624,11 @@ class _Handler(BaseHTTPRequestHandler):
                 f"<LastModified>{lm}</LastModified>"
                 "</CopyObjectResult>").encode())
         elif self.command == "PUT":
-            self._require_bucket_perm(bucket, "WRITE",
-                                      action="s3:PutObject", key=key)
-            etag = st.put_object(bucket, key, body, extra=_stamp())
+            bmeta = self._require_bucket_perm(bucket, "WRITE",
+                                              action="s3:PutObject",
+                                              key=key)
+            etag = st.put_object(bucket, key, body, extra=_stamp(),
+                                 bmeta=bmeta)
             self._reply(200, extra={"ETag": f'"{etag}"'})
         elif self.command == "POST" and "uploads" in query:
             self._require_bucket_perm(bucket, "WRITE",
@@ -952,6 +956,7 @@ class S3Gateway:
         perf.inc("rgw_put")
         perf.inc("rgw_put_bytes", put_bytes)
         perf.inc("rgw_put_rados_ops", req.ops)
+        perf.inc("rgw_put_bucket_row_reads", req.bucket_row_reads)
         perf.inc("rgw_put_account_writes", req.account_writes)
         perf.hinc("rgw_put_lat", time.perf_counter() - req.t0)
         for key, kind in (("rgw_put_frontend_lat", "frontend"),

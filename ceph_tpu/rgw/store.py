@@ -85,6 +85,9 @@ def _build_perf():
          .add_u64_counter("rgw_put_rados_ops", "RADOS ops issued on "
                           "behalf of those PUTs, authorization "
                           "included")
+         .add_u64_counter("rgw_put_bucket_row_reads", "of those, "
+                          "cls rgw dir_get calls on the bucket "
+                          "registry object")
          .add_u64_counter("rgw_put_account_writes", "cls user calls "
                           "made for those PUTs that rewrote an account "
                           "object: a reserve that came back with a "
@@ -116,13 +119,15 @@ class RequestTally:
     kind, and hand the request's trace context to the objecter, so an
     OSD's `dump_historic_ops` joins to the request by trace_id."""
 
-    __slots__ = ("trace", "t0", "ops", "account_writes", "lat")
+    __slots__ = ("trace", "t0", "ops", "account_writes",
+                 "bucket_row_reads", "lat")
 
     def __init__(self, t0: float):
         self.trace = TraceContext.new()
         self.t0 = t0
         self.ops = 0
         self.account_writes = 0
+        self.bucket_row_reads = 0
         self.lat: dict[str, float] = {}
 
 
@@ -271,6 +276,10 @@ class RGWStore:
     def _cls(self, io, oid: str, method: str, payload: dict | None = None
              ) -> bytes:
         inp = json.dumps(payload).encode() if payload is not None else b""
+        if oid == BUCKETS_OBJ and method == "dir_get":
+            req = self.current_request()
+            if req is not None:
+                req.bucket_row_reads += 1
         return io.execute(oid, "rgw", method, inp)
 
     def _modlog(self, op: str, bucket: str,
@@ -791,13 +800,17 @@ class RGWStore:
                               bmeta=bmeta)
 
     def put_object(self, bucket: str, key: str, body: bytes,
-                   extra: dict | None = None) -> str:
+                   extra: dict | None = None,
+                   bmeta: dict | None = None) -> str:
         """Returns the ETag (md5 hex, S3 semantics).  On a versioned
         bucket every PUT archives a new immutable version; the current
         pointer rides the bucket index like before.  `extra` merges
         additional rows into the object meta (owner/acl stamps from
-        the gateway's auth layer)."""
-        bmeta = self._bucket_meta(bucket)
+        the gateway's auth layer).  `bmeta` is the bucket row the
+        caller read for this request (the gateway's authorization);
+        without it the row is read here."""
+        if bmeta is None:
+            bmeta = self._bucket_meta(bucket)
         if bmeta is None:
             raise RGWError(404, "NoSuchBucket", bucket)
         owner = (extra or {}).get("owner") or bmeta.get("owner")
@@ -814,7 +827,7 @@ class RGWStore:
             etag = self._etag(body)
             self._modlog("sync", bucket, key)
             if bmeta.get("versioning") == "Enabled":
-                self._archive_null_version(bucket, key)
+                self._archive_null_version(bucket, key, bmeta=bmeta)
                 vid = self._new_version_id()
                 meta = {"size": len(body), "etag": etag,
                         "mtime": time.time(), **(extra or {})}
@@ -833,7 +846,7 @@ class RGWStore:
                 return etag
             suspended = bool(bmeta.get("versioning"))  # "" = never
             reap = self._displaced_manifests(bucket, key, suspended,
-                                             cur=cur)
+                                             cur=cur, bmeta=bmeta)
             meta = {"size": len(body), "etag": etag,
                     "mtime": time.time(), **(extra or {})}
             self.data.write_full(_data_oid(bucket, key), body)
@@ -979,33 +992,34 @@ class RGWStore:
                       bmeta=bmeta)
         self._modlog("sync", bucket, key)       # post-success
 
-    def _version_row(self, bucket: str, key: str,
-                     version_id: str) -> dict | None:
+    def _version_row(self, bucket: str, key: str, version_id: str,
+                     bmeta: dict | None = None) -> dict | None:
         try:
             raw = self.index.get(bucket, "versions",
-                                 f"{key}\x00{version_id}", route=key)
+                                 f"{key}\x00{version_id}", route=key,
+                                 bmeta=bmeta)
         except RadosError as e:
             self._not_found(e)
             return None
         return json.loads(raw.decode())
 
     def _displaced_manifests(self, bucket: str, key: str,
-                             suspended: bool,
-                             cur: dict | None = None) -> list[dict]:
+                             suspended: bool, cur: dict | None,
+                             bmeta: dict) -> list[dict]:
         """Manifests whose LAST reference disappears when a
         non-versioned write/delete displaces the current object: the
         current index row's manifest (unless its own version row
         still references it), plus — on a Suspended bucket, where S3
         says the write REPLACES the null version — the existing null
         row's manifest.  Reaping anything else would destroy an
-        archived version's data; reaping less leaks parts forever."""
+        archived version's data; reaping less leaks parts forever.
+        `cur` is the key's index entry as the caller read it for this
+        request (None: no entry), `bmeta` its bucket row."""
         out: dict[str, dict] = {}
-        if cur is None:
-            cur = self._current_meta(bucket, key)
         if cur and cur.get("multipart") and not cur.get("version_id"):
             out[cur["multipart"]["upload_id"]] = cur["multipart"]
         if suspended:
-            row = self._version_row(bucket, key, "null")
+            row = self._version_row(bucket, key, "null", bmeta=bmeta)
             if row and row.get("multipart"):
                 out[row["multipart"]["upload_id"]] = row["multipart"]
         return list(out.values())
@@ -1091,7 +1105,7 @@ class RGWStore:
             return
         suspended = bool(bmeta.get("versioning"))
         reap = self._displaced_manifests(bucket, key, suspended,
-                                         cur=cur)
+                                         cur=cur, bmeta=bmeta)
         try:
             self.index.rm(bucket, "index", key, bmeta=bmeta)
         except RadosError as e:
@@ -1251,7 +1265,8 @@ class RGWStore:
                                bmeta=bmeta)
             else:
                 suspended = bool(bmeta.get("versioning"))
-                reap = self._displaced_manifests(bucket, key, suspended)
+                reap = self._displaced_manifests(bucket, key, suspended,
+                                                 cur=cur, bmeta=bmeta)
                 self.index.add(bucket, "index", key, obj_meta,
                                bmeta=bmeta)
                 if suspended:

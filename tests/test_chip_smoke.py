@@ -61,11 +61,11 @@ def test_phases_green_at_tiny_size_on_the_cpu_twin():
     assert rep["scrub"]["errors"] == 0
     assert rep["scrub"]["objects"] == rep["ops_acked"]
     # the S3 step: an 11-shard bucket from the configuration, 8 signed
-    # PUTs on EC k4m2 read back and listed, nine RADOS ops a PUT
+    # PUTs on EC k4m2 read back and listed, six RADOS ops a PUT
     s3 = rep["phases"]["s3"]
     assert (s3["ops"], s3["bytes"], s3["index_shards"],
             s3["rados_ops_per_put"]) == (
-        8, 8 * TINY.s3_object_bytes, 11, 9.0)
+        8, 8 * TINY.s3_object_bytes, 11, 6.0)
     assert {p["window"] for p in rep["phases"].values()} == \
         {"setup", "serving"}
     assert rep["fused_point"]["source"] == "default (cpu)"

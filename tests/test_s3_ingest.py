@@ -12,6 +12,7 @@ its trace id reaches the OSDs; and each fault of
 benchmark/faults_s3.py reads not correct."""
 
 import copy
+import errno
 import json
 import os
 import sys
@@ -32,7 +33,9 @@ from run import load_module  # noqa: E402
 
 from ceph_tpu.common import spans  # noqa: E402
 from ceph_tpu.rados import RadosClient  # noqa: E402
+from ceph_tpu.rados.client import RadosError  # noqa: E402
 from ceph_tpu.rgw.gateway import S3Gateway  # noqa: E402
+from ceph_tpu.rgw.store import _part_oid  # noqa: E402
 
 GEN = load_module("generators", "s3_closed_loop_put")
 REF = load_module("references", "s3_bucket_ec")
@@ -125,16 +128,16 @@ def test_index_shards_equal_on_their_replicas(sound):
 def test_reader_on_the_runs_own_dumps(sound):
     got = READER.read(sound)
     assert set(got) == set(READER.METRICS)
-    # authorization 1, bucket row 2, index look-ups 2, quota gate
-    # (the user has no limit: it reserves nothing and no release
-    # follows), write, index add, stats
-    assert got["rgw_rados_ops_per_put"] == 9.0
+    # the bucket row once (authorization, handed on to the store), the
+    # key's index entry once, quota gate (the user has no limit: it
+    # reserves nothing and no release follows), write, index add, stats
+    assert got["rgw_rados_ops_per_put"] == 6.0
     split = sum(got[k] for k in (
         "rgw_frontend_ms_mean", "rgw_data_write_ms_mean",
         "rgw_index_ms_per_put", "rgw_account_ms_per_put"))
     assert 0.8 * got["rgw_put_ms_mean"] < split <= got["rgw_put_ms_mean"]
-    # eight of a PUT's nine ops go to the replicated pool
-    assert 0.84 < got["rgw_index_ops_share"] < 0.94
+    # five of a PUT's six ops go to the replicated pool
+    assert 0.78 < got["rgw_index_ops_share"] < 0.88
     assert got["client_outside_rgw_ms_mean"] > 0
     # a cell without a gateway, a program without the counters
     assert READER.read({"run": {"ops": []}}) == {}
@@ -242,9 +245,10 @@ def test_rgw_counters_move_by_the_exact_counts(live):
     assert delta("rgw_req") == n and delta("rgw_failed") == 0
     assert delta("rgw_put") == n
     assert delta("rgw_put_bytes") == n * SIZE
-    assert delta("rgw_put_rados_ops") == 9 * n
-    assert delta(f"rgw_rados_ops.{meta}") == 8 * n
+    assert delta("rgw_put_rados_ops") == 6 * n
+    assert delta(f"rgw_rados_ops.{meta}") == 5 * n
     assert delta(f"rgw_rados_ops.{data}") == n
+    assert delta("rgw_put_bucket_row_reads") == n
     # the user has no limit: every PUT passes the gate, none reserves,
     # and the account object is rewritten once a PUT, by the stats
     assert delta("rgw_quota_gates") == n
@@ -301,7 +305,9 @@ def test_counters_are_exact_under_concurrent_puts(live):
     assert not errors and not any(t.is_alive() for t in threads)
     after = _rgw(live)
     assert after["rgw_put"] - before["rgw_put"] == 32
-    assert after["rgw_put_rados_ops"] - before["rgw_put_rados_ops"] == 288
+    assert after["rgw_put_rados_ops"] - before["rgw_put_rados_ops"] == 192
+    assert after["rgw_put_bucket_row_reads"] - \
+        before["rgw_put_bucket_row_reads"] == 32
     assert after["rgw_put_account_writes"] - \
         before["rgw_put_account_writes"] == 32
     assert after["rgw_quota_gates"] - before["rgw_quota_gates"] == 32
@@ -328,11 +334,127 @@ def test_a_limited_users_put_is_nine_ops_and_two_account_writes(live):
         assert "pending" not in store.get_user_header(user)
     finally:
         store.set_user_quota(user)
-    for key, want in (("rgw_put", n), ("rgw_put_rados_ops", 9 * n),
+    for key, want in (("rgw_put", n), ("rgw_put_rados_ops", 6 * n),
                       ("rgw_put_account_writes", 2 * n),
                       ("rgw_quota_gates", n),
                       ("rgw_quota_reservations", n)):
         assert after[key] - before[key] == want, key
+
+
+# -- one bucket row and one index entry a PUT --------------------------------
+
+def _index_gets(live, bucket: str) -> int:
+    """Look-ups of the bucket's index shards (every plane) so far."""
+    return sum(c["get"] for c in
+               live["gw"].store.index.perf_dump(bucket).values())
+
+
+def _multipart(store, bucket: str, key: str) -> list[str]:
+    """Complete a two-part upload of `key`: -> its part objects."""
+    upload_id = store.init_multipart(bucket, key)
+    parts = [(num, store.upload_part(bucket, key, upload_id, num,
+                                     bytes([num]) * SIZE))
+             for num in (1, 2)]
+    store.complete_multipart(bucket, key, upload_id, parts)
+    return [_part_oid(bucket, upload_id, num) for num, _ in parts]
+
+
+def _absent(io, oid: str) -> bool:
+    try:
+        io.stat(oid)
+    except RadosError as e:
+        assert e.errno == errno.ENOENT
+        return True
+    return False
+
+
+@pytest.mark.parametrize("overwrite", [False, True],
+                         ids=["fresh_key", "overwrite"])
+def test_a_put_reads_its_bucket_row_once_and_its_index_entry_once(
+        live, overwrite):
+    """The row the authorization read is the one `put_object` uses,
+    and an entry found absent is not looked up again."""
+    conn, spec = live["conn"], live["spec"]
+    bucket = "overwritten" if overwrite else "fresh"
+    assert conn.request("PUT", f"/{bucket}")[0] == 200
+    n = 3
+    if overwrite:
+        for i in range(n):
+            assert conn.request("PUT", f"/{bucket}/k{i}",
+                                body=b"o" * SIZE)[0] == 200
+    before, gets = _rgw(live), _index_gets(live, bucket)
+    for i in range(n):
+        # a size of its own: the overwrite's stats call goes out too
+        assert conn.request("PUT", f"/{bucket}/k{i}",
+                            body=bytes([i]) * (SIZE // 2))[0] == 200
+    after = _rgw(live)
+    meta, data = spec["meta_pool"]["name"], live["dep"].pool
+    assert after["rgw_put"] - before["rgw_put"] == n
+    assert after["rgw_put_bucket_row_reads"] - \
+        before["rgw_put_bucket_row_reads"] == n
+    assert _index_gets(live, bucket) - gets == n
+    assert after["rgw_put_rados_ops"] - before["rgw_put_rados_ops"] == 6 * n
+    assert after[f"rgw_rados_ops.{meta}"] - \
+        before[f"rgw_rados_ops.{meta}"] == 5 * n
+    assert after[f"rgw_rados_ops.{data}"] - \
+        before[f"rgw_rados_ops.{data}"] == n
+    rows = GEN.list_bucket(conn, bucket, 7)
+    assert [(k, size) for k, size, _ in rows] == \
+        [(f"k{i}", SIZE // 2) for i in range(n)]
+
+
+def test_an_overwrite_of_a_multipart_object_removes_its_parts(live):
+    conn, store = live["conn"], live["gw"].store
+    assert conn.request("PUT", "/mpover")[0] == 200
+    parts = _multipart(store, "mpover", "big")
+    assert not any(_absent(store.data, oid) for oid in parts)
+    before = _rgw(live)
+    assert conn.request("PUT", "/mpover/big", body=b"p" * SIZE)[0] == 200
+    after = _rgw(live)
+    assert after["rgw_put_bucket_row_reads"] - \
+        before["rgw_put_bucket_row_reads"] == 1
+    assert all(_absent(store.data, oid) for oid in parts)
+    status, _, body = conn.request("GET", "/mpover/big")
+    assert (status, body) == (200, b"p" * SIZE)
+
+
+def test_a_put_on_a_suspended_bucket_replaces_the_null_rows_manifest(live):
+    conn, store = live["conn"], live["gw"].store
+    assert conn.request("PUT", "/suspended")[0] == 200
+    store.set_versioning("suspended", "Suspended")
+    parts = _multipart(store, "suspended", "doc")
+    assert store._version_row("suspended", "doc", "null")["multipart"]
+    before = _rgw(live)
+    status, headers, _ = conn.request("PUT", "/suspended/doc",
+                                      body=b"n" * SIZE)
+    assert status == 200
+    after = _rgw(live)
+    assert after["rgw_put_bucket_row_reads"] - \
+        before["rgw_put_bucket_row_reads"] == 1
+    row = store._version_row("suspended", "doc", "null")
+    assert "multipart" not in row and row["null_data"] is True
+    assert (row["size"], f'"{row["etag"]}"') == (SIZE, headers["ETag"])
+    assert all(_absent(store.data, oid) for oid in parts)
+    body, _ = store.get_object_version("suspended", "doc", "null")
+    assert bytes(body) == b"n" * SIZE
+
+
+def test_a_put_to_a_deleted_bucket_is_404_after_one_op(live, monkeypatch):
+    conn, gw = live["conn"], live["gw"]
+    assert conn.request("PUT", "/gonebucket")[0] == 200
+    assert conn.request("DELETE", "/gonebucket")[0] == 204
+    seen = []
+    real = gw.account
+
+    def spy(req, status, put_bytes):
+        seen.append((status, req.ops, req.bucket_row_reads))
+        return real(req, status, put_bytes)
+
+    monkeypatch.setattr(gw, "account", spy)
+    status, _, body = conn.request("PUT", "/gonebucket/k",
+                                   body=b"x" * SIZE)
+    assert status == 404 and b"NoSuchBucket" in body
+    assert seen == [(404, 1, 1)]
 
 
 def test_span_tree_of_one_put(live, monkeypatch):
@@ -360,7 +482,8 @@ def test_span_tree_of_one_put(live, monkeypatch):
     children = [name for name, parent in seen if parent == "rgw.put"]
     assert children.count("rgw.auth") == 1
     assert children.count("rgw.data_write") == 1
-    assert children.count("rgw.index") == 6
+    # the bucket row (authorization), the key's entry, the index add
+    assert children.count("rgw.index") == 3
     assert children.count("rgw.account") == 2
     assert all(parent == "rgw.put" for name, parent in seen
                if name != "rgw.put")
