@@ -537,6 +537,26 @@ class ErasureCodeJax(ErasureCode):
             out[e] = rec[idx]
         return out
 
+    def decode_chunks_from(self, dense: np.ndarray, survivors,
+                           targets) -> np.ndarray:
+        """decode_chunks from the k `survivors` rows given, rebuilding
+        only the `targets` rows (the launch queue's run_decode): its
+        program is decode_chunks' with len(targets) erased, whichever
+        rows answered."""
+        survivors = tuple(sorted(int(s) for s in survivors))
+        targets = tuple(sorted(int(t) for t in targets))
+        if len(survivors) != self.k or set(survivors) & set(targets):
+            raise ErasureCodeError(
+                errno.EIO, f"decode needs {self.k} survivors apart from "
+                           f"the targets: {survivors} -> {targets}")
+        _, bitmat = self._decode_plan(survivors, targets)
+        rec = self._apply_bitmat(bitmat, dense[list(survivors)],
+                                 len(targets))
+        out = dense.copy()
+        for idx, t in enumerate(targets):
+            out[t] = rec[idx]
+        return out
+
 
 class ErasureCodePluginJax(ErasureCodePlugin):
     def factory(self, profile: Profile):

@@ -811,16 +811,21 @@ class MPGLogQuery(Message):
 
     type_id = 116
 
-    def __init__(self, pgid: spg_t = None, tid: int = 0):
+    def __init__(self, pgid: spg_t = None, tid: int = 0,
+                 epoch: int = 0):
         super().__init__()
-        self.pgid, self.tid = pgid, tid
+        # epoch: the map epoch the querying round read its acting set
+        # under (the interval it peers)
+        self.pgid, self.tid, self.epoch = pgid, tid, epoch
 
     def to_meta(self):
-        return {"pgid": spg_to_json(self.pgid), "tid": self.tid}
+        return {"pgid": spg_to_json(self.pgid), "tid": self.tid,
+                "epoch": self.epoch}
 
     def decode_wire(self, meta, data):
         self.pgid = spg_from_json(meta["pgid"])
         self.tid = meta["tid"]
+        self.epoch = meta.get("epoch", 0)
 
 
 @register_message
@@ -856,18 +861,20 @@ class MPGLogRollback(Message):
     type_id = 118
 
     def __init__(self, pgid: spg_t = None, tid: int = 0,
-                 v: eversion_t = None):
+                 v: eversion_t = None, epoch: int = 0):
         super().__init__()
-        self.pgid, self.tid, self.v = pgid, tid, v
+        # epoch: the interval of the round that asks (MPGLogQuery)
+        self.pgid, self.tid, self.v, self.epoch = pgid, tid, v, epoch
 
     def to_meta(self):
         return {"pgid": spg_to_json(self.pgid), "tid": self.tid,
-                "v": [self.v.epoch, self.v.version]}
+                "v": [self.v.epoch, self.v.version], "epoch": self.epoch}
 
     def decode_wire(self, meta, data):
         self.pgid = spg_from_json(meta["pgid"])
         self.tid = meta["tid"]
         self.v = eversion_t(*meta["v"])
+        self.epoch = meta.get("epoch", 0)
 
 
 @register_message

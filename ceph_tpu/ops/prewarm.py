@@ -87,10 +87,14 @@ class PrewarmPlan:
             plain_widths = [2048 << j for j in range(4)]
         if decode_widths is None:
             # up to parallel/launch_queue.DECODE_MAX_LAUNCH_W: the
-            # grouped recovery decode caps its concatenated launch
-            # width there and the launch queue pow2-pads every decode,
-            # so {pow2 <= cap} IS the full runtime decode width set
-            # (single chunks wider than the cap excepted)
+            # launch queue pow2-pads every decode and splits a key's
+            # submissions there, so {pow2 <= cap} is every width a
+            # reconstructing read, or a recovery of objects whose
+            # shards are at most a quarter of the cap, launches.  A
+            # recovery of wider shards launches the widths
+            # parallel/launch_queue.recovery_decode_widths(shard) names:
+            # a caller that knows its object size plans those (one
+            # erased shard a lost OSD)
             decode_widths = [2048 << j for j in range(6)]   # 2K..64K
         if decode_erasures is None:
             # one representative per erasure CARDINALITY, for every

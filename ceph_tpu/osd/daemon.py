@@ -82,14 +82,31 @@ class MessengerShardBackend(ShardBackend):
                   at_version=None, rollforward_to=None, trace=None,
                   top=None):
         from .pg_log import entry_to_wire
+        if log_entries:
+            state = self.daemon.pgs.get(self.pgid)
+            if state is None or state.needs_peer or \
+                    state.backend.shards is not self:
+                # the PG's interval changed after the op entered and it
+                # has not peered since: sent now, the write would land
+                # on holders (or leave holes) the new interval's logs
+                # do not account for — a returning shard's shorter log
+                # could then look current and roll the write back
+                # everywhere.  Not acknowledged; the client sends it
+                # again once the PG has peered
+                on_commit(shard, ErasureCodeError(
+                    errno.EAGAIN, f"pg {self.pgid} changed interval "
+                                  f"since the op entered"))
+                return
         osd = self._osd_for(shard)
         spg = spg_t(self.pgid, shard)
         if osd is None:
             # Hole in the acting set: the shard is degraded; ack now and
             # leave the rebuild to recovery.  Safe only because op
             # admission already enforced pool min_size (live shards >=
-            # min_size), mirroring the reference's split between
-            # PeeringState min_size gating and degraded-write tolerance.
+            # min_size) and the PG peered in this interval (the holes'
+            # logs are stale from then on), mirroring the reference's
+            # split between PeeringState min_size gating and
+            # degraded-write tolerance.
             self.degraded_shards.add(shard)
             self.daemon._pg_unclean(self.pgid)
             self.perf.inc("ec_sub_writes_skipped_down")
@@ -111,7 +128,7 @@ class MessengerShardBackend(ShardBackend):
             return
         tid = self._next_tid()
         with self.lock:
-            self._pending_writes[tid] = (on_commit, shard)
+            self._pending_writes[tid] = (on_commit, shard, osd)
         conn = self.daemon.conn_to_osd(osd)
         m = M.MOSDECSubOpWrite(
             spg, tid, at_version or eversion_t(), txn,
@@ -128,7 +145,7 @@ class MessengerShardBackend(ShardBackend):
         with self.lock:
             ent = self._pending_writes.pop(msg.tid, None)
         if ent:
-            on_commit, shard = ent
+            on_commit, shard, _osd = ent
             # Replies are fast-dispatched on the reactor, but
             # on_commit transitively runs the write pipeline
             # (try_finish_rmw -> check_ops -> possibly a BLOCKING
@@ -145,6 +162,46 @@ class MessengerShardBackend(ShardBackend):
                     f"changed"),)
             Messenger.submit_dispatch("ec.on_commit", on_commit, *args)
 
+    def resolve_departed(self) -> None:
+        """An interval change took holders away: sub-ops still waiting
+        on an OSD the map now says is down will never be answered.  A
+        write there fails with EAGAIN — its op is not acknowledged;
+        the client sends it again and it runs once the PG has peered
+        for the new interval.  (Acknowledging it as the hole path at
+        send time does would be wrong here: the hole path runs after
+        that peering raised the live shards' last_epoch_started above
+        the departed one's, and this write ran before it.  Were the
+        departed holder back before any peering, its shorter log would
+        look current and the write would be rolled back everywhere.)
+        A read there resolves as failed."""
+        up = self.daemon.osdmap.is_up
+        with self.lock:
+            writes = [(tid, ent) for tid, ent in self._pending_writes.items()
+                      if not up(ent[2])]
+            for tid, _ in writes:
+                del self._pending_writes[tid]
+            reads = [(tid, ent) for tid, ent in self._pending_reads.items()
+                     if not up(ent[2])]
+            for tid, _ in reads:
+                del self._pending_reads[tid]
+        for _tid, (on_commit, shard, osd) in writes:
+            Messenger.submit_dispatch(
+                "ec.on_commit", on_commit, shard, ErasureCodeError(
+                    errno.EAGAIN, f"shard {shard} of {self.pgid}: osd.{osd} "
+                    f"went down with the write in flight"))
+        for tid, (on_done, shard, _osd) in reads:
+            if on_done is None:
+                # a sync metadata RPC (_stat_rpc): its raw waiter
+                raw = self.daemon.raw_read_waiters.pop(
+                    (spg_t(self.pgid, shard), tid), None)
+                if raw is not None:
+                    raw(None)
+            elif getattr(on_done, "loop_safe", False):
+                on_done(shard, None)
+            else:
+                Messenger.submit_dispatch("ec.on_read_done", on_done,
+                                          shard, None)
+
     # -- reads --------------------------------------------------------------
 
     def sub_read(self, shard, oid, off, length, on_done):
@@ -159,7 +216,7 @@ class MessengerShardBackend(ShardBackend):
             return
         tid = self._next_tid()
         with self.lock:
-            self._pending_reads[tid] = (on_done, shard)
+            self._pending_reads[tid] = (on_done, shard, osd)
         conn = self.daemon.conn_to_osd(osd)
         conn.send_message(M.MOSDECSubOpRead(spg, tid, oid, off, length))
 
@@ -180,7 +237,7 @@ class MessengerShardBackend(ShardBackend):
                 continue
             tid = self._next_tid()
             with self.lock:
-                self._pending_reads[tid] = (on_done, shard)
+                self._pending_reads[tid] = (on_done, shard, osd)
             conn = self.daemon.conn_to_osd(osd)
             pairs.append((conn, M.MOSDECSubOpRead(spg, tid, oid, off,
                                                   length)))
@@ -193,7 +250,7 @@ class MessengerShardBackend(ShardBackend):
         with self.lock:
             ent = self._pending_reads.pop(msg.tid, None)
         if ent:
-            on_done, shard = ent
+            on_done, shard, _osd = ent
             data = (np.frombuffer(msg.data, dtype=np.uint8)
                     if msg.result == 0 else None)
             if getattr(on_done, "loop_safe", False):
@@ -225,7 +282,7 @@ class MessengerShardBackend(ShardBackend):
             ev.set()
 
         with self.lock:
-            self._pending_reads[tid] = (None, shard)
+            self._pending_reads[tid] = (None, shard, osd)
             self.daemon.raw_read_waiters[(spg, tid)] = on_done_raw
         conn = self.daemon.conn_to_osd(osd)
         conn.send_message(
@@ -412,6 +469,9 @@ class PGState:
         # serving (reference PeeringState: no ops until Active)
         self.needs_peer = True
         self.peer_lock = threading.Lock()
+        # acting-set changes seen (OSDDaemon's map handler): a peering
+        # round that saw it move does not clear needs_peer
+        self.acting_gen = 0
         # clean for the current interval (docs/PIPELINE.md
         # "Authoritative local shard"): interval_gen counts every
         # event that starts an interval or casts doubt on this one
@@ -757,6 +817,10 @@ class OSDDaemon:
         # (in memory: a restarted holder fences nothing until the
         # interval's primary peers it again)
         self._activated_by: dict[spg_t, int | None] = {}
+        # shard -> (the primary whose peering last asked for its log,
+        # the interval that round peers); None: an OSD this daemon
+        # cannot name (_claim_peering)
+        self._peering_by: dict[spg_t, tuple[int | None, int]] = {}
         # shard-resident replicated PG logs (reference: pglog omap keys
         # in the pg meta collection) + peering RPC plumbing
         self.shard_logs: dict = {}
@@ -807,9 +871,15 @@ class OSDDaemon:
         # thread-executed spans (common/spans.py): one table per
         # process, exported by ONE daemon under the same rule
         _hs = spans.host_spans()
+        # the host-level sets this daemon exports (shutdown hands
+        # them back)
+        self._host_sets = [obj for obj, mine in (
+            (self._profiler, self._profiler_reporter),
+            (_mled, self._msgr_reporter)) if mine]
         if not getattr(_hs, "_perf_registered", False):
             _hs._perf_registered = True
             self.cct.perf.add(_hs)
+            self._host_sets.append(_hs)
         # fast dispatch (reference ms_fast_dispatch): the EC data-path
         # RPCs run inline on the reactor — their handlers never block
         # on nested RPCs (shard read = store read + async send; the
@@ -881,6 +951,7 @@ class OSDDaemon:
 
     def boot(self, timeout: float = 10.0) -> None:
         """reference OSD::init + MOSDBoot."""
+        self.pg_ledger.note_boot()
         self._maybe_prewarm()
         self.mon_conn.send_message(M.MMonGetMap())
         self.mon_conn.send_message(M.MOSDBoot(self.osd_id, self.addr))
@@ -911,6 +982,13 @@ class OSDDaemon:
             name=f"osd.{self.osd_id}.pgstats").start()
 
     def shutdown(self) -> None:
+        # the host-level perf sets this daemon exported go back: the
+        # next daemon built or wiring the launch queue (this one
+        # restarted, as a rule) exports them, so a host's `perf dump`
+        # keeps them across an OSD restart
+        for host_set in self._host_sets:
+            host_set._perf_registered = False
+        self._host_sets = []
         self._hb_stop.set()
         self._op_pool.shutdown(wait=False)
         if self.op_wq is not None:
@@ -928,6 +1006,10 @@ class OSDDaemon:
     # -- dispatch -----------------------------------------------------------
 
     def _dispatch(self, conn, msg) -> None:
+        if self._hb_stop.is_set():
+            # shut down: frames still arriving on its connections must
+            # not touch the store, which a restarted daemon now owns
+            return
         try:
             # privilege fence (reference OSDCap): with auth on, only
             # service-keyed peers (other daemons, the mon) may speak
@@ -1040,18 +1122,29 @@ class OSDDaemon:
                     msg.pgid, msg.tid, msg.pgid.shard))
                 self.op_tracker.unregister(stop, 0)
             elif isinstance(msg, M.MPGLogQuery):
+                # the querying primary leads the interval from here:
+                # sub-writes of any other primary are refused from now
+                # on (_stale_interval_write), so the log it is sent
+                # stays the whole of what this shard applied — unless
+                # a round of a later interval asked first
+                self._claim_peering(msg.pgid, self._peer_osd(conn),
+                                    msg.epoch)
                 slog = self._shard_log(msg.pgid)
                 from .pg_log import entry_to_wire
                 conn.send_message(M.MPGLogReply(
                     msg.pgid, msg.tid, slog.info.to_json(),
                     [entry_to_wire(e) for e in slog.log.entries]))
             elif isinstance(msg, M.MPGLogRollback):
-                removed = self._shard_log(msg.pgid).rollback_to(msg.v)
+                removed = self._shard_log(msg.pgid).rollback_to(msg.v) \
+                    if self._peering_current(msg.pgid, self._peer_osd(conn),
+                                             msg.epoch) else []
                 conn.send_message(M.MPGLogRollbackReply(
                     msg.pgid, msg.tid,
                     [M.hobj_to_json(o) for o in removed]))
             elif isinstance(msg, M.MPGActivate):
-                self._handle_activate(msg, self._peer_osd(conn))
+                if self._peering_current(msg.pgid, self._peer_osd(conn),
+                                         msg.les):
+                    self._handle_activate(msg, self._peer_osd(conn))
                 conn.send_message(M.MPGActivateReply(msg.pgid, msg.tid))
             elif isinstance(msg, (M.MPGLogReply, M.MPGLogRollbackReply,
                                   M.MPGActivateReply)):
@@ -1230,13 +1323,20 @@ class OSDDaemon:
                 shards = getattr(state.backend, "shards", None) or \
                     getattr(state.backend, "replicas", None)
                 if hasattr(shards, "acting"):
-                    if list(acting) != list(shards.acting):
+                    changed = list(acting) != list(shards.acting)
+                    if changed:
                         state.needs_peer = True
+                        state.acting_gen += 1
                         state.unclean()
                         self.pg_ledger.transition(
                             pgid, "interval_change",
                             epoch=newmap.epoch)
                     shards.acting = list(acting)
+                    if changed and state.kind == "ec":
+                        # sub-ops in flight to a holder that went down
+                        # would hold their ops (and every later op of
+                        # the PG, completion being in order) forever
+                        shards.resolve_departed()
                     if state.kind != "ec":
                         # replicated width follows the acting set
                         shards.n_replicas = len(shards.acting)
@@ -1246,6 +1346,8 @@ class OSDDaemon:
         # heartbeat-grace flaps on a loaded host would otherwise leave
         # it marked down forever (reference OSD::_committed_osd_maps
         # re-sends MOSDBoot when !osdmap->is_up(whoami))
+        if newmap.is_up(self.osd_id):
+            self.pg_ledger.note_up()
         if not self._hb_stop.is_set() and self.osd_id in newmap.osds \
                 and not newmap.is_up(self.osd_id):
             try:
@@ -2858,20 +2960,53 @@ class OSDDaemon:
     def _stale_interval_write(self, conn, msg: M.MOSDECSubOpWrite
                               ) -> bool:
         """A client write's sub-op from the primary of an interval this
-        shard has left: versioned before the shard's last activation
-        and sent by another OSD than the one that activated it
-        (reference PG::can_discard_replica_op: replicas drop ops from
-        before same_interval_since).  Refusing it is what lets the
-        activating primary's recovery listing — taken after the
-        activation — stand for everything its peers hold."""
+        shard has left: sent by another OSD than the primary whose
+        peering last asked for this shard's log, or versioned before
+        the shard's last activation and sent by another OSD than the
+        one that activated it (reference PG::can_discard_replica_op:
+        replicas drop ops from before same_interval_since).  Refusing
+        it is what lets the log a peering collects, and the activating
+        primary's recovery listing taken after the activation, stand
+        for everything its peers hold."""
+        sender = self._peer_osd(conn)
+        peering = self._peering_by.get(msg.pgid)
+        if peering is not None and peering[0] is not None and \
+                sender is not None and sender != peering[0]:
+            return True
         by = self._activated_by.get(msg.pgid)
         if by is None:
             return False
-        sender = self._peer_osd(conn)
         if sender is None or sender == by:
             return False
         return msg.at_version.epoch < \
             self._shard_log(msg.pgid).info.last_epoch_started
+
+    def _peering_current(self, spg: spg_t, osd: int | None,
+                         epoch: int) -> bool:
+        """Whether a peering round of interval `epoch` led by `osd` may
+        act on this shard: not when another OSD's round of a LATER
+        interval has asked for the shard's log since.  An interval has
+        one primary; an older round still in flight is one whose
+        primary the map has since replaced (an interim primary's round,
+        begun before the map that gives a restarted OSD its PGs back,
+        can reach a shard after the returning primary's) — its rollback
+        and activation would undo or overwrite the newer round's."""
+        held = self._peering_by.get(spg)
+        return held is None or osd is None or held[0] == osd or \
+            epoch >= held[1]
+
+    def _claim_peering(self, spg: spg_t, osd: int | None,
+                       epoch: int) -> None:
+        """A round's log query reached this shard: its primary leads
+        the shard from here (_stale_interval_write), unless the round
+        is older than the one that holds it (_peering_current) — a late
+        query must not fence the newer primary's writes, which would
+        be refused until the next map change."""
+        if self._peering_current(spg, osd, epoch):
+            held = self._peering_by.get(spg)
+            self._peering_by[spg] = (osd, max(
+                epoch, held[1] if held is not None and held[0] == osd
+                else 0))
 
     def _handle_activate(self, msg: M.MPGActivate,
                          by: int | None) -> None:
@@ -2983,6 +3118,12 @@ class OSDDaemon:
             state = self.pgs.get(pgid)
             if state is None:
                 pool = self.osdmap.pools[pgid.pool]
+                if pgid.seed >= pool.pg_num:
+                    # merged away (or not split off yet): an op for it
+                    # retargets on the client's refreshed map
+                    raise ErasureCodeError(
+                        errno.EAGAIN, f"no pg {pgid} at pg_num "
+                                      f"{pool.pg_num}")
                 up, acting, _, primary = \
                     self.osdmap.pg_to_up_acting_osds(pgid)
                 if primary != self.osd_id:
@@ -3048,11 +3189,19 @@ class OSDDaemon:
                         "peering" if state.kind == "ec"
                         else "reconcile",
                         epoch=self.osdmap.epoch)
+                    gen = state.acting_gen
                     with self.pg_ledger.stage(pgid, "peering"):
                         ok = self._peer_pg(pgid, state) \
                             if state.kind == "ec" else \
                             self._reconcile_replicated_pg(pgid, state)
-                    state.needs_peer = not ok
+                    with self.pg_lock:
+                        # the acting set changed during the round: the
+                        # round peered an interval that has ended, and
+                        # the new one must peer again
+                        ok = ok and state.acting_gen == gen
+                        state.needs_peer = not ok
+                    if not ok:
+                        self._repeer_later(pgid)
                     self.pg_ledger.transition(
                         pgid, "active" if ok else "peering_incomplete",
                         epoch=self.osdmap.epoch)
@@ -3064,13 +3213,34 @@ class OSDDaemon:
                     f"pg {pgid} peering incomplete; retry")
         return state
 
+    def _repeer_later(self, pgid: pg_t, delay: float = 1.0) -> None:
+        """A round that did not finish leaves needs_peer set; the next
+        op on the PG retries it — and so does this timer, for a PG no
+        op comes to (quiescence waits on needs_peer).  A round that
+        fails again arms the next."""
+        def again():
+            state = self.pgs.get(pgid)
+            if self._hb_stop.is_set() or state is None or \
+                    not state.needs_peer:
+                return
+            try:
+                self._get_pg(pgid)
+            except ErasureCodeError:
+                pass        # not primary any more, or failed again
+        t = threading.Timer(delay, again)
+        t.daemon = True
+        t.start()
+
     # -- peering (reference PeeringState.cc GetInfo/GetLog/Activate:
     #    collect shard logs, pick the authoritative one, reconcile) ---------
 
     def _peer_rpc(self, osd: int, spg: spg_t, msg_cls,
                   timeout: float = 5.0, **kw):
         """One synchronous peering RPC to a remote shard; None on
-        timeout/unreachable (the shard is then treated as down)."""
+        timeout/unreachable (the shard is then treated as down), and
+        None once the daemon is shut down."""
+        if self._hb_stop.is_set():
+            return None
         with self.pg_lock:
             self._raw_tid += 1
             tid = self._raw_tid
@@ -3111,17 +3281,47 @@ class OSDDaemon:
         from .pg_log import (LogEntry, PGLog, entry_from_wire,
                              entry_to_wire, pg_info_t)
         be = state.backend
-        acting = be.shards.acting
+        # the ops this primary still has in flight finish first (those
+        # waiting on a holder that went down were resolved with the
+        # map): a log collected under them could miss a sub-write
+        # that lands later.  Taking the PG's lock once orders this
+        # after any op's version-and-enqueue step: an op there now
+        # counts as in flight, and one after it sees needs_peer
+        with state.lock:
+            pass
+        if not be.wait_idle(min(10.0, be.read_timeout)):
+            self.cct.dout("osd", 2, f"peering {pgid} deferred: ops of "
+                                    f"the last interval still in flight")
+            return False
+        # the interval this round peers: its acting set and the map
+        # epoch it was read under, which the shards' new
+        # last_epoch_started is (a round the map overtook must not
+        # stamp a later interval's epoch on this one's members).  A
+        # map that moved the PG's primary away while this round waited
+        # ends it: the thread still holds the PG, this OSD no longer
+        # leads it, and its queries would fence the new primary's
+        # writes at the new primary's own epoch
+        with self.pg_lock:
+            osdmap = self.osdmap
+            epoch = osdmap.epoch
+            acting = be.shards.acting
+            ours = self.pgs.get(pgid) is state and \
+                osdmap.pg_to_up_acting_osds(pgid)[3] == self.osd_id
+        if not ours:
+            self.cct.dout("osd", 2, f"peering {pgid} deferred: this OSD "
+                                    f"no longer leads it")
+            return False
         live = {s: osd for s, osd in enumerate(acting)
-                if osd != CRUSH_ITEM_NONE and self.osdmap.is_up(osd)}
+                if osd != CRUSH_ITEM_NONE and osdmap.is_up(osd)}
         replies: dict[int, tuple] = {}   # shard -> (pg_info_t, [LogEntry])
         for s, osd in live.items():
             spg = spg_t(pgid, s)
             if osd == self.osd_id:
+                self._claim_peering(spg, self.osd_id, epoch)
                 slog = self._shard_log(spg)
                 replies[s] = (slog.info, list(slog.log.entries))
             else:
-                m = self._peer_rpc(osd, spg, M.MPGLogQuery)
+                m = self._peer_rpc(osd, spg, M.MPGLogQuery, epoch=epoch)
                 if m is not None:
                     replies[s] = (pg_info_t.from_json(m.info),
                                   [entry_from_wire(w) for w in m.entries])
@@ -3153,6 +3353,13 @@ class OSDDaemon:
                           f"peering {pgid}: auth_head={auth_head} "
                           f"current={sorted(current)} "
                           f"live={sorted(replies)}")
+        if list(be.shards.acting) != list(acting) or \
+                self._hb_stop.is_set():
+            # the interval changed during the round, or this daemon
+            # was shut down under it (a restarted one owns its store)
+            self.cct.dout("osd", 2, f"peering {pgid} deferred: the "
+                                    f"interval changed during the round")
+            return False
         # 4: divergent rollback
         removed: list[hobject_t] = []
         for s, (info, _) in replies.items():
@@ -3160,24 +3367,27 @@ class OSDDaemon:
                 continue
             spg = spg_t(pgid, s)
             if live[s] == self.osd_id:
-                removed.extend(self._shard_log(spg).rollback_to(auth_head))
+                if self._peering_current(spg, self.osd_id, epoch):
+                    removed.extend(
+                        self._shard_log(spg).rollback_to(auth_head))
             else:
                 m = self._peer_rpc(live[s], spg, M.MPGLogRollback,
-                                   v=auth_head)
+                                   v=auth_head, epoch=epoch)
                 if m is not None:
                     removed.extend(M.hobj_from_json(j)
                                    for j in m.removed)
         # 5: activate (stale shards adopt the authoritative log)
-        les_new = self.osdmap.epoch
+        les_new = epoch
         wire_auth = [entry_to_wire(e) for e in auth_entries]
         activated = True
         for s in replies:
             spg = spg_t(pgid, s)
             adopt = s not in current
             if live[s] == self.osd_id:
-                self._handle_activate(M.MPGActivate(
-                    spg, 0, les_new, auth_head, wire_auth, adopt),
-                    self.osd_id)
+                if self._peering_current(spg, self.osd_id, les_new):
+                    self._handle_activate(M.MPGActivate(
+                        spg, 0, les_new, auth_head, wire_auth, adopt),
+                        self.osd_id)
             elif self._peer_rpc(live[s], spg, M.MPGActivate,
                                 les=les_new, head=auth_head,
                                 entries=wire_auth, adopt=adopt) is None:
@@ -3445,10 +3655,10 @@ class OSDDaemon:
                     vtrunc = off + ln
             elif name == "writefull":
                 _, ln = op
-                txn.write(msg.oid, 0,
-                          np.frombuffer(msg.data[data_off:data_off + ln],
-                                        dtype=np.uint8))
-                txn.truncate(msg.oid, ln)  # clip any previous tail
+                # the object becomes these bytes (any previous tail
+                # clipped; on an EC pool its crcs start again)
+                txn.write_full(msg.oid, np.frombuffer(
+                    msg.data[data_off:data_off + ln], dtype=np.uint8))
                 data_off += ln
                 vsize, vexists, vtrunc = ln, True, ln
             elif name == "truncate":
@@ -3709,16 +3919,27 @@ class OSDDaemon:
             # version entering the FIFO pipeline first would commit out
             # of order and violate the PG log's monotonicity.  The
             # blocking metadata prefetch runs BEFORE the lock.
-            staged = be.make_op(txn, done.set, top=top) \
-                if state.kind == "ec" else None
-            with state.lock:
-                version = state.next_version(self.osdmap.epoch)
-                top.set_info("version", str(version))
-                spans.end(getattr(msg, "prep_span", None))
-                if staged is not None:
-                    be.enqueue(staged, version)
-                else:
-                    be.submit_transaction(txn, version, done.set)
+            while True:
+                staged = be.make_op(txn, done.set, top=top) \
+                    if state.kind == "ec" else None
+                with state.lock:
+                    # an interval change since _get_pg: the op must not
+                    # enter the pipeline until the PG has peered again
+                    # (a peering collects shard logs once the ops that
+                    # entered before it have finished, _peer_pg)
+                    if not state.needs_peer:
+                        version = state.next_version(self.osdmap.epoch)
+                        top.set_info("version", str(version))
+                        spans.end(getattr(msg, "prep_span", None))
+                        if staged is not None:
+                            be.enqueue(staged, version)
+                        else:
+                            be.submit_transaction(txn, version, done.set)
+                        break
+                if self._get_pg(msg.pgid.pgid) is not state:
+                    raise ErasureCodeError(
+                        errno.EAGAIN, f"pg {msg.pgid.pgid} moved while "
+                                      f"the op waited for its peering")
             if not done.wait(30):
                 result = -errno.ETIMEDOUT
                 top.mark_event("timeout")
@@ -4065,6 +4286,7 @@ class OSDDaemon:
         if not getattr(queue, "_perf_registered", False):
             queue._perf_registered = True
             self.cct.perf.add(queue.perf)
+            self._host_sets.append(queue)
         return queue
 
     def _asok_launch_queue_status(self, cmd: dict) -> dict:

@@ -46,12 +46,13 @@ from typing import Callable
 
 import numpy as np
 
+from ..common import spans
 from ..common.spans import span
 from ..common.tracked_op import NULL_TRACKED
 from ..ec.interface import ErasureCodeError, ErasureCodeInterface
 from ..ops.profiler import device_profiler
-from ..parallel.launch_queue import (DECODE_MAX_LAUNCH_W, ECLaunchQueue,
-                                     _codec_label)
+from ..parallel.launch_queue import (ECLaunchQueue, _codec_label,
+                                     recovery_decode_cap)
 from ..store.object_store import ObjectStore, Transaction
 from . import ec_transaction as ect
 from . import ec_util
@@ -352,6 +353,13 @@ def _build_ec_perf(name: str):
                              "survivor/helper bytes read for repair")
             .add_u64_counter("ec_repair_reconstructed_bytes",
                              "shard bytes rebuilt by repair decodes")
+            .add_u64_counter("ec_recovery_decode_launches",
+                             "decode submissions of grouped recovery "
+                             "(one a slice of a geometry group) handed "
+                             "to the launch queue")
+            .add_u64_counter("ec_recovery_decode_bytes",
+                             "survivor bytes those submissions carried "
+                             "(k rows x their width)")
             .add_u64_counter("ec_clay_repairs",
                              "objects repaired from repair-plane reads "
                              "(bandwidth-optimal CLAY path)")
@@ -457,6 +465,9 @@ class ECBackend:
         self.waiting_state: list[ECOp] = []
         self.waiting_reads: list[ECOp] = []
         self.waiting_commit: list[ECOp] = []
+        # ops entered (enqueue) and not yet acked: wherever they are,
+        # reading, on the device or committing (wait_idle)
+        self._open_ops = 0
         self.completed: int = 0
         self.batched_launches: int = 0
         self.batched_extents: int = 0
@@ -680,6 +691,22 @@ class ECBackend:
         self._flush_timer = t
         t.start()
 
+    def wait_idle(self, timeout: float) -> bool:
+        """Block until no op of this PG is in flight — none waiting
+        for its state, its reads, the device or its commits — or
+        `timeout` passes (False).  Peering waits here so that the shard
+        logs it collects hold every sub-write its own ops will ever
+        send in the interval that ended."""
+        deadline = time.monotonic() + timeout
+        while True:
+            with self.lock:
+                busy = self._open_ops > 0 or bool(self._inflight)
+            if not busy:
+                return True
+            if time.monotonic() >= deadline:
+                return False
+            time.sleep(0.005)
+
     def inflight_ops(self) -> list[ECOp]:
         """Ops submitted to the device pipeline, not yet committing
         (for dump_ops_in_flight)."""
@@ -741,6 +768,7 @@ class ECBackend:
         with this call (versions must enter the FIFO in order)."""
         op.version = version
         with self.lock:
+            self._open_ops += 1
             self.waiting_state.append(op)
             self.check_ops()
         return op
@@ -1418,7 +1446,10 @@ class ECBackend:
                 rb.extents = [
                     (self.sinfo.aligned_logical_offset_to_chunk_offset(
                         e.off), e.length // self.sinfo.k)
-                    for e in op.plan.will_write.get(oid, [])]
+                    for e in op.plan.will_write.get(oid, [])] \
+                    if not objop.replace else None
+                # (a write_full's hinfo is fresh: the shard re-hashes
+                # its chunk_crc whole rather than patch from it)
             # anything not a pure append keeps the old object under a
             # generation so the shard can roll it back locally
             # (reference ecbackend.rst local-rollbackability contract)
@@ -1508,6 +1539,7 @@ class ECBackend:
                     if proj["refs"] <= 0:
                         del self._projected[oid]
             self.completed += 1
+            self._open_ops -= 1
             op.on_commit()
         self.check_ops()
 
@@ -1675,12 +1707,13 @@ class ECBackend:
     # queues MANY objects missing the SAME shards, so the batch entry
     # fans out every object's survivor reads concurrently, groups the
     # results by (survivors, targets) recovery geometry, and rebuilds
-    # each group in ONE decode — a sharded collective launch on the
-    # mesh plane (survivor rows over the 'shard' axis), or a single
-    # concatenated host decode on the single-chip plane.  The
-    # reference's continue_recovery_op gathers k shards to one node
-    # and decodes per object; here the whole queue is a handful of
-    # launches.
+    # each group in as few decodes as its width allows — a sharded
+    # collective launch on the mesh plane (survivor rows over the
+    # 'shard' axis), or on the single-chip plane the launch queue's
+    # device decode of the group's objects side by side, capped at
+    # launch_queue.recovery_decode_cap.  The reference's
+    # continue_recovery_op gathers k shards to one node and decodes
+    # per object; here the whole queue is a handful of launches.
 
     def recover_shard(self, oid: hobject_t, missing: list[int],
                       push: Callable[[int, np.ndarray, HashInfo], None]
@@ -1798,6 +1831,11 @@ class ECBackend:
         results: dict[hobject_t, Exception | None] = {}
         states: list[dict] = []
         clay_states: list[dict] = []
+        # ec.recover_reads: the slice's survivor reads issued -> the
+        # replies every object waits for (k of them, or all sent)
+        reads_sp = spans.begin("ec.recover_reads",
+                               device_profiler().enabled,
+                               pgid=self.perf.name)
         # phase 1: every object's survivor reads in flight before any
         # wait (the fan-out IS the storm's concurrency).  Single-shard
         # losses of a sub-chunked plugin with a repair lowering take
@@ -1837,15 +1875,6 @@ class ECBackend:
             clay_groups.setdefault(
                 (st["lost"], st["helpers"], st["chunk_len"]),
                 []).append(st)
-        for (lost, helpers, _clen), sts in clay_groups.items():
-            try:
-                self._clay_repair_group(lost, helpers, sts, push_for)
-            except Exception as e:  # noqa: BLE001 — whole-group launch
-                for st in sts:
-                    results.setdefault(st["oid"], e)
-                continue
-            for st in sts:
-                results.setdefault(st["oid"], st.get("error"))
         # phase 2 (full): collect; drop objects that can't reach k
         # survivors (a locality code's decode judges its own)
         groups: dict[tuple, list[dict]] = {}
@@ -1865,17 +1894,30 @@ class ECBackend:
             if self.perf:
                 self.perf.inc("ec_repair_helper_bytes",
                               len(have) * st["chunk_len"])
+            # the first k replies in shard order are the decode's
+            # survivors, whichever others answered too: the geometry
+            # (and the decode's program) is the same for every object
+            # of a PG missing the same shards
             survivors = tuple(sorted(have))[: self.k]
             targets = tuple(sorted(st["missing"]))
-            erasures = tuple(s for s in range(self.n) if s not in have)
             st["survivors"] = survivors
-            groups.setdefault((survivors, targets, erasures),
-                              []).append(st)
-        # phase 3: one decode per geometry group
-        for (survivors, targets, erasures), sts in groups.items():
+            groups.setdefault((survivors, targets), []).append(st)
+        spans.end(reads_sp)
+        for (lost, helpers, _clen), sts in clay_groups.items():
             try:
-                self._decode_recovery_group(survivors, targets,
-                                            erasures, sts, push_for)
+                self._clay_repair_group(lost, helpers, sts, push_for)
+            except Exception as e:  # noqa: BLE001 — whole-group launch
+                for st in sts:
+                    results.setdefault(st["oid"], e)
+                continue
+            for st in sts:
+                results.setdefault(st["oid"], st.get("error"))
+        # phase 3: one decode per geometry group (per capped slice of
+        # it on the launch queue)
+        for (survivors, targets), sts in groups.items():
+            try:
+                self._decode_recovery_group(survivors, targets, sts,
+                                            push_for)
             except Exception as e:  # noqa: BLE001 — whole-group launch
                 for st in sts:
                     results.setdefault(st["oid"], e)
@@ -2021,17 +2063,20 @@ class ECBackend:
             except Exception as e:  # noqa: BLE001 — per-object verify
                 st["error"] = e
 
-    def _decode_recovery_group(self, survivors, targets, erasures,
+    def _decode_recovery_group(self, survivors, targets,
                                sts: list[dict], push_for) -> None:
         """Rebuild one (survivors, targets) geometry group: a single
         mesh collective launch (byte axes of all objects concatenated,
         survivor rows sharded over 'shard') when the mesh plane is up,
-        else one concatenated host decode; sub-chunked codes (CLAY)
+        else the launch queue's device decode of the group's objects
+        side by side, one submission per slice of at most
+        recovery_decode_cap summed width, rebuilding only the target
+        rows from exactly the k survivors; sub-chunked codes (CLAY)
         decode per object — their plane layout does not concatenate
         along the byte axis — and so does a code that need not decode
         from any k chunks (ALLOW_PARTIAL_DECODE: LRC, SHEC), by its own
         plan from every shard at hand.  The geometry is in shards; the
-        batched decodes work in chunk order (shard_chunk)."""
+        decodes work in chunk order (shard_chunk)."""
         if self.ec_impl.ALLOW_PARTIAL_DECODE:
             self._push_recovered(sts, [self._decode_own_plan(st, targets)
                                        for st in sts], push_for)
@@ -2059,59 +2104,68 @@ class ECBackend:
                     rebuilt_per_st.append(
                         {s: rows[i] for i, s in enumerate(targets)})
             except Exception as e:  # noqa: BLE001 — mesh died mid-storm
-                # containment: fall back to the host decode for this
-                # (and every later) group; recovery itself proceeds
+                # containment: fall back to the launch queue's decode
+                # for this (and every later) group; recovery proceeds
                 self._disable_mesh(e)
                 meshed = False
-        if not meshed:
-            erased = [chunk[s] for s in erasures]
-            if self.ec_impl.get_sub_chunk_count() == 1:
-                # one concatenated decode for the whole group, which
-                # the launch queue coalesces with OTHER PGs' repairs
-                # (and counts with the writes' occupancy)
-                # width-capped slices (DECODE_MAX_LAUNCH_W): the
-                # concatenated width, pow2-padded by the queue, stays
-                # inside the prewarm-enumerable bucket set instead of
-                # growing with the storm's queue depth
-                slices: list[list[dict]] = []
-                cur: list[dict] = []
-                cur_w = 0
-                for st in sts:
-                    w = st["chunk_len"]
-                    if cur and cur_w + w > DECODE_MAX_LAUNCH_W:
-                        slices.append(cur)
-                        cur, cur_w = [], 0
-                    cur.append(st)
-                    cur_w += w
-                if cur:
+        if not meshed and self.ec_impl.get_sub_chunk_count() == 1:
+            # slices of at most recovery_decode_cap summed width: the
+            # pow2-padded launch widths stay the few prewarm plans
+            # (launch_queue.recovery_decode_widths) instead of growing
+            # with the storm's queue depth; each slice is one
+            # submission, which the launch queue may coalesce with
+            # OTHER PGs' decodes of the same geometry
+            cap = recovery_decode_cap(max(st["chunk_len"] for st in sts))
+            slices: list[list[dict]] = []
+            cur: list[dict] = []
+            cur_w = 0
+            for st in sts:
+                w = st["chunk_len"]
+                if cur and cur_w + w > cap:
                     slices.append(cur)
-                for chunk_sts in slices:
-                    widths = [st["chunk_len"] for st in chunk_sts]
-                    big = np.zeros((self.n, sum(widths)),
-                                   dtype=np.uint8)
-                    col = 0
-                    for st, w in zip(chunk_sts, widths):
-                        for s, d in st["have"].items():
-                            big[chunk[s], col:col + w] = d
-                        col += w
-                    dec = np.asarray(self._launch_queue.submit_decode(
-                        self.ec_impl, big, erased,
-                        owner=id(self)).result())
-                    col = 0
-                    for st, w in zip(chunk_sts, widths):
-                        rebuilt_per_st.append(
-                            {s: dec[chunk[s], col:col + w]
-                             for s in targets})
-                        col += w
-            else:
-                for st in sts:
-                    dense = np.zeros((self.n, st["chunk_len"]),
-                                     dtype=np.uint8)
-                    for s, d in st["have"].items():
-                        dense[chunk[s]] = d
-                    dec = self.ec_impl.decode_chunks(dense, erased)
-                    rebuilt_per_st.append({s: dec[chunk[s]]
-                                           for s in targets})
+                    cur, cur_w = [], 0
+                cur.append(st)
+                cur_w += w
+            if cur:
+                slices.append(cur)
+            rows = [chunk[s] for s in survivors]
+            erased = [chunk[s] for s in targets]
+            for chunk_sts in slices:
+                widths = [st["chunk_len"] for st in chunk_sts]
+                big = np.zeros((self.n, sum(widths)), dtype=np.uint8)
+                col = 0
+                for st, w in zip(chunk_sts, widths):
+                    for s in survivors:
+                        big[chunk[s], col:col + w] = st["have"][s]
+                    col += w
+                ticket = self._launch_queue.submit_decode(
+                    self.ec_impl, big, erased, owner=id(self),
+                    survivors=rows)
+                if self.perf:
+                    self.perf.inc("ec_recovery_decode_launches")
+                    self.perf.inc("ec_recovery_decode_bytes",
+                                  self.k * big.shape[1])
+                with span("ec.recover_decode_wait",
+                          device_profiler().enabled,
+                          pgid=self.perf.name):
+                    dec = np.asarray(ticket.result())
+                col = 0
+                for st, w in zip(chunk_sts, widths):
+                    rebuilt_per_st.append(
+                        {s: dec[chunk[s], col:col + w]
+                         for s in targets})
+                    col += w
+        elif not meshed:
+            for st in sts:
+                dense = np.zeros((self.n, st["chunk_len"]),
+                                 dtype=np.uint8)
+                for s, d in st["have"].items():
+                    dense[chunk[s]] = d
+                erased = [chunk[s] for s in range(self.n)
+                          if s not in st["have"]]
+                dec = self.ec_impl.decode_chunks(dense, erased)
+                rebuilt_per_st.append({s: dec[chunk[s]]
+                                       for s in targets})
         self._push_recovered(sts, rebuilt_per_st, push_for)
 
     def _decode_own_plan(self, st: dict, targets) -> dict:
@@ -2126,18 +2180,22 @@ class ECBackend:
 
     def _push_recovered(self, sts: list[dict], rebuilt_per_st: list,
                         push_for) -> None:
-        """Verify each rebuilt shard against its hinfo and push it."""
-        for st, rebuilt in zip(sts, rebuilt_per_st):
-            if "error" in st:
-                continue
-            try:
-                push = push_for(st["oid"])
-                for s in st["missing"]:
-                    data = rebuilt[s]
-                    self._verify_recovered(st, s, data)
-                    push(s, data, st["hinfo"])
-                    if self.perf:
-                        self.perf.inc("ec_repair_reconstructed_bytes",
-                                      int(np.asarray(data).size))
-            except Exception as e:  # noqa: BLE001 — per-object verify
-                st["error"] = e
+        """Verify each rebuilt shard against its hinfo and push it
+        (span ec.recover_push: the group's verifies and pushes)."""
+        with span("ec.recover_push", device_profiler().enabled,
+                  pgid=self.perf.name):
+            for st, rebuilt in zip(sts, rebuilt_per_st):
+                if "error" in st:
+                    continue
+                try:
+                    push = push_for(st["oid"])
+                    for s in st["missing"]:
+                        data = rebuilt[s]
+                        self._verify_recovered(st, s, data)
+                        push(s, data, st["hinfo"])
+                        if self.perf:
+                            self.perf.inc(
+                                "ec_repair_reconstructed_bytes",
+                                int(np.asarray(data).size))
+                except Exception as e:  # noqa: BLE001 — per-object
+                    st["error"] = e      # verify

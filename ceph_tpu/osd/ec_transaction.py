@@ -51,6 +51,11 @@ class PGObjectOp:
     writes: list[PGWrite] = field(default_factory=list)
     truncate_to: int | None = None
     delete: bool = False
+    # the writes replace the object whole (write_full): its old bytes
+    # are neither read nor kept under the new ones, and its crcs start
+    # again from the first byte — reference do_osd_ops WRITEFULL on a
+    # rollback (EC) pool truncates to 0 before it writes.  Attrs stay
+    replace: bool = False
     attrs: dict[str, bytes | None] = field(default_factory=dict)
     # omap (replicated pools only — the reference rejects omap on EC
     # pools via the SUPPORTS_OMAP pool flag, and so does the OSD op
@@ -71,6 +76,14 @@ class PGTransaction:
 
     def truncate(self, oid: hobject_t, size: int) -> None:
         self.obj(oid).truncate_to = size
+
+    def write_full(self, oid: hobject_t, data) -> None:
+        """The object becomes `data` (PGObjectOp.replace); what was
+        staged for it before in this op vector goes, attrs excepted."""
+        op = self.obj(oid)
+        op.writes = [PGWrite(0, data)]
+        op.truncate_to = op.writes[0].data.size
+        op.replace = True
 
     def delete(self, oid: hobject_t) -> None:
         # delete supersedes anything staged before it in this op
@@ -146,6 +159,17 @@ def get_write_plan(sinfo: StripeInfo, txn: PGTransaction,
         plan.hash_infos[oid] = get_hinfo(oid)
         if op.delete and not op.writes:
             continue
+        if op.replace and not op.delete:
+            # write_full: no pre-reads and fresh crcs, as after a
+            # delete; plan.sizes keeps the old size (the commit keeps
+            # the old object as a generation for rollback)
+            size = 0
+            if reset_hinfo is not None:
+                plan.hash_infos[oid] = reset_hinfo(oid)
+            else:
+                old = plan.hash_infos[oid]
+                plan.hash_infos[oid] = HashInfo.make(
+                    len(old.cumulative_shard_hashes))
         if op.delete:
             # delete-then-recreate in one vector (reference do_osd_ops
             # evolves obs through the vector; the replicated backend's
@@ -248,6 +272,11 @@ def generate_transactions(
             for s in range(n_shards):
                 txns[s].clone(shard_oid(oid, s),
                               shard_oid(oid, s, generation=gen))
+        if op.replace and not op.delete and plan.sizes.get(oid, 0) > 0:
+            # write_full over an existing object: empty it first, so
+            # its writes below append from 0 onto fresh crcs
+            for s in range(n_shards):
+                txns[s].truncate(shard_oid(oid, s), 0)
         hinfo = plan.hash_infos[oid]
         for ext in plan.will_write.get(oid, []):
             shards = encoded[(oid, ext.off)]

@@ -70,6 +70,16 @@ def _build_ledger_perf(name: str):
                           "below full redundancy (>= min_size)")
          .add_gauge("pg_degraded_open_windows",
                     "PGs currently inside an open degraded window")
+         .add_gauge("pg_last_clean_at",
+                    "unix time of this OSD's latest PG transition to "
+                    "clean (a recovery pass that left every acting "
+                    "shard holding every object; 0 = none yet)")
+         .add_gauge("osd_boot_at",
+                    "unix time this daemon's boot began (a restart: "
+                    "the revived daemon's own ledger)")
+         .add_gauge("osd_up_at",
+                    "unix time this daemon first saw itself up in the "
+                    "osdmap after boot (0 = not yet)")
          .add_histogram("lat_peering_total",
                         "wall seconds of one peering/reconcile round",
                         buckets=CONTROL_LAT_BUCKETS)
@@ -191,6 +201,7 @@ class PGLedger:
         self._pgs: dict = {}          # pg_t -> _PGRecord
         self._seq = 0                 # daemon-wide monotonic sequence
         self._t0 = time.time()
+        self._up_noted = False
 
     # -- record access ------------------------------------------------------
 
@@ -225,6 +236,19 @@ class PGLedger:
         if epoch:
             rec.epoch = epoch
         self.perf.inc("pg_transitions")
+        if state == "clean":
+            self.perf.set("pg_last_clean_at", now)
+
+    def note_boot(self) -> None:
+        """This daemon's boot began (`osd_boot_at`)."""
+        self.perf.set("osd_boot_at", time.time())
+
+    def note_up(self) -> None:
+        """This daemon saw itself up in the osdmap for the first time
+        since its boot (`osd_up_at`; later flaps leave it)."""
+        if not self._up_noted:
+            self._up_noted = True
+            self.perf.set("osd_up_at", time.time())
 
     def stage(self, pgid, name: str):
         """Context manager timing one recovery stage (STAGES) for one

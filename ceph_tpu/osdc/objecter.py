@@ -153,6 +153,7 @@ class Objecter:
                 self.osdmap = newmap
             self._map_nudge_pending = False
             self.map_event.set()
+            self._kick_moved()
         elif isinstance(msg, M.MOSDMapInc):
             # incremental publish / keepalive ack: apply the delta
             # chain like the OSD does; a gap (or a keepalive claiming
@@ -168,6 +169,7 @@ class Objecter:
             self.osdmap = m
             self._map_nudge_pending = False
             self.map_event.set()
+            self._kick_moved()
         elif isinstance(msg, M.MOSDOpReply):
             with self._lock:
                 w = self._waiters.pop(msg.tid, None)
@@ -293,7 +295,8 @@ class Objecter:
             with self._lock:
                 self._tid += 1
                 tid = self._tid
-                w = {"event": threading.Event(), "reply": None}
+                w = {"event": threading.Event(), "reply": None,
+                     "target": (pool_id, name, tgt)}
                 self._waiters[tid] = w
             conn = self.messenger.connect(tuple(info.addr))
             conn.send_message(M.MOSDOp(spg, oid, ops, data, tid,
@@ -306,6 +309,11 @@ class Objecter:
             sends += 1
             if w["event"].wait(timeout):
                 reply = w["reply"]
+                if reply is None:
+                    # a new map moved the op's target (_kick_moved):
+                    # send it again, to the new primary, at once
+                    top.mark_event("retarget")
+                    continue
                 if reply.sent_ts is not None:
                     self.perf.hinc("lat_reply_leg", max(
                         0.0, time.time() - reply.sent_ts))
@@ -360,6 +368,28 @@ class Objecter:
         self.op_tracker.unregister(top, last_err)
         raise TimedOut(f"op {name} failed after {attempts} attempts "
                        f"(last {last_err})")
+
+    def _kick_moved(self) -> None:
+        """A new map is in: every op waiting on a primary this map no
+        longer names for its PG is sent again to the one it does
+        (reference Objecter::_scan_requests -> _send_op on a changed
+        target), instead of waiting out its attempt on an OSD that is
+        down or no longer leads the PG."""
+        with self._lock:
+            waiting = [(tid, w) for tid, w in self._waiters.items()
+                       if "target" in w]
+        for tid, w in waiting:
+            pool_id, name, tgt = w["target"]
+            try:
+                now = self._calc_target(pool_id, name)
+            except Exception:  # noqa: BLE001 — pool gone: the attempt
+                continue       # times out as before
+            if now == tgt:
+                continue
+            with self._lock:
+                if self._waiters.pop(tid, None) is None:
+                    continue     # answered meanwhile
+            w["event"].set()
 
     # -- watch/notify -------------------------------------------------------
 

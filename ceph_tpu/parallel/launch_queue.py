@@ -73,6 +73,48 @@ from ..ops.profiler import device_profiler
 # follows the object geometry, which prewarm covers separately.
 DECODE_MAX_LAUNCH_W = 65536
 
+# objects one grouped recovery decode concatenates at most where their
+# shards are wide (a 4 MiB object at k=8: 512 KiB a shard).  Under
+# DECODE_MAX_LAUNCH_W a slice is capped by width alone; above it by
+# this count, so the launch widths of a recovery of objects of one
+# size are the few that recovery_decode_widths names and prewarm can
+# compile, and a launch carries 8 x 2 MiB of survivors at most.
+RECOVERY_DECODE_MAX_OBJECTS = 4
+
+
+def recovery_decode_cap(chunk_len: int) -> int:
+    """Summed width up to which a recovery group's objects whose
+    shards are `chunk_len` bytes concatenate into one decode."""
+    return max(DECODE_MAX_LAUNCH_W,
+               RECOVERY_DECODE_MAX_OBJECTS * int(chunk_len))
+
+
+def recovery_decode_widths(chunk_len: int) -> list[int]:
+    """Every launch width (pow2-padded, as _do_launch pads) of a
+    grouped recovery decode of objects whose shards are `chunk_len`
+    bytes: 1 .. cap / chunk_len of them side by side."""
+    chunk_len = int(chunk_len)
+    most = max(1, recovery_decode_cap(chunk_len) // chunk_len)
+    return sorted({next_pow2(j * chunk_len) for j in range(1, most + 1)})
+
+
+def run_decode(plugin, dense: np.ndarray, erasures, survivors=None):
+    """One decode of a (k+m, W) dense array.  Without `survivors` the
+    plugin's `decode_chunks(dense, erasures)`: every erased row
+    rebuilt from the first k others.  With them (a recovery that chose
+    its k survivor rows), only the `erasures` rows are rebuilt, from
+    exactly those: the plugin's `decode_chunks_from` where it has one
+    (a decode's program then depends on how many rows it rebuilds,
+    not on how many shards happened to answer), else `decode_chunks`
+    with every row outside the survivors erased."""
+    if survivors is not None:
+        own = getattr(plugin, "decode_chunks_from", None)
+        if own is not None:
+            return own(dense, survivors, erasures)
+        erasures = [i for i in range(plugin.get_chunk_count())
+                    if i not in set(survivors)]
+    return plugin.decode_chunks(dense, list(erasures))
+
 
 def _codec_label(plugin) -> str:
     """Short human codec tag for the flight recorder (the full
@@ -434,19 +476,25 @@ class ECLaunchQueue:
             traces=traces)
 
     def submit_decode(self, plugin, dense: np.ndarray, erasures,
-                      owner=None, traces=()) -> LaunchTicket:
+                      owner=None, traces=(),
+                      survivors=None) -> LaunchTicket:
         """Queue one recovery/reconstruct decode: `dense` is the
         (k+m, W) array with zeros in the erased rows.  Submissions
-        sharing (codec, erasure pattern) coalesce into one
-        `decode_chunks` launch across PGs — repair rides the same
+        sharing (codec, erasure pattern, survivors) coalesce into one
+        decode launch across PGs — repair rides the same
         launch-occupancy machinery as writes (ROADMAP item 2's named
         remainder); `result()` yields this submission's decoded
-        (k+m, W) columns."""
+        (k+m, W) columns.  `survivors` (chunk indices, k of them): the
+        rows to decode from, and then only the `erasures` rows are
+        rebuilt (run_decode)."""
         erasures = tuple(sorted(int(e) for e in erasures))
+        if survivors is not None:
+            survivors = tuple(sorted(int(s) for s in survivors))
         return self._submit(
             "d", plugin,
             [np.ascontiguousarray(dense, dtype=np.uint8)], owner,
-            key_suffix=(erasures,), extra=erasures, traces=traces)
+            key_suffix=(erasures, survivors),
+            extra=(erasures, survivors), traces=traces)
 
     def submit_clay_repair(self, plan, rows: np.ndarray,
                            owner=None, traces=()) -> LaunchTicket:
@@ -658,7 +706,7 @@ class ECLaunchQueue:
             return
         width = sum(r.shape[1] for s in subs for r in s.runs)
         plugin = subs[0].plugin
-        rows_out = len(subs[0].extra) if kind == "d" \
+        rows_out = len(subs[0].extra[0]) if kind == "d" \
             else plugin.get_coding_chunk_count()
         self.perf.dinc(key_in, plugin.get_data_chunk_count() * width)
         self.perf.dinc(key_out, rows_out * width)
@@ -748,10 +796,11 @@ class ECLaunchQueue:
                     big = np.concatenate(
                         [big, np.zeros((big.shape[0], w2 - w),
                                        dtype=np.uint8)], axis=1)
-                era = "".join(str(e) for e in subs[0].extra)
+                erasures, survivors = subs[0].extra
+                era = "".join(str(e) for e in erasures)
                 bucket = f"d:e{era}:w{big.shape[1]}"
-                handle = ("np", np.asarray(plugin.decode_chunks(
-                    big, list(subs[0].extra))))
+                handle = ("np", np.asarray(run_decode(
+                    plugin, big, erasures, survivors)))
                 padded = int(big.size)
                 if self.perf and getattr(plugin, "jit_backed", False):
                     # the k survivor rows in, the erased rows out (the
@@ -760,7 +809,7 @@ class ECLaunchQueue:
                         "ec_h2d_bytes",
                         plugin.get_data_chunk_count() * big.shape[1])
                     self.perf.inc("ec_d2h_bytes",
-                                  len(subs[0].extra) * big.shape[1])
+                                  len(erasures) * big.shape[1])
             else:
                 bigs = [s.runs[0] for s in subs]
                 big = np.concatenate(bigs, axis=1) if len(bigs) > 1 \
@@ -848,8 +897,8 @@ class ECLaunchQueue:
                         h = ("np", np.asarray(
                             s.plugin.apply_device(s.runs[0])))
                     elif kind == "d":
-                        h = ("np", np.asarray(s.plugin.decode_chunks(
-                            s.runs[0], list(s.extra))))
+                        h = ("np", np.asarray(run_decode(
+                            s.plugin, s.runs[0], *s.extra)))
                     elif hasattr(s.plugin, "encode_chunks_submit"):
                         h = ("h", s.plugin.encode_chunks_submit(
                             s.runs[0]))
