@@ -15,7 +15,7 @@ out_seq/in_seq, ack frames, session resume + replay on reconnect):
 - Acks ride.  An ack does nothing but trim that window, so it is not
   worth a frame: a session OWES one while in_seq > last_acked, and
   pays by writing the CTRL_ACK ahead of the next data frame to that
-  peer, in the same transport call (_write_raw) — no segment, no
+  peer, in the same transport call (_put) — no segment, no
   wake-up and no system call of its own at either end.  At the rates
   the cluster runs (a frame every ~100 ms per session) "ack when the
   pipe goes idle" was an ack frame for every frame delivered, half of
@@ -30,6 +30,18 @@ out_seq/in_seq, ack frames, session resume + replay on reconnect):
   writelines → one sendmsg when large, so a payload is never copied
   into a frame buffer).  Part by part, TCP_NODELAY made each part a
   segment and the peer's receiver woke for each.
+- One pass onto the reactor: send_message appends (conn, msg) to its
+  reactor's send queue (_SendQueue) and signals the loop only when
+  the queue was empty — one call_soon_threadsafe for a whole fan-out,
+  a plain call_soon from the loop's own thread.  The queue's flush,
+  ONE callback, writes every frame whose session can take it now in
+  place (Connection._send_now: seq, encode, retention, the write — no
+  Task, no await); the rest go to the coroutine Connection._send
+  (dial and replay, back-pressure through drain(), fault injection),
+  and while a session has frames there its later frames follow them,
+  so per-session order and exactly-once delivery are _send's.  A
+  Task per frame started one loop pass after the callback that made
+  it, so every frame waited two passes to reach its wire.
 - One read per burst of small frames, and a large body in place
   (FrameReceiver, an asyncio.BufferedProtocol under every connection:
   the transport `recv_into`s the buffer it is handed, no StreamReader
@@ -86,6 +98,7 @@ import random
 import struct
 import threading
 import time
+import traceback
 import uuid
 from concurrent.futures import TimeoutError as _FuturesTimeout
 from typing import Callable
@@ -444,6 +457,9 @@ class Session:
         self.reader: FrameReceiver | None = None
         self.writer: asyncio.StreamWriter | None = None
         self.send_lock = asyncio.Lock()
+        # frames handed to the coroutine send path and not written
+        # yet (their Tasks): while any is, later frames follow them
+        self.slow_sends: set[asyncio.Task] = set()
         self.broken = False
         self.down_since: float | None = None
         self.last_acked = 0       # highest seq we have acked to the peer
@@ -653,55 +669,120 @@ class Connection:
         # trip"): read on the caller's thread, before the frame has
         # the seq that decides whether it is sampled
         led = m.ledger
-        m._run_soon(self._send(msg, led.now_ns() if led.enabled else 0))
+        m._sendq.put(self, msg, led.now_ns() if led.enabled else 0)
 
-    async def _send(self, msg: Message, t_call: int = 0) -> None:
+    def _take_queued(self, msg: Message, t_call: int) -> None:
+        """On the reactor, from its send queue's flush (module doc,
+        "one pass onto the reactor"): write `msg` in place if the
+        session can take it now, else hand it to `_send` behind the
+        session's frames already there."""
         sess = self.session
         m = self.messenger
-        # `t_call` nonzero: the ledger is on.  Two more readings every
-        # frame pays, because seq is assigned under the lock
-        now_ns = m.ledger.now_ns
-        t_in = now_ns() if t_call else 0
+        led = m.ledger
+        # the end of `hop`: the queue has reached the frame
+        t_in = led.now_ns() if t_call else 0
+        writer = sess.writer
+        if writer is not None and not sess.slow_sends and \
+                not sess.broken and not sess.send_lock.locked() and \
+                not writer.transport.is_closing() and \
+                not sess.reader._paused and \
+                len(sess.unacked) < UNACKED_HARD_CAP and \
+                not (m.inject_socket_failures or m.inject_delay_prob or
+                     m.inject_dispatch_stall):
+            if led.enabled:
+                led.note_send_path(inline=True)
+            self._send_now(msg, t_call, t_in)
+            return
+        if led.enabled:
+            led.note_send_path(inline=False)
+        task = m._loop.create_task(self._send(msg, t_call, t_in))
+        sess.slow_sends.add(task)
+        task.add_done_callback(sess.slow_sends.discard)
+
+    def _send_now(self, msg: Message, t_call: int, t_in: int) -> None:
+        """`_send` with nothing to wait for (`_take_queued` checked
+        it): the send lock is free and not taken, so `sendlock` is 0;
+        the transport is not paused, so `drain()` would return at
+        once (a write that pauses it sends the session's next frame
+        to `_send`, whose `drain()` waits)."""
+        sess = self.session
+        raw, tx = self._seal(msg, t_call, t_in, t_in)
+        try:
+            t_w = self._put(sess.writer, raw, type(msg).__name__, tx)
+        except (ConnectionError, OSError) as e:
+            # retained: the read loop's re-dial (or the peer's, on an
+            # accepted wire) replays it
+            self.last_error = str(e)
+            sess.drop_wire()
+            return
+        if t_w:
+            self.messenger.ledger.frame_drained(tx, t_w)
+        self._note_sent(msg, raw)
+
+    def _seal(self, msg: Message, t_call: int, t_in: int,
+              t_lock: int) -> tuple | None:
+        """Give `msg` the session's next seq, encode and retain it —
+        under the send lock, or with the lock free and no await
+        before the write.  Returns (raw, the stamps of a sampled
+        frame or None), or None: the frame is dropped (a broken
+        session this side cannot re-dial)."""
+        sess = self.session
+        if sess.broken:
+            if not self.can_reconnect:
+                # accepted side cannot dial; the peer's next
+                # reconnect gets a fresh session (see _on_accept)
+                return None
+            sess.reset_epoch()
+        sess.out_seq += 1
+        tx = None
+        if t_call and frame_sampled(sess.out_seq, sess.sample_off):
+            tx = FrameTx(
+                type(msg).__name__,
+                (sess.nonce, self.can_reconnect, sess.out_seq),
+                t_call, t_in, t_lock)
+        # trace-only (common/spans.py: the reactors' CPU is
+        # accounted by thread) and never across an await, so
+        # msgr.send is two rows a frame — encode here, socket
+        # write in _put; type and seq join them to the
+        # receiver's msgr.decode / msgr.dispatch rows
+        row = spans.annotation(
+            "msgr.send", type=type(msg).__name__,
+            seq=sess.out_seq) \
+            if spans.tracing_now else None
+        try:
+            raw = msg.encode_parts(sess.out_seq)
+            sess.record_out(sess.out_seq, raw)
+        finally:
+            if row is not None:
+                row.__exit__(None, None, None)
+        if tx is not None:
+            tx.t_enc = self.messenger.ledger.now_ns()
+        if sess.broken:       # overflow tripped by this very frame
+            if not self.can_reconnect:
+                return None
+            sess.reset_epoch()          # carry this frame into the
+            sess.out_seq = 1            # fresh epoch
+            raw = msg.encode_parts(1)
+            sess.record_out(1, raw)
+            tx = None                   # (its trip is not timed)
+        return raw, tx
+
+    async def _send(self, msg: Message, t_call: int = 0,
+                    t_in: int = 0) -> None:
+        """The coroutine send path: a frame the send queue could not
+        write in place (no wire yet, the lock held, a paused
+        transport, fault injection, or an earlier frame of the
+        session still here)."""
+        sess = self.session
+        m = self.messenger
+        # `t_call` nonzero: the ledger is on, and `t_in` was read
+        # where the send queue reached the frame
         async with sess.send_lock:
-            t_lock = now_ns() if t_call else 0
-            if sess.broken:
-                if not self.can_reconnect:
-                    # accepted side cannot dial; the peer's next
-                    # reconnect gets a fresh session (see _on_accept)
-                    return
-                sess.reset_epoch()
-            sess.out_seq += 1
-            tx = None
-            if t_call and frame_sampled(sess.out_seq, sess.sample_off):
-                tx = FrameTx(
-                    type(msg).__name__,
-                    (sess.nonce, self.can_reconnect, sess.out_seq),
-                    t_call, t_in, t_lock)
-            # trace-only (common/spans.py: the reactors' CPU is
-            # accounted by thread) and never across an await, so
-            # msgr.send is two rows a frame — encode here, socket
-            # write in _write_raw; type and seq join them to the
-            # receiver's msgr.decode / msgr.dispatch rows
-            row = spans.annotation(
-                "msgr.send", type=type(msg).__name__,
-                seq=sess.out_seq) \
-                if spans.tracing_now else None
-            try:
-                raw = msg.encode_parts(sess.out_seq)
-                sess.record_out(sess.out_seq, raw)
-            finally:
-                if row is not None:
-                    row.__exit__(None, None, None)
-            if tx is not None:
-                tx.t_enc = now_ns()
-            if sess.broken:       # overflow tripped by this very frame
-                if not self.can_reconnect:
-                    return
-                sess.reset_epoch()          # carry this frame into the
-                sess.out_seq = 1            # fresh epoch
-                raw = msg.encode_parts(1)
-                sess.record_out(1, raw)
-                tx = None                   # (its trip is not timed)
+            sealed = self._seal(msg, t_call, t_in,
+                                m.ledger.now_ns() if t_call else 0)
+            if sealed is None:
+                return
+            raw, tx = sealed
             if m.inject_dispatch_stall > 0:
                 # fault injection (conf ms_inject_dispatch_stall): the
                 # assembled frame sits in the send queue while the
@@ -758,11 +839,12 @@ class Connection:
 
     async def _write_raw(self, raw: bytes, mtype: str,
                          tx: FrameTx | None = None) -> None:
-        """Single choke point for outgoing bytes: fault injection hooks
-        live here (reference ms_inject_socket_failures / ms_inject_delay
-        applied in AsyncConnection::write).  `mtype`: the message's
-        type, for the trace row and the by-type frame count; `tx`: the
-        stamps of a sampled frame, whose `write` phase ends here."""
+        """The coroutine path's write: fault injection hooks live here
+        (reference ms_inject_socket_failures / ms_inject_delay applied
+        in AsyncConnection::write), then `_put`, then `drain()` with
+        the send lock held.  `mtype`: the message's type, for the
+        trace row and the by-type frame count; `tx`: the stamps of a
+        sampled frame, whose `write` phase ends in `_put`."""
         m = self.messenger
         if m.inject_delay_prob > 0 and \
                 m._inject_rng.random() < m.inject_delay_prob:
@@ -777,8 +859,21 @@ class Connection:
             # wire dropped while we slept in the injected delay (the
             # accepted-conn read loop nulls it without the send lock)
             raise ConnectionResetError("wire dropped during delayed write")
+        t_w = self._put(writer, raw, mtype, tx)
+        await writer.drain()
+        if t_w:
+            m.ledger.frame_drained(tx, t_w)
+
+    def _put(self, writer: asyncio.StreamWriter, raw, mtype: str,
+             tx: FrameTx | None) -> int:
+        """Single choke point for outgoing data frames: hand one
+        sealed frame, and the ack the session owes ahead of it, to
+        the transport in one call.  Returns the write-end stamp of a
+        sampled frame (where `lat_frame_drain` starts), else 0."""
+        m = self.messenger
         led = m.ledger
         sess = self.session
+        t_w = 0
         row = spans.annotation("msgr.send", type=mtype,
                                seq=sess.out_seq) \
             if spans.tracing_now else None
@@ -807,16 +902,15 @@ class Connection:
             else:
                 bufs = parts if ack is None else (ack, *parts)
             _write_once(writer, bufs)
-            t_w = led.frame_sent(tx) if tx is not None else 0
+            if tx is not None:
+                t_w = led.frame_sent(tx)
             if led.enabled:
                 led.note_wire(1, (mtype,),
                               rode=0 if ack is None else 1)
         finally:
             if row is not None:
                 row.__exit__(None, None, None)
-        await writer.drain()
-        if t_w:
-            led.frame_drained(tx, t_w)
+        return t_w
 
     async def _connect(self) -> None:
         """Open the TCP stream and run the HELLO exchange: send our
@@ -931,8 +1025,8 @@ class Connection:
 
     def _owe_ack(self, nbytes: int) -> None:
         """A frame of `nbytes` payload was delivered on a lossless
-        session.  Its ack waits for the next frame _send writes to
-        this peer (_write_raw) and is a frame of its own only at the
+        session.  Its ack waits for the next data frame written to
+        this peer (_put) and is a frame of its own only at the
         ACK_EVERY_* limits or after ACK_DELAY_S."""
         sess = self.session
         sess.owed_bytes += nbytes
@@ -977,12 +1071,58 @@ class Connection:
         self.messenger._run_soon(self._close())
 
 
+class _SendQueue:
+    """One reactor loop's frames between `send_message` and the wire
+    (module doc, "one pass onto the reactor").  Any thread appends
+    (conn, msg, t_call); the append that finds no flush pending
+    signals the loop — through its self-pipe from another thread, a
+    plain call_soon from the loop's own — and `flush`, one callback,
+    takes the frames queued by then in order."""
+
+    def __init__(self, loop: asyncio.AbstractEventLoop):
+        self.loop = loop
+        self.thread_id: int | None = None   # the loop's, once it runs
+        self.frames: collections.deque[tuple] = collections.deque()
+        # a flush is scheduled and has not started: appends need not
+        # signal.  Two threads may both find it false and both signal;
+        # the second flush then finds nothing to take
+        self.armed = False
+
+    def put(self, conn: Connection, msg: Message, t_call: int) -> None:
+        self.frames.append((conn, msg, t_call))
+        if self.armed:
+            return
+        self.armed = True
+        if threading.get_ident() == self.thread_id:
+            self.loop.call_soon(self.flush)
+            return
+        self.loop.call_soon_threadsafe(self.flush)
+        led = conn.messenger.ledger
+        if led.enabled:
+            led.note_send_wake()
+
+    def flush(self) -> None:
+        # disarmed BEFORE the first take: an append from here on
+        # either is taken below or signals a flush of its own
+        self.armed = False
+        frames = self.frames
+        # only what is queued now: appends from other threads while
+        # this runs wait for their own flush, not hold the loop
+        for _ in range(len(frames)):
+            conn, msg, t_call = frames.popleft()
+            try:
+                conn._take_queued(msg, t_call)
+            except Exception:  # noqa: BLE001 - one frame, not the queue
+                traceback.print_exc()
+
+
 class Messenger:
     """Owns the reactor; binds servers; creates client connections
     (reference Messenger::create + bind + add_dispatcher_head)."""
 
     _loops: list[asyncio.AbstractEventLoop] = []
     _loop_threads: list[threading.Thread] = []
+    _send_queues: list[_SendQueue] = []
     _executor = None
     _next_loop = 0
     _loop_lock = threading.Lock()
@@ -1058,6 +1198,7 @@ class Messenger:
         # pin this messenger to one loop of the pool for its lifetime
         self._loop = self._pick_loop()
         self.stats.reactor = self._loops.index(self._loop)
+        self._sendq = self._send_queues[self.stats.reactor]
 
     # -- reactor pool -------------------------------------------------------
 
@@ -1067,6 +1208,7 @@ class Messenger:
             if not cls._loops or \
                     not all(t.is_alive() for t in cls._loop_threads):
                 cls._loops, cls._loop_threads = [], []
+                cls._send_queues = []
                 # Wide dispatcher pool, SHARED across loops: handlers may
                 # block on nested RPC round-trips (shard stat/attr fetches
                 # inside a client-op handler), so the pool must exceed the
@@ -1084,9 +1226,11 @@ class Messenger:
                     meters.append(meter)
                     loop = asyncio.SelectorEventLoop(meter)
                     loop.set_default_executor(cls._executor)
+                    sendq = _SendQueue(loop)
 
-                    def run(loop=loop, meter=meter):
+                    def run(loop=loop, meter=meter, sendq=sendq):
                         asyncio.set_event_loop(loop)
+                        sendq.thread_id = threading.get_ident()
                         meter.loop_started()
                         loop.run_forever()
 
@@ -1096,6 +1240,7 @@ class Messenger:
                     t.start()
                     cls._loops.append(loop)
                     cls._loop_threads.append(t)
+                    cls._send_queues.append(sendq)
                 # arm the per-reactor loop-lag probe on the fresh pool
                 # (wire-plane flight recorder, msg/msgr_ledger.py)
                 msgr_ledger().attach_reactors(cls._loops, meters=meters)
@@ -1154,32 +1299,14 @@ class Messenger:
         asyncio.run_coroutine_threadsafe(coro, self._loop)
 
     def send_batch(self, pairs) -> None:
-        """Send [(conn, msg), ...] with ONE loop signal for the whole
-        batch — a k-way shard fan-out otherwise pays a task creation +
-        loop wakeup per message.  Each CONNECTION still gets its own
-        task (messages to one peer stay ordered, but a dead/
-        unreachable peer must not head-of-line-block the other
-        shards' sends behind its reconnect timeouts)."""
-
-        # one `hop` start for the batch (send_message has the why)
+        """`send_message` for each (conn, msg) of `pairs`, in order,
+        with the `hop` clock read once for the batch: a k-way shard
+        fan-out joins the send queue behind one wake-up, and a peer
+        that is dead or re-dialling holds back only its own session's
+        frames (the coroutine path is per session)."""
         t_call = self.ledger.now_ns() if self.ledger.enabled else 0
-
-        async def _send_group(conn, msgs):
-            for m in msgs:
-                try:
-                    await conn._send(m, t_call)
-                except Exception:  # noqa: BLE001 - per-conn isolation
-                    import traceback
-                    traceback.print_exc()
-
-        async def _all():
-            groups: dict[int, tuple] = {}
-            for conn, msg in pairs:
-                groups.setdefault(id(conn), (conn, []))[1].append(msg)
-            for conn, msgs in groups.values():
-                asyncio.ensure_future(_send_group(conn, msgs))
-
-        self._run_soon(_all())
+        for conn, msg in pairs:
+            conn.messenger._sendq.put(conn, msg, t_call)
 
     def _run_sync(self, coro, timeout: float | None = None):
         """Blocking bridge into the reactor.  The default deadline is

@@ -137,11 +137,12 @@ def sample_offset(nonce: str) -> int:
 
 class FrameTx:
     """The sender's stamps of one sampled frame, made by
-    `Connection._send` once the frame has its seq: message type, the
+    `Connection._seal` once the frame has its seq: message type, the
     flight-table key (session nonce, sender is the connector, seq) and
-    the clock at `send_message` called, `_send` entered, send lock
-    held, encode done; `entry` is the flight-table slot that takes the
-    write-end stamp."""
+    the clock at `send_message` called, the send queue reached the
+    frame, send lock held (the same reading for a frame written in
+    place), encode done; `entry` is the flight-table slot that takes
+    the write-end stamp."""
 
     __slots__ = ("mtype", "key", "t_call", "t_in", "t_lock", "t_enc",
                  "entry")
@@ -278,6 +279,15 @@ def _ledger_perf_builder(name: str) -> PerfCountersBuilder:
          .add_u64_counter("msgr_acks_piggybacked",
                           "CTRL_ACK frames that rode a data "
                           "frame's write")
+         .add_u64_counter("msgr_sends_inline",
+                          "data frames a reactor's send-queue flush "
+                          "wrote in place (Connection._send_now)")
+         .add_u64_counter("msgr_sends_slow",
+                          "data frames the flush handed to the "
+                          "coroutine send path (Connection._send)")
+         .add_u64_counter("msgr_send_wakes",
+                          "signals to a reactor's send queue from "
+                          "another thread (call_soon_threadsafe)")
          .add_u64_counter("msgr_rx_reads",
                           "reads that brought bytes from a socket "
                           "(FrameReceiver.buffer_updated calls; every "
@@ -316,9 +326,10 @@ def _ledger_perf_builder(name: str) -> PerfCountersBuilder:
          .add_histogram("lat_msgr_dispatch",
                         "dispatch handler run time"))
     for phase, what in zip(FRAME_PHASES, (
-            "send_message called -> Connection._send entered on the "
-            "reactor",
-            "-> the session's send lock held",
+            "send_message called -> the reactor's send queue has "
+            "reached the frame",
+            "-> the session's send lock held (0 for a frame written "
+            "in place)",
             "-> encode_parts + record_out done",
             "-> the transport took the frame (_write_once returned)",
             "-> the receiver has parsed the header (in-process "
@@ -702,6 +713,15 @@ class MsgrLedger:
             inc("msgr_acks_out", acks)
         if rode:
             inc("msgr_acks_piggybacked", rode)
+
+    def note_send_path(self, inline: bool) -> None:
+        """A send queue's flush wrote a data frame in place, or handed
+        it to the coroutine path."""
+        self.perf.inc("msgr_sends_inline" if inline else "msgr_sends_slow")
+
+    def note_send_wake(self) -> None:
+        """A thread other than the loop's signalled a send queue."""
+        self.perf.inc("msgr_send_wakes")
 
     # -- socket reads (called behind the enabled gate) -----------------------
 

@@ -221,9 +221,9 @@ class MessengerShardBackend(ShardBackend):
         conn.send_message(M.MOSDECSubOpRead(spg, tid, oid, off, length))
 
     def sub_read_batch(self, reqs, on_done) -> None:
-        """Fan out [(shard, oid, off, length), ...] with ONE reactor
-        task for all remote sends; the local shard (if any) is read
-        after the remote requests are in flight."""
+        """Fan out [(shard, oid, off, length), ...] with ONE wake-up of
+        the reactor for all remote sends; the local shard (if any) is
+        read after the remote requests are in flight."""
         pairs = []
         local = []
         for shard, oid, off, length in reqs:

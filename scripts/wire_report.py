@@ -18,6 +18,10 @@ frame's trip", "Reactor loops"):
 - `reactors`: per reactor loop the seconds asleep, running, on a CPU
   and stalled (running, not on a CPU), wake-ups, and the messengers
   pinned to it;
+- `sends`: data frames the reactors' send queues wrote in place and
+  handed to the coroutine path, the share in place, and frames a
+  cross-thread wake-up (messenger.py module doc, "one pass onto the
+  reactor");
 - `locks`: the object lock's waits and holds over all OSDs;
 - `checks`: the numbers the two instruments share (`op_wire_in` of the
   op tracker against the `MOSDOp` trip; `msgr.reactor_cpu` against
@@ -80,6 +84,8 @@ def report(snaps: list, ledgers: list, pinned: dict, ops: int) -> dict:
             "busy_share": 1 - asleep / wall if wall else None,
             "sleeps": _delta(a, b, f"reactor_sleeps.{i}"),
             "iterations": _delta(a, b, f"reactor_iterations.{i}")})
+    inline, slow, wakes = (_delta(a, b, f"msgr_{k}") for k in (
+        "sends_inline", "sends_slow", "send_wakes"))
     wait_s, wait_n = _hist(snaps, "optracker.", "lat_obj_lock_wait")
     hold_s, hold_n = _hist(snaps, "optracker.", "lat_obj_lock_hold")
     wire_in_s, wire_in_n = _hist(snaps, "optracker.",
@@ -99,6 +105,11 @@ def report(snaps: list, ledgers: list, pinned: dict, ops: int) -> dict:
         "samples_unpaired": _delta(a, b, "msgr_frame_samples_unpaired"),
         "stamps_evicted": _delta(a, b, "msgr_frame_stamps_evicted"),
         "reactors": reactors,
+        "sends": {"inline": inline, "slow": slow, "wakes": wakes,
+                  "inline_share": inline / (inline + slow)
+                  if inline + slow else None,
+                  "frames_per_wake": (inline + slow) / wakes
+                  if wakes else None},
         "locks": {"wait_ms_mean": 1e3 * wait_s / wait_n if wait_n
                   else None, "waits": wait_n,
                   "hold_ms_mean": 1e3 * hold_s / hold_n if hold_n

@@ -100,6 +100,8 @@ def _pair(wrap: str, inline: bool, far: MsgrLedger | None = None):
     conn = client.connect(addr)
     conn.send_message(M.MOSDPing(from_osd=0))
     assert _wait(lambda: handled)
+    # (the ping dialled, so the coroutine path carried it: done)
+    assert _wait(lambda: not conn.session.slow_sends)
     handled.clear()
     return server, client, conn, handled
 
@@ -120,6 +122,8 @@ def test_phases_partition_send_called_to_handler_start(
     led = fresh_ledger
     clock = led.now_ns = FakeClock()
     server, client, conn, handled = _pair(wrap, inline)
+    # (the priming ping dialled the wire: the coroutine path's)
+    slow0 = led.perf.dump()["msgr_sends_slow"]
     try:
         lo = None
         for i in range(64):
@@ -148,9 +152,13 @@ def test_phases_partition_send_called_to_handler_start(
         # two threads meet the split follows who came first: the
         # header can be in the receiver's hands before the sender is
         # back from its write (`write` then ends there, transit 0),
-        # and the sender's stamp can overtake a read that had ended
+        # and the sender's stamp can overtake a read that had ended.
+        # A frame written in place by the send queue's flush never
+        # takes the send lock: its `sendlock` is 0
+        assert led.perf.dump()["msgr_sends_slow"] == slow0
+        assert ns["sendlock"] == 0
         for phase in FRAME_PHASES:
-            if phase in ("transit", "body_read"):
+            if phase in ("transit", "body_read", "sendlock"):
                 assert ns[phase] >= 0, (phase, ns)
             else:
                 assert ns[phase] > 0, (phase, ns)
@@ -167,6 +175,54 @@ def test_phases_partition_send_called_to_handler_start(
         assert dump["frame_ns.MOSDOp.hop"] == ns["hop"]
         assert dump["frame_n.MOSDOp"] == 1
         assert not led._flight              # the slot was claimed
+    finally:
+        client.shutdown()
+        server.shutdown()
+
+
+def test_phases_partition_for_a_frame_the_coroutine_path_carries(
+        fresh_ledger):
+    """A frame the send queue hands to `Connection._send` — here
+    because a fault-injection knob is on, so every frame goes there —
+    reads `sendlock` where the lock is held (the Task's own pass to
+    get there included): the eight phases still add up to the trip,
+    none negative."""
+    led = fresh_ledger
+    clock = led.now_ns = FakeClock()
+    server, client, conn, handled = _pair("plain", inline=True)
+    client.inject_dispatch_stall = 0.001
+    try:
+        before = led.perf.dump()
+        lo = None
+        for i in range(64):
+            lo = len(clock.log)
+            conn.send_message(_mosdop(i))
+            assert _wait(lambda: len(handled) > i)
+            if _kind(led, "MOSDOp")["rx_n"]:
+                break
+        assert _wait(lambda: _kind(led, "MOSDOp")["n"] == 1)
+        row = _kind(led, "MOSDOp")
+        assert (row["n"], row["rx_n"], row["transit_n"]) == (1, 1, 1)
+        after = led.perf.dump()
+        assert after["msgr_sends_inline"] == before["msgr_sends_inline"]
+        assert after["msgr_sends_slow"] - before["msgr_sends_slow"] == \
+            i + 1
+        reads = clock.log[lo:]
+        t_call = [t for t, who in reads if who == "send_message"]
+        t_handler = [t for t, who in reads if who == "frame_delivered"]
+        assert len(t_call) == 1 and len(t_handler) == 1
+        ns = row["ns"]
+        assert sum(ns[p] for p in FRAME_PHASES) == \
+            t_handler[0] - t_call[0]
+        for phase in FRAME_PHASES:
+            if phase in ("transit", "body_read"):
+                assert ns[phase] >= 0, (phase, ns)
+            else:
+                assert ns[phase] > 0, (phase, ns)
+        # where each was read: `hop` ends in the flush, `sendlock` in
+        # the coroutine once the lock is held
+        who = [w for _, w in reads]
+        assert who.index("_take_queued") < who.index("_send")
     finally:
         client.shutdown()
         server.shutdown()

@@ -1054,3 +1054,198 @@ def test_small_frames_cost_no_more_reads_than_frames_by_exact_count():
     assert calls["recv_into"] <= 2 * n * 1.05, calls
     server.shutdown()
     client.shutdown()
+
+
+# -- the send queue: one pass onto the reactor (messenger.py module doc) -----
+
+def _send_counts(m):
+    d = m.ledger.perf.dump()
+    return Counter({k: d[k] for k in (
+        "msgr_sends_inline", "msgr_sends_slow", "msgr_send_wakes")})
+
+
+def _primed_pair(handler):
+    """(server, client, conn): a lossless pair whose wire is up and
+    whose priming frame (it dialled: the coroutine path) is written
+    and done, so the next frame finds the session idle."""
+    server = Messenger("server")
+    server.add_dispatcher(handler)
+    addr = server.bind(("127.0.0.1", 0))
+    client = Messenger("client")
+    conn = client.connect(addr)
+    conn.send_message(M.MOSDPing(from_osd=-1))
+    assert _wait(lambda: conn.is_connected()
+                 and not conn.session.slow_sends)
+    return server, client, conn
+
+
+def test_threads_sending_across_redials_deliver_exactly_once_in_order():
+    """Four threads interleave frames to ONE lossless session while the
+    wire is dropped and re-dialled twice mid-stream: every frame
+    arrives exactly once, each thread's in its send order, and the
+    session's seqs are contiguous — the frames written in place and
+    those the coroutine path carried through the re-dials share one
+    seq space and one order."""
+    got = []
+    server, client, conn = _primed_pair(
+        lambda c, msg: got.append((msg.seq, msg.from_osd, msg.epoch)))
+    try:
+        before = _send_counts(client)
+        n, threads = 300, 4
+        # all senders stop at `mid` while the wire is dropped, so the
+        # frames after it find no wire; the second drop comes while
+        # they send
+        start, mid, resume = (threading.Barrier(threads + 1)
+                              for _ in range(3))
+
+        def sender(t):
+            start.wait(10)
+            for i in range(n):
+                if i == n // 2:
+                    mid.wait(10)
+                    resume.wait(10)
+                conn.send_message(M.MOSDPing(from_osd=t, epoch=i))
+                if i % 10 == 0:
+                    time.sleep(0.001)
+
+        workers = [threading.Thread(target=sender, args=(t,))
+                   for t in range(threads)]
+        for w in workers:
+            w.start()
+        start.wait(10)
+        mid.wait(10)
+        client._run_sync(_abort_wire(conn))
+        resume.wait(10)
+        assert _wait(lambda: len(got) > 1 + n * threads * 3 // 4)
+        client._run_sync(_abort_wire(conn))
+        for w in workers:
+            w.join(30)
+            assert not w.is_alive()
+        assert _wait(lambda: len(got) >= 1 + n * threads, 30)
+        time.sleep(0.2)                     # nothing more arrives
+        assert len(got) == 1 + n * threads
+        seqs = [s for s, _, _ in got]
+        assert seqs == list(range(1, len(got) + 1))
+        for t in range(threads):
+            assert [i for _, who, i in got if who == t] == list(range(n))
+        d = _send_counts(client) - before
+        assert d["msgr_sends_inline"] + d["msgr_sends_slow"] == \
+            n * threads
+        assert d["msgr_sends_slow"] > 0     # the re-dials
+        assert client.stats.totals()["reconnects"] >= 1
+    finally:
+        client.shutdown()
+        server.shutdown()
+
+
+def test_frames_behind_a_paused_transport_take_the_slow_path_in_order():
+    """A 32 MiB frame the socket cannot take at once leaves the
+    transport paused for writing; the frames queued behind it in the
+    same flush go to the coroutine path (which waits in drain()) and
+    arrive after it, in order."""
+    got = []
+    server, client, conn = _primed_pair(lambda c, msg: got.append(
+        ("op", len(msg.data)) if isinstance(msg, M.MOSDOp)
+        else ("ping", msg.from_osd)))
+    try:
+        before = _send_counts(client)
+        big = M.MOSDOp(spg_t(pg_t(1, 2), 0), hobject_t(1, "big"),
+                       [["write", 0, 32 << 20]], bytes(32 << 20))
+        k = 20
+        paused = []
+
+        def burst():                        # on the client's loop
+            conn.send_message(big)
+            for i in range(k):
+                conn.send_message(M.MOSDPing(from_osd=i))
+            # runs right after the flush the first send scheduled
+            client._loop.call_soon(lambda: paused.append(
+                conn.session.reader._paused))
+
+        client._loop.call_soon_threadsafe(burst)
+        assert _wait(lambda: len(got) >= 2 + k, 30)
+        assert got[1] == ("op", 32 << 20)
+        assert got[2:] == [("ping", i) for i in range(k)]
+        d = _send_counts(client) - before
+        assert d["msgr_sends_inline"] == 1          # the big frame
+        assert d["msgr_sends_slow"] == k
+        assert paused == [True]
+        assert _wait(lambda: not conn.session.slow_sends)
+    finally:
+        client.shutdown()
+        server.shutdown()
+
+
+def test_a_send_from_the_reactor_thread_queues_behind_other_threads():
+    """Frames queued from a foreign thread while the reactor is busy,
+    then one sent from the reactor's own thread: it is delivered last,
+    and the n + 1 frames cost the one wake-up the first append made;
+    a later send from the reactor's thread onto the empty queue costs
+    none (it signals its own loop without the self-pipe)."""
+    got = []
+    server, client, conn = _primed_pair(
+        lambda c, msg: got.append(msg.from_osd))
+    gate = threading.Event()
+    on_loop = []
+    try:
+        before = _send_counts(client)
+        n = 50
+
+        def on_reactor():
+            gate.wait(10)                   # the reactor is busy
+            conn.send_message(M.MOSDPing(from_osd=n))
+            on_loop.append(threading.current_thread().name)
+
+        client._loop.call_soon_threadsafe(on_reactor)
+        for i in range(n):
+            conn.send_message(M.MOSDPing(from_osd=i))
+        gate.set()
+        assert _wait(lambda: len(got) >= 2 + n)
+        assert got[1:] == list(range(n + 1))
+        assert on_loop and on_loop[0].startswith("msgr-reactor-")
+        # and from the reactor's thread onto an EMPTY queue: a plain
+        # call_soon, no cross-thread wake-up
+        client._loop.call_soon_threadsafe(
+            conn.send_message, M.MOSDPing(from_osd=n + 1))
+        assert _wait(lambda: len(got) >= 3 + n)
+        assert got[-1] == n + 1
+        d = _send_counts(client) - before
+        assert d["msgr_send_wakes"] == 1
+        assert d["msgr_sends_inline"] == n + 2
+    finally:
+        gate.set()
+        client.shutdown()
+        server.shutdown()
+
+
+@pytest.mark.parametrize("inject", [False, True],
+                         ids=["healthy", "socket_failures"])
+def test_send_path_counters_count_exactly(inject):
+    """A burst of N frames from one thread to a healthy session: all N
+    written in place, none slow, at most N cross-thread wake-ups.
+    With ms_inject_socket_failures on, the coroutine path carries
+    every frame (the knob lives in its write), and each still arrives
+    exactly once."""
+    got = []
+    server, client, conn = _primed_pair(
+        lambda c, msg: got.append(msg.from_osd))
+    try:
+        if inject:
+            client.inject_socket_failures = 50
+        before = _send_counts(client)
+        n = 200
+        for i in range(n):
+            conn.send_message(M.MOSDPing(from_osd=i))
+        assert _wait(lambda: len(got) >= 1 + n, 30)
+        assert got[1:] == list(range(n))
+        d = _send_counts(client) - before
+        if inject:
+            assert (d["msgr_sends_inline"], d["msgr_sends_slow"]) == \
+                (0, n)
+        else:
+            assert (d["msgr_sends_inline"], d["msgr_sends_slow"]) == \
+                (n, 0)
+        assert 1 <= d["msgr_send_wakes"] <= n
+    finally:
+        client.shutdown()
+        server.shutdown()
